@@ -23,11 +23,12 @@ fed_aggregator.py:455).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from commefficient_tpu.config import FedConfig
@@ -45,7 +46,6 @@ from commefficient_tpu.telemetry import tracing
 from commefficient_tpu.telemetry.clients import (CLIENT_GRAD_KEYS,
                                                  summarize_per_client)
 from commefficient_tpu.telemetry.signals import round_signals
-from commefficient_tpu.utils.jax_compat import shard_map
 
 
 class FedRuntime:
@@ -223,6 +223,13 @@ class FedRuntime:
                 cfg.sketch_impl, cfg.grad_size, cfg.num_cols, cfg.num_rows,
                 cfg.num_blocks, seed=cfg.sketch_seed, dtype=cfg.sketch_dtype,
                 scan_rows=cfg.sketch_scan_rows, pallas=cfg.pallas)
+            if cfg.sketch_impl == "circ":
+                # which implementation this run's encode/decode take —
+                # said out loud, so a kernel that gave way to the XLA
+                # rolls is seen and not inferred from a slow round
+                blocker = self.cs.pallas_blocker()
+                print("sketch kernel path: "
+                      + ("pallas" if blocker is None else f"xla ({blocker})"))
         # sketch-table wire dtype (--sketch_dtype): uploads/psum payloads
         # travel rounded to this dtype; all server math stays fp32
         self._table_dtype = (jnp.dtype(cfg.sketch_dtype)
@@ -328,6 +335,27 @@ class FedRuntime:
                 "unavailable for this configuration (use auto to fall "
                 "back to the replicated tail instead):\n  "
                 + "\n  ".join(ss_problems))
+        # The replicated server tail on a mesh decodes under GSPMD, and
+        # a Mosaic kernel cannot be partitioned automatically (the TPU
+        # lowering raises "Mosaic kernels cannot be automatically
+        # partitioned. Please wrap the call in a shard_map." — met on
+        # four v5e chips, PR 21). That tail's decode takes the XLA rolls;
+        # the client block's encode, inside shard_map, keeps its kernel.
+        self._server_tail_xla = (
+            cfg.mode == "sketch" and cfg.sketch_impl == "circ"
+            and mesh is not None and not self._sharded_server
+            and self.cs.kernel_path == "pallas")
+        if self._server_tail_xla:
+            if cfg.pallas == "on":
+                raise ValueError(
+                    "--pallas on: the replicated server tail on a mesh "
+                    "cannot host the Pallas decode (Mosaic kernels are not "
+                    "partitioned automatically); keep the sharded tail "
+                    "(--sketch_sharded_server auto) or pass --pallas auto "
+                    "to decode with the XLA rolls there")
+            print("sketch kernel path, server tail: xla (replicated tail "
+                  "on a mesh runs under GSPMD, which cannot partition a "
+                  "Mosaic call)")
         # --decode_overlap composition: the table reduce itself MOVES
         # into the decode executable — the cohort ends at each device's
         # LOCAL partial table, so the round's metrics sync completes
@@ -690,9 +718,9 @@ class FedRuntime:
         before the first round. A repeat call is a no-op: the wrapper
         needs the raw jitted functions' AOT surface, so double-wrapping
         would silently break the observation it exists to provide."""
-        if getattr(self, "_compile_watched", False):
+        if getattr(self, "compile_watcher", None) is not None:
             return
-        self._compile_watched = True
+        self.compile_watcher = watcher
         self._round = watcher.wrap("round_step", self._round)
         self._val = watcher.wrap("val_step", self._val)
         if self._cohort is not None:
@@ -965,6 +993,8 @@ class FedRuntime:
             update, Vvel, Verr = self._sharded_server_apply(
                 agg, state.Vvelocity, state.Verror, server_lr, cs)
             return update, Vvel, Verr, None
+        if self._server_tail_xla:
+            cs = dataclasses.replace(cs, pallas="off")
         return server_update(cfg, agg, state.Vvelocity, state.Verror,
                              server_lr, cs=cs, dp_rng=server_rng,
                              dense_preimage=self._dense_preimage)

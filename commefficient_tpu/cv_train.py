@@ -25,7 +25,7 @@ import numpy as np
 from commefficient_tpu import models
 from commefficient_tpu.config import (FedConfig, enable_compilation_cache,
                                       num_classes_of_dataset, parse_args)
-from commefficient_tpu.core import FedRuntime, RoundPipeline
+from commefficient_tpu.core import FedRuntime, PreemptGuard, RoundPipeline
 from commefficient_tpu.data import (
     FedSampler,
     ValSampler,
@@ -83,17 +83,24 @@ def build_model(cfg: FedConfig, num_classes: int):
 
 def build_mesh(cfg: FedConfig):
     """Honor --mesh_shape/--mesh_axes (TPU-native flags): returns a Mesh or
-    None for plain single-device jit."""
+    None for plain single-device jit. Says how many of the visible
+    devices the run uses — without --mesh_shape a four-chip host runs on
+    chip 0 alone, which should be read off the log, not a profile."""
+    dev = jax.devices()[0]
+    visible = f"{jax.device_count()} visible {dev.platform} device(s)"
     if not cfg.mesh_shape:
+        print(f"devices: using 1 of {visible} ({dev.device_kind}; "
+              "no --mesh_shape, plain jit on device 0)")
         return None
     from commefficient_tpu.parallel import make_mesh
     mesh = make_mesh(cfg.mesh_shape, cfg.mesh_axes)
-    if mesh is not None:
-        n = mesh.shape[mesh.axis_names[0]]
-        if cfg.num_workers % n != 0:
-            raise ValueError(
-                f"--num_workers {cfg.num_workers} must be divisible by the "
-                f"mesh axis size {n}")
+    n = mesh.shape[mesh.axis_names[0]]
+    if cfg.num_workers % n != 0:
+        raise ValueError(
+            f"--num_workers {cfg.num_workers} must be divisible by the "
+            f"mesh axis size {n}")
+    print(f"devices: using {mesh.size} of {visible} ({dev.device_kind}; "
+          f"mesh {dict(mesh.shape)})")
     return mesh
 
 
@@ -291,8 +298,7 @@ def run_validation(runtime: FedRuntime, state, val_ds, cfg: FedConfig,
                    val_store=None):
     """Validation sweep. With a DeviceStore, every batch is gathered on
     device and the per-batch sums accumulate on device — exactly one host
-    fetch for the whole sweep (host<->device latency on this runtime is
-    ~170 ms per transfer, see data/device_store.py)."""
+    fetch for the whole sweep (see data/device_store.py)."""
     acc_sums = None
     host_sums = [0.0, 0.0, 0.0]
     for idx, mask in ValSampler(len(val_ds), cfg.valid_batch_size):
@@ -333,7 +339,13 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
           lr_mult: Optional[jax.Array] = None, loggers=(), timer=None,
           ckpt_mgr=None, start_epoch: int = 0, writer=None, schedule=None,
           telemetry=None, model_flops_per_round: Optional[float] = None,
-          resume_info=None):
+          resume_info=None, guard=None):
+    """The shared driver loop. Returns ``(state, summary)``; ``summary``
+    is None when the run ended before its schedule — a preemption drain
+    (an orderly handoff) or an abort (non-finite update, alert abort,
+    quarantine exhausted). A caller that must tell the two apart passes
+    its own ``guard`` (core/preempt.PreemptGuard) and reads
+    ``guard.requested`` afterwards; see :func:`finish_run`."""
     timer = timer or Timer()
     # rounds already trained inside start_epoch (round-granular resume:
     # a preempt-tagged checkpoint written mid-epoch; 0 everywhere else)
@@ -432,8 +444,7 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
     # re-admit known-bad clients), participation coverage, and the
     # anomaly monitor's rolling histories — then announce the resume
     # lineage (and any corrupt-generation fallbacks) into the stream
-    from commefficient_tpu.core.preempt import (PreemptGuard,
-                                                RoundWatchdog,
+    from commefficient_tpu.core.preempt import (RoundWatchdog,
                                                 collect_ledger_state,
                                                 restore_ledger_state,
                                                 with_retries)
@@ -458,7 +469,8 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
     # INSTALLED (and the watchdog thread started) immediately before
     # the try whose finally reclaims them — an exception in the setup
     # code between must not leak a replaced signal handler or a thread
-    guard = PreemptGuard(cfg.preempt_grace)
+    if guard is None:
+        guard = PreemptGuard(cfg.preempt_grace)
     # hang watchdog (--watchdog): deadline each round's dispatch+sync at
     # watchdog_mult x the rolling median round time; on expiry fire a
     # critical round_stall alert THROUGH the monitor and record an
@@ -494,11 +506,11 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
               f"nonfinite_action={cfg.nonfinite_action}")
     # device-resident data path: upload the dataset once, gather + augment
     # each round's batch on device, accumulate metrics on device, and fetch
-    # once per epoch — a host<->device transfer costs ~170 ms latency on
-    # this runtime, so the reference's per-round stream-and-read pattern
-    # (cv_train.py:193-229) would dominate the ~50 ms round ~10x. On a
-    # mesh the arrays replicate across devices and train batches come out
-    # already sharded over the round's client axis.
+    # once per epoch — the reference's per-round stream-and-read pattern
+    # (cv_train.py:193-229) would put a host sync on every round's
+    # critical path (data/device_store.py). On a mesh the arrays replicate
+    # across devices and train batches come out already sharded over the
+    # round's client axis.
     train_store = make_device_store(
         train_ds, cfg.dataset_name, True, mesh=runtime.mesh,
         out_shardings=(runtime.batch_sharding()
@@ -511,6 +523,14 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
               f"{train_store.nbytes / 2**20:.0f} MiB"
               + (f", val {val_store.nbytes / 2**20:.0f} MiB"
                  if val_store else ""))
+    if train_store is None or val_store is None:
+        # the host gathers at least one of the two streams: say with
+        # which implementation (a failed C++ build is a slower gather,
+        # not an error)
+        from commefficient_tpu.data import native
+        print("host gather path: "
+              + ("native (native/fedloader.cpp)" if native.available()
+                 else f"numpy ({native.unavailable_reason()})"))
     data_key = jax.random.PRNGKey(cfg.seed ^ 0xDA7A)
     if schedule is None:
         # CV default: the cifar10_fast triangular ramp
@@ -1093,7 +1113,7 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
                         final=telemetry.last_epoch)
                     # never hand a truncated stream to the postmortem:
                     # everything above must survive the process dying
-                    # right after this return (BENCH_r02 lesson, fsync'd)
+                    # right after this return (fsync'd)
                     telemetry.fsync()
                 return state, None
             total = max(float(sums[2]), 1.0)
@@ -1229,7 +1249,24 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
     return state, summary
 
 
-def main(argv=None):
+def finish_run(summary, guard, on_finish, runtime, state) -> None:
+    """What both drivers do once ``train`` has returned: a run that
+    stopped early for any reason but a preemption is a FAILED process
+    (``SystemExit`` with the reason, exit code 1) — a diverged run must
+    never look like a finished one to whatever launched it; a drained
+    preemption keeps its documented exit 0. Then hand the live run to
+    ``on_finish(runtime, state, summary)`` if the caller gave one
+    (chip_smoke.py asserts on device placement and the compiled round
+    through it)."""
+    if summary is None and not guard.requested:
+        raise SystemExit(
+            "run aborted before its schedule completed (see the "
+            "TERMINATING line above): exiting non-zero")
+    if on_finish is not None:
+        on_finish(runtime, state, summary)
+
+
+def main(argv=None, *, on_finish=None):
     cfg = parse_args(argv, default_lr=0.4)
     enable_compilation_cache(cfg)
     np.random.seed(cfg.seed)
@@ -1314,6 +1351,7 @@ def main(argv=None):
         telemetry.instrument(runtime)
         telemetry.memory_event("init")
     tsv = TSVLogger()
+    guard = PreemptGuard(cfg.preempt_grace)
     try:
         state, summary = train(cfg, runtime, state, train_ds, val_ds,
                                lr_mult=lr_mult, loggers=(TableLogger(), tsv),
@@ -1321,11 +1359,12 @@ def main(argv=None):
                                start_epoch=start_epoch,
                                writer=make_writer(cfg, logdir=logdir),
                                telemetry=telemetry,
-                               resume_info=resume_info)
+                               resume_info=resume_info, guard=guard)
     finally:
         if telemetry is not None:
             telemetry.close()
     print(tsv)
+    finish_run(summary, guard, on_finish, runtime, state)
 
     if cfg.do_checkpoint and summary is not None:
         os.makedirs(cfg.checkpoint_path, exist_ok=True)

@@ -3,10 +3,11 @@
 Why this exists
 ---------------
 The reference streams every round's batch host->GPU and reads metrics back
-per round (fed_worker.py:41, cv_train.py:193-229) — cheap over PCIe. On this
-TPU runtime a single host<->device transfer costs ~170 ms of LATENCY
-regardless of size, so a per-round upload+fetch pair dominates the 50 ms
-federated round ~10x. The TPU-native discipline (SURVEY.md §7 "hard parts":
+per round (fed_worker.py:41, cv_train.py:193-229). Here a per-round
+upload+fetch pair would put the host on the round's critical path: the
+device idles while the batch is built and copied, and the fetch waits for
+the round to finish before the next can be dispatched (one host sync on
+the v5e: PERF.md). The TPU-native discipline (SURVEY.md §7 "hard parts":
 keep state resident, fetch only metrics) extends to the DATA: raw uint8
 arrays are uploaded once (CIFAR-10 train is 150 MB), each round's batch is
 gathered and augmented ON DEVICE from tiny resident index arrays, and the

@@ -1,10 +1,14 @@
 """ctypes bindings for the native C++ data-plane (native/fedloader.cpp).
 
 Compiles the shared library on first use with g++ (no pybind11 in this
-environment; pure C ABI + ctypes). Falls back silently to the numpy
-transforms when a compiler is unavailable — set
-``COMMEFFICIENT_NATIVE=0`` to force the numpy path,
-``COMMEFFICIENT_NATIVE=1`` to make a missing native build an error.
+environment; pure C ABI + ctypes) for the baseline ISA of the host's
+architecture — no ``-march=native``: ``native/build/`` is untracked, a
+working tree gets copied between machines, and a binary tuned to the
+build host's CPU dies with SIGILL on another. Falls back to the numpy
+transforms when the build fails; :func:`unavailable_reason` says why
+and the driver prints which path runs. ``COMMEFFICIENT_NATIVE=0``
+forces the numpy path, ``COMMEFFICIENT_NATIVE=1`` makes a missing
+native build an error.
 """
 
 from __future__ import annotations
@@ -23,32 +27,42 @@ _SO = os.path.join(_REPO_ROOT, "native", "build", "libfedloader.so")
 
 _lib = None
 _tried = False
+_why_not: Optional[str] = None
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Compile the library; returns None on success, else the reason."""
     os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-           "-pthread", _SRC, "-o", _SO]
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
+           _SRC, "-o", _SO]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.TimeoutExpired:
+        return "g++ timed out after 120 s"
+    except subprocess.CalledProcessError as e:
+        tail = e.stderr.decode(errors="replace").strip().splitlines()[-1:]
+        return f"g++ exited {e.returncode}: {' '.join(tail)}"
+    return None
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _why_not
     if _lib is not None or _tried:
         return _lib
     _tried = True
     if os.environ.get("COMMEFFICIENT_NATIVE") == "0":
+        _why_not = "COMMEFFICIENT_NATIVE=0"
         return None
     if not os.path.exists(_SO) or (
             os.path.exists(_SRC)
             and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-        if not _build():
+        _why_not = _build()
+        if _why_not is not None:
             if os.environ.get("COMMEFFICIENT_NATIVE") == "1":
-                raise RuntimeError("native fedloader build failed")
+                raise RuntimeError(
+                    f"native fedloader build failed: {_why_not}")
             return None
     lib = ctypes.CDLL(_SO)
     u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
@@ -67,6 +81,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return get_lib() is not None
+
+
+def unavailable_reason() -> Optional[str]:
+    """Why :func:`available` is False (None while it is True)."""
+    get_lib()
+    return _why_not
 
 
 def gather_augment(images: np.ndarray, idx: np.ndarray, mean: np.ndarray,

@@ -23,9 +23,10 @@ import numpy as np
 
 from commefficient_tpu.config import (FedConfig,
                                       enable_compilation_cache, parse_args)
-from commefficient_tpu.core import FedRuntime
+from commefficient_tpu.core import FedRuntime, PreemptGuard
 from commefficient_tpu.cv_train import (
     build_mesh,
+    finish_run,
     run_validation,
     setup_checkpointing,
     train as shared_train,
@@ -144,7 +145,7 @@ def load_pretrained(out_dir: str):
     return model, params, gcfg, tokenizer
 
 
-def main(argv=None):
+def main(argv=None, *, on_finish=None):
     cfg = parse_args(argv, default_lr=0.16)  # reference gpt2 lr lineage
     enable_compilation_cache(cfg)
     np.random.seed(cfg.seed)
@@ -251,6 +252,7 @@ def main(argv=None):
                     * cfg.num_candidates * max_seq_len)
     round_flops = gpt2_model_flops(gcfg, round_tokens, max_seq_len)
     tsv = TSVLogger()
+    guard = PreemptGuard(cfg.preempt_grace)
     try:
         state, summary = shared_train(cfg, runtime, state, train_ds, val_ds,
                                       loggers=(TableLogger(), tsv),
@@ -260,11 +262,12 @@ def main(argv=None):
                                       writer=make_writer(cfg, logdir=logdir),
                                       telemetry=telemetry,
                                       model_flops_per_round=round_flops,
-                                      resume_info=resume_info)
+                                      resume_info=resume_info, guard=guard)
     finally:
         if telemetry is not None:
             telemetry.close()
     print(tsv)
+    finish_run(summary, guard, on_finish, runtime, state)
 
     if summary is not None:
         nll = summary["test_loss"]

@@ -86,9 +86,10 @@ class CirculantSketch:
     c: int
     r: int
     num_blocks: int                 # decode memory chunking over the m axis
-    # pallas kernel policy (config.py --pallas): "auto"/"on" = fused
-    # encode AND decode when eligible (both measured wins under the
-    # fused-clients round), "off" = XLA paths only
+    # pallas kernel policy (config.py --pallas): "auto" = fused encode
+    # AND decode when eligible, XLA paths otherwise; "on" = the same,
+    # but on the TPU backend an ineligible sketch is an error
+    # (make_circulant_sketch) instead of a quiet 6x; "off" = XLA only
     pallas: str = "auto"
 
     dense_transform = False
@@ -149,25 +150,43 @@ class CirculantSketch:
         k = jnp.arange(self.c, dtype=jnp.int32)[None, :]
         return (k - sign * s) % self.c
 
-    def _pallas_eligible(self) -> bool:
-        """Fused pallas kernels (ops/circulant_pallas.py) need: TPU
-        backend, a SHIFT_ALIGN-granular column count AND shift table
+    def pallas_blocker(self) -> Optional[str]:
+        """The condition that keeps this sketch off the fused pallas
+        kernels (ops/circulant_pallas.py), or None when they serve it.
+        They need: ``--pallas`` not off, the TPU backend, more than one
+        block, a SHIFT_ALIGN-granular column count AND shift table
         (``make_circulant_sketch`` generates aligned shifts whenever
         c % 1024 == 0 — the reference's default c=500,000 = 2^5·5^6 can
         never align; pick e.g. --num_cols 524288), and the wrap-padded
-        table within the decode kernel's VMEM residency budget.
-        ``--pallas off`` disables outright."""
-        if (self.m <= 1 or self.pallas == "off"
-                or jax.default_backend() != "tpu"):
-            return False
+        table within the decode kernel's VMEM residency budget."""
         from commefficient_tpu.ops.circulant_pallas import (
-            SHIFT_ALIGN, TABLE_VMEM_BUDGET, _lane_tile)
+            SHIFT_ALIGN, TABLE_VMEM_BUDGET, table_vmem_bytes)
+        if self.pallas == "off":
+            return "--pallas off"
+        if jax.default_backend() != "tpu":
+            return f"backend is {jax.default_backend()!r}, not 'tpu'"
+        if self.m <= 1:
+            return (f"d={self.d} <= num_cols={self.c}: one block, the "
+                    "roll is a single slice pair")
         if self.c % SHIFT_ALIGN:
-            return False
+            return f"num_cols={self.c} is not a multiple of {SHIFT_ALIGN}"
         if any(s % SHIFT_ALIGN for row in self.shifts for s in row):
-            return False
-        return 4 * self.r * (self.c + _lane_tile(self.c)) \
-            <= TABLE_VMEM_BUDGET
+            return f"shift table is not {SHIFT_ALIGN}-aligned"
+        need = table_vmem_bytes(self.c, self.r)
+        if need > TABLE_VMEM_BUDGET:
+            return (f"wrap-padded {self.r}x{self.c} table is {need} bytes, "
+                    f"over TABLE_VMEM_BUDGET={TABLE_VMEM_BUDGET}")
+        return None
+
+    def _pallas_eligible(self) -> bool:
+        return self.pallas_blocker() is None
+
+    @property
+    def kernel_path(self) -> str:
+        """Which implementation encode/decode take for this sketch on
+        this backend: ``"pallas"`` (both fused kernels) or ``"xla"``
+        (static rolls, or the gather form past _UNROLL_MAX_BLOCKS)."""
+        return "pallas" if self._pallas_eligible() else "xla"
 
     def _use_pallas_decode(self) -> bool:
         # default ON when eligible: measured 21 ms vs the roll path's
@@ -452,5 +471,13 @@ def make_circulant_sketch(d: int, c: int, r: int, num_blocks: int = 1,
                        for _ in range(r))
     sign_keys = rng.randint(0, 2**32, size=(r,),
                             dtype=np.uint64).astype(np.uint32) | 1
-    return CirculantSketch(jnp.asarray(sign_keys), shifts, d=d, c=c, r=r,
-                           num_blocks=num_blocks, pallas=pallas)
+    cs = CirculantSketch(jnp.asarray(sign_keys), shifts, d=d, c=c, r=r,
+                         num_blocks=num_blocks, pallas=pallas)
+    if pallas == "on" and jax.default_backend() == "tpu":
+        blocker = cs.pallas_blocker()
+        if blocker is not None:
+            raise ValueError(
+                "--pallas on: the fused sketch kernels cannot serve this "
+                f"sketch ({blocker}); fix the geometry or pass --pallas "
+                "auto to accept the XLA path")
+    return cs

@@ -60,8 +60,11 @@ _GOLDEN = 0x9E3779B9
 SHIFT_ALIGN = 1024
 
 # decode keeps the wrap-padded (r, c/128 + ct/128, 128) table resident in
-# VMEM: cap its footprint (bytes) under the ~16 MB/core budget with room
-# for temporaries
+# VMEM: cap its footprint (table_vmem_bytes). Checked on a v5e under jax
+# 0.9.0 / libtpu 0.0.34 (PR 21): both kernels compile under Mosaic's
+# default scoped-VMEM limit at 10.1 MB (r=5, c=500,736) and at 11.8 MB
+# (r=5, c=524,288), so no vmem_limit_bytes is passed. A larger budget
+# has not met the compiler.
 TABLE_VMEM_BUDGET = 12 << 20
 
 # lane-tile width of the streamed output/input spans
@@ -76,6 +79,11 @@ def _lane_tile(c: int) -> int:
         if c % n == 0 and (c // n) % SHIFT_ALIGN == 0 and c // n <= _CT_MAX:
             return c // n
     raise ValueError(f"c={c} has no {SHIFT_ALIGN}-aligned lane tile")
+
+
+def table_vmem_bytes(c: int, r: int) -> int:
+    """Bytes of the decode kernel's resident wrap-padded f32 table."""
+    return 4 * r * (c + _lane_tile(c))
 
 
 def _signs2d(start, sub, key):
@@ -134,6 +142,12 @@ def _encode_kernel(shifts_ref, keys_ref, v_ref, out_ref, *, c, r, ct):
                                        keys_ref[j]) * span
 
 
+# stable kernel names: what a compiled round's HLO and a device trace
+# show for the two Mosaic custom calls
+ENCODE_KERNEL_NAME = "circulant_sketch_encode"
+DECODE_KERNEL_NAME = "circulant_sketch_decode"
+
+
 def _wrap_pad(x3, sub):
     """(..., n, 128) -> (..., n+sub, 128) with the first ``sub``
     sublane-rows appended, so a mod-n span never wraps."""
@@ -165,6 +179,7 @@ def pallas_encode(vec_padded, shifts, sign_keys, *, c, r, m,
         out_shape=jax.ShapeDtypeStruct((nct, r, sub, 128), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name=ENCODE_KERNEL_NAME,
     )(shifts, sign_keys, blocks)
     # (nct, r, sub, 128) -> (r, c): element (t, j, s, l) is
     # table[j, t·ct + s·128 + l]
@@ -192,5 +207,6 @@ def pallas_decode(table, shifts, sign_keys, *, c, r, m, interpret=False):
         out_shape=jax.ShapeDtypeStruct((m, nct, sub, 128), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name=DECODE_KERNEL_NAME,
     )(shifts, sign_keys, t3)
     return out.reshape(-1)

@@ -30,7 +30,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 NEG = -1e30
@@ -54,9 +54,8 @@ def ring_attention_inner(q: jax.Array, k: jax.Array, v: jax.Array,
     # accumulators start identical on every device but become
     # device-varying after the first step — mark them varying up front
     # (shard_map's check would otherwise reject the scan carry)
-    from commefficient_tpu.utils.jax_compat import pcast
     m0, l0, o0 = jax.tree.map(
-        lambda t: pcast(t, (axis_name,), to="varying"),
+        lambda t: lax.pcast(t, (axis_name,), to="varying"),
         (jnp.full(batch_shape + (H, Sl), NEG, jnp.float32),
          jnp.zeros(batch_shape + (H, Sl), jnp.float32),
          jnp.zeros(batch_shape + (Sl, H, D), jnp.float32)))
@@ -96,8 +95,6 @@ def ring_attention_inner(q: jax.Array, k: jax.Array, v: jax.Array,
 def make_ring_attention(mesh: Mesh, axis: str = "seq") -> Callable:
     """Drop-in ``attn_impl`` for the GPT-2 modules: takes full
     (..., S, H, D) arrays, shards S over ``axis`` and runs the ring."""
-    from commefficient_tpu.utils.jax_compat import shard_map
-
     n = mesh.shape[axis]
 
     def attn(q, k, v):

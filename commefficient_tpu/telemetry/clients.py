@@ -99,14 +99,12 @@ def client_stats_to_host(summary: Optional[Dict[str, Dict[str, Any]]],
     maps to {p5,...,p95,max,mean,argmax_client}, non-finite -> None."""
     if not summary:
         return {}
-    try:
-        # ONE batched device->host fetch of the whole pytree: the
-        # per-field float() conversions below would otherwise each
-        # issue their own synchronous transfer (~50 per event)
-        import jax
-        summary = jax.device_get(summary)
-    except ImportError:  # plain-numpy summaries (tests, offline tools)
-        pass
+    # ONE batched device->host fetch of the whole pytree: the per-field
+    # float() conversions below would otherwise each issue their own
+    # synchronous transfer (~50 per event). (A plain-numpy summary
+    # passes through device_get unchanged.)
+    import jax
+    summary = jax.device_get(summary)
     ids = np.asarray(client_ids)
 
     def fin(x) -> Optional[float]:

@@ -36,10 +36,7 @@ def _signature(args) -> Any:
 
 def _cost_analysis(compiled) -> Dict[str, Any]:
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        return dict(cost) if cost else {}
+        return dict(compiled.cost_analysis() or {})
     except Exception:
         return {}
 
@@ -63,6 +60,11 @@ class JitWatcher:
         # .py) — the per-executable static byte inventory; the flight
         # recorder ships the aborting executable's entry in memory.json
         self.memory: Dict[str, Dict[str, Any]] = {}
+        # latest compiled executable per watched name: the program that
+        # actually runs, for whoever must read its HLO (chip_smoke.py
+        # counts the Mosaic custom calls in the round) without paying a
+        # second lower+compile
+        self.executables: Dict[str, Any] = {}
 
     def wrap(self, name: str, fn: Callable) -> Callable:
         cache: Dict[Any, Any] = {}
@@ -105,6 +107,7 @@ class JitWatcher:
                     emit(len(cache), 0.0, 0.0, {}, fallback=True)
                     return fn(*args)
                 cache[key] = compiled
+                self.executables[name] = compiled
                 emit(len(cache), t1 - t0, t2 - t1,
                      _cost_analysis(compiled))
                 # collective ledger of the fresh executable (count/kind/

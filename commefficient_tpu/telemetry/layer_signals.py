@@ -12,7 +12,7 @@ accumulator. This module measures exactly that: the model pytree is
 partitioned into named groups mapped to ravel-order index ranges (the
 same leaf order ``jax.flatten_util`` and the PR-9 ``encode_grad_tree``
 leaf-range stream walk), and the round reduces its dense quantities
-per group (ops/segments.py scatter-adds keyed by a precomputed int32
+per group (ops/segments.py masked reductions keyed by a precomputed int32
 group-id map — on a mesh each device reduces its coordinate shard and
 ONE small (G,) psum recombines; the collective ledger gates against a
 per-group unroll):
@@ -113,9 +113,9 @@ class GroupSpec:
 
     def gid(self, d_pad: Optional[int] = None):
         """The (d_pad,) int32 group-id map the in-jit reductions key
-        off. Coordinates >= d (mesh padding) map to ``n_groups`` —
-        out of bounds for the (G,) buckets, so the scatter DROPS them
-        (ops/segments.py): padding lands in no group."""
+        off. Coordinates >= d (mesh padding) map to ``n_groups``,
+        which matches no group (ops/segments.py): padding lands in no
+        group."""
         import numpy as np
         d_pad = self.d if d_pad is None else int(d_pad)
         gid = np.full((d_pad,), self.n_groups, np.int32)
@@ -234,14 +234,13 @@ def layer_group_signals(cfg, *, gid, n_groups: int, update,
 
     from commefficient_tpu.ops.segments import group_sum_at, group_sum_cols
 
-    # ONE batched segment reduction for every live dense source: the
-    # columns stack into an (L, C) operand and scatter-add into (G, C)
-    # buckets, so the whole per-group story costs one scatter and (on a
-    # mesh) ONE small (G*C,) psum — adding a source must never add a
-    # collective launch (the per-group-unroll regression class the
-    # dryrun ledger gates). All live sources share the update's length
-    # by construction (the runtime passes round quantities of one
-    # topology — asserted, not assumed).
+    # one segment reduction per live dense source into (G, C) buckets
+    # (ops/segments.py: masked reductions, no scatter); on a mesh the C
+    # small (G,) psums combine into one launch — adding a source must
+    # never add a collective launch (the per-group-unroll regression
+    # class the dryrun ledger gates). All live sources share the
+    # update's length by construction (the runtime passes round
+    # quantities of one topology — asserted, not assumed).
     cols = [("update_mass", update.astype(jnp.float32) ** 2),
             ("topk_count", (update != 0).astype(jnp.float32))]
     if grad_dense is not None:
@@ -252,8 +251,7 @@ def layer_group_signals(cfg, *, gid, n_groups: int, update,
         assert err_dense.shape == update.shape, (err_dense.shape,
                                                  update.shape)
         cols.append(("error_mass", err_dense.astype(jnp.float32) ** 2))
-    buckets = group_sum_cols(jnp.stack([c for _, c in cols], axis=-1),
-                             gid, n_groups)
+    buckets = group_sum_cols([c for _, c in cols], gid, n_groups)
     out: Dict[str, Any] = {name: buckets[:, j]
                            for j, (name, _) in enumerate(cols)}
     out.setdefault("grad_mass", None)

@@ -3,9 +3,8 @@
 Writes ``telemetry.jsonl`` (schema.py) into the run's logdir next to
 whatever else the run records (tensorboard events, traces). The console
 TableLogger/TSVLogger output is deliberately untouched: telemetry is a
-parallel channel, not a replacement — the BENCH_r02 post-mortem (a
-dropped remote-compile body nearly losing a whole benchmark artifact)
-is why every event is flushed to disk the moment it happens, and why a
+parallel channel, not a replacement. Every event is flushed to disk the
+moment it happens (a run that dies must leave its stream behind), and a
 telemetry failure only disables telemetry, never the run.
 """
 

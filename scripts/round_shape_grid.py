@@ -75,10 +75,7 @@ def flops_per_image():
              "target": jnp.asarray(rng.randint(0, 10, (N,)), jnp.int32)}
     mask = jnp.ones((N,), bool)
     g = jax.jit(jax.value_and_grad(lambda p: loss_fn(p, batch, mask)[0]))
-    cost = g.lower(params).compile().cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
-    return float(cost["flops"]) / N
+    return float(g.lower(params).compile().cost_analysis()["flops"]) / N
 
 
 def main():

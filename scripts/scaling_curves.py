@@ -68,8 +68,6 @@ def _configure(n: int) -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n}").strip()
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 
 def run_arm(scaling: str, n: int, stream_dir: str, rounds: int,
@@ -92,11 +90,10 @@ def run_arm(scaling: str, n: int, stream_dir: str, rounds: int,
 
     assert len(jax.devices()) == n, (len(jax.devices()), n)
     # persistent XLA compile cache: without it EVERY subprocess arm pays
-    # the cold round compile (BENCH r05 measured it at 77 s on the
-    # flagship round) — the launcher threads --compile_cache through so
-    # repeat sweeps start warm; warmup_s below records what was paid
-    if compile_cache:
-        enable_compilation_cache_dir(compile_cache)
+    # the cold round compile — the launcher threads --compile_cache
+    # through so repeat sweeps start warm; warmup_s below records what
+    # was paid
+    enable_compilation_cache_dir(compile_cache)
     mesh = make_mesh((n,), ("clients",)) if n > 1 else None
 
     W = WEAK_PER_DEVICE * n if scaling == "weak" else STRONG_WORKERS
@@ -230,7 +227,9 @@ def main() -> int:
                          "the weak curve; int8's own gate compares its "
                          "table-reduce wire bytes against the f32 arm)")
     ap.add_argument("--compile_cache",
-                    default="~/.cache/commefficient_tpu_xla",
+                    default=os.path.join(
+                        os.path.dirname(os.path.dirname(
+                            os.path.abspath(__file__))), ".jax_cache"),
                     help="persistent XLA compile cache DIR threaded "
                          "into every subprocess arm (empty string "
                          "disables — each arm then pays the cold round "

@@ -95,8 +95,8 @@ def test_group_spec_tiles_ravel_order_exactly():
 
 
 def test_gid_padding_lands_in_no_group():
-    """Mesh d_pad coordinates map to n_groups (out of bounds) and the
-    scatter drops them: padded mass never leaks into a real group."""
+    """Mesh d_pad coordinates map to n_groups, which matches no group:
+    padded mass never leaks into a real group."""
     from commefficient_tpu.ops.segments import group_sq_mass
     spec = make_group_spec(make_params(), "coarse")
     d_pad = D + 11
@@ -127,7 +127,7 @@ def test_segment_reductions_match_numpy_reference():
                                ref_sq, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(group_count(x != 0, gid, G)),
                                ref_ct, rtol=1e-6)
-    cols = jnp.stack([x * x, (x != 0).astype(jnp.float32)], axis=-1)
+    cols = [x * x, (x != 0).astype(jnp.float32)]
     got = np.asarray(group_sum_cols(cols, gid, G))
     np.testing.assert_allclose(got[:, 0], ref_sq, rtol=1e-5)
     np.testing.assert_allclose(got[:, 1], ref_ct, rtol=1e-6)

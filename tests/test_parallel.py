@@ -14,6 +14,7 @@ import pytest
 from commefficient_tpu.config import FedConfig
 from commefficient_tpu.core import FedRuntime
 from commefficient_tpu.parallel import FedShardings, make_mesh
+from commefficient_tpu.telemetry.clients import CLIENT_STAT_KEYS
 
 
 def quad_loss(params, batch, mask):
@@ -180,10 +181,23 @@ def test_collectives_are_shard_or_table_sized(mode, extra):
     # column-sharded tables, telemetry/signals.py) gather the compressed
     # payload — bounded by the same table size as the aggregation psum
     gather_bound = max(d_pad, table if mode == "sketch" else 0)
+    # ...or the client-stats summary: summarize_per_client replicates ONE
+    # (K, W) matrix of per-client scalars, K <= len(CLIENT_STAT_KEYS).
+    # That is O(K*W) whatever d is (56 floats against d = 6.5M on
+    # ResNet-9); it exceeds d_pad only because this test's d is 18. XLA
+    # under jax 0.9.0 gathers the stacked matrix in one launch (f32[5,8]
+    # here: 5 stats x 8 clients), and with client_stats=False the gather
+    # is gone (test_client_stats_gather_is_the_only_oversized_one).
+    W = cfg.num_workers
+    stats_gathers = [n for kind, n in colls if kind == "all-gather"
+                     and n > gather_bound]
+    assert len(stats_gathers) <= 1, colls
+    for n in stats_gathers:
+        assert n % W == 0 and n // W <= len(CLIENT_STAT_KEYS), colls
     for kind, n in colls:
         if kind == "all-gather":
-            assert n <= gather_bound, (kind, n)
-        elif n > 1:
+            continue
+        if n > 1:
             assert n <= bound, (kind, n)
         if kind == "reduce-scatter":
             if mode == "sketch":
@@ -198,6 +212,22 @@ def test_collectives_are_shard_or_table_sized(mode, extra):
     assert any(k == "reduce-scatter" for k, _ in colls), colls
     if cfg.needs_client_velocities or cfg.needs_client_errors:
         assert any(k == "all-to-all" for k, _ in colls), colls
+
+
+def test_client_stats_gather_is_the_only_oversized_one():
+    """Without the client-stats summary no all-gather exceeds the weight
+    vector: the (K, W) stats matrix is the one exception the bound above
+    admits, not a cover for a d-scaled gather."""
+    cfg = make_cfg(mode="uncompressed", track_bytes=False,
+                   client_stats=False)
+    params = {"w": jnp.asarray(
+        np.random.RandomState(0).randn(6, 3), jnp.float32)}
+    mesh = make_mesh((8,), ("clients",))
+    rt = FedRuntime(cfg, params, quad_loss, num_clients=16, mesh=mesh)
+    batch, mask, client_ids = make_batch(1)
+    colls = _collective_shapes(rt, rt.init_state(), batch, mask, client_ids)
+    gathers = [n for kind, n in colls if kind == "all-gather"]
+    assert gathers and max(gathers) <= rt.d_pad, colls
 
 
 @pytest.mark.parametrize("mode,extra", [

@@ -24,7 +24,7 @@ from commefficient_tpu.ops.topk import (local_topk_candidates,
                                         merge_topk_candidates,
                                         topk_with_idx)
 from commefficient_tpu.parallel import make_mesh
-from commefficient_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _sketches(d, c=64, r=3):
@@ -238,7 +238,23 @@ def _sketch_cfg(**kw):
     return FedConfig(**base)
 
 
-def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None):
+# Sharded vs replicated weight tolerance after a few rounds — the dryrun
+# gate's committed contract (__graft_entry__._sharded_server_gate). The
+# two tails trace the same ops, but they are two XLA programs, and the
+# CPU backend under jax 0.9.0 contracts ``agg + rho * Vvel`` into a fused
+# multiply-add per loop, not per program: measured at this geometry, the
+# sharded tail fuses all 192 table cells and the replicated tail fuses
+# rows 0-1 and rounds the product first in row 2. No reduction reorders
+# (round-1 tables and the rho=0 aggregates are bit-equal); from round 2
+# the tables differ by <= 5 ulp in ~10 cells and the weights by <= 2 ulp
+# (7.5e-9). A top-k selection flip, the failure this gate exists for,
+# moves a weight by lr * |estimate| ~ 1e-2, four orders above atol.
+SHARDED_W_RTOL, SHARDED_W_ATOL = 1e-4, 1e-6
+
+
+def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None, w_first=None):
+    """``w_first``, when a list, receives the flat weights after round 1
+    (momentum is still zero there, so nothing can contract)."""
     params, loss_fn, batch_for = _params_and_loss()
     mesh = make_mesh((8,), ("clients",))
     rt = FedRuntime(cfg, params, loss_fn, num_clients=cfg.num_clients,
@@ -251,6 +267,8 @@ def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None):
     for g in range(1, n_rounds + 1):
         st, m = obj.round(st, ids, batch_for(8, 4, g), mask, lr)
         losses.append(np.asarray(m["results"][0]))
+        if g == 1 and w_first is not None:
+            w_first.append(np.asarray(rt.flat_weights(st)))
     return rt, np.stack(losses), np.asarray(rt.flat_weights(st))
 
 
@@ -262,24 +280,31 @@ def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None):
 ])
 def test_sharded_round_matches_replicated(variant):
     """The tentpole parity gate at test granularity: a sharded-server
-    sketch round must train identically to the replicated tail on this
-    backend (the merge is order-stable and the scattered reduce sums in
-    device order, so the rounds are BITWISE equal here; on other
-    toolchains the committed contract is the dryrun's tolerance gate)."""
-    rt_s, losses_s, w_s = _run_rounds(_sketch_cfg(**variant))
+    sketch round must train like the replicated tail. The merge is
+    order-stable and the scattered reduce sums in device order, so round
+    1 (zero momentum) is BITWISE equal — selection, decode and reduce
+    order all pinned; later rounds hold to SHARDED_W_*TOL (see there for
+    what differs and why)."""
+    w1_s, w1_r = [], []
+    rt_s, losses_s, w_s = _run_rounds(_sketch_cfg(**variant), w_first=w1_s)
     assert rt_s._sharded_server, variant
     rt_r, losses_r, w_r = _run_rounds(
-        _sketch_cfg(sketch_sharded_server="off", **variant))
+        _sketch_cfg(sketch_sharded_server="off", **variant), w_first=w1_r)
     assert not rt_r._sharded_server
     assert np.all(np.isfinite(losses_s)), variant
-    assert (losses_s == losses_r).all(), (variant, losses_s, losses_r)
-    assert (w_s == w_r).all(), variant
+    assert (w1_s[0] == w1_r[0]).all(), variant
+    # losses through round 2 are functions of the round-1 weights
+    assert (losses_s[:2] == losses_r[:2]).all(), (variant, losses_s,
+                                                  losses_r)
+    np.testing.assert_allclose(losses_s, losses_r, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(w_s, w_r, rtol=SHARDED_W_RTOL,
+                               atol=SHARDED_W_ATOL)
 
 
 def test_sharded_round_per_param_lr_vector():
     """The per-parameter LR vector path (Fixup groups): the sharded tail
     multiplies d_pad-length shards, the replicated tail a true-d slice
-    — same trained weights."""
+    — same trained weights (round 1 bitwise, then SHARDED_W_*TOL)."""
     params, loss_fn, batch_for = _params_and_loss()
     mesh = make_mesh((8,), ("clients",))
     d = 24 * 10
@@ -292,10 +317,14 @@ def test_sharded_round_per_param_lr_vector():
         st = rt.init_state()
         ids = jnp.arange(8, dtype=jnp.int32)
         mask = jnp.ones((8, 4), bool)
+        ws = []
         for g in range(1, 4):
             st, m = rt.round(st, ids, batch_for(8, 4, g), mask, lr_vec)
-        outs[ss] = np.asarray(rt.flat_weights(st))
-    assert (outs["auto"] == outs["off"]).all()
+            ws.append(np.asarray(rt.flat_weights(st)))
+        outs[ss] = ws
+    assert (outs["auto"][0] == outs["off"][0]).all()
+    np.testing.assert_allclose(outs["auto"][-1], outs["off"][-1],
+                               rtol=SHARDED_W_RTOL, atol=SHARDED_W_ATOL)
 
 
 def test_decode_overlap_composes_with_sharded_server():

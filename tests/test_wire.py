@@ -393,7 +393,7 @@ def test_int8_mesh_reduce_matches_numpy_reference(devices):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from commefficient_tpu.ops.wire import REDUCE_SALT, int8_reduce_scatter
-    from commefficient_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     n, r, c, blk = 8, 3, 512, 64
     mesh = Mesh(np.array(devices[:8]), ("clients",))
