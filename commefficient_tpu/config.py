@@ -979,13 +979,17 @@ def enable_compilation_cache_dir(cache_dir: str) -> Optional[str]:
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and this
     function sets nothing — ``cache_dir`` is ignored, whatever it says
     (a throw-away machine may be handed a cache that outlives it, and
-    only its operator knows where). Where the variable is unset,
+    only its operator knows where). It does make that directory if it is
+    not there yet: JAX does not, every write to it then fails with a
+    warning, and each run compiles everything again. Where the variable
+    is unset,
     ``cache_dir`` is used: by default the fixed
     in-checkout ``DEFAULT_COMPILATION_CACHE_DIR``; ``""`` disables. A
     directory that cannot be created raises — a run that silently
     recompiles for minutes each start is a fault, not a fallback."""
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
+        os.makedirs(env_dir, exist_ok=True)
         return env_dir
     if not cache_dir:
         return None

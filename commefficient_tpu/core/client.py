@@ -29,6 +29,7 @@ from jax import lax
 
 from commefficient_tpu.config import FedConfig
 from commefficient_tpu.ops import clip_by_l2_norm, topk
+from commefficient_tpu.telemetry.profiling import phase
 
 
 class ClientOut(NamedTuple):
@@ -201,39 +202,40 @@ def encode_grad_tree(cs, table, gtree, scale=None, token=None,
     Returns ``table + encode(scale * ravel(gtree))`` up to fp addition
     order (sketch linearity; pinned by tests/test_fused_encode.py).
     """
-    leaves = jax.tree_util.tree_leaves(gtree)
-    if getattr(cs, "_use_pallas_encode", lambda: False)():
-        flat = jnp.concatenate([l.reshape(-1) for l in leaves])
-        return cs.encode_accum(table, flat, 0, scale=scale, token=token)
-    if max_chunk <= 0:
-        max_chunk = _encode_chunk_max(int(getattr(cs, "d", 0)))
-    chunks = []          # (static start, [flat leaf pieces])
-    cur, cur_n, cur_start, off = [], 0, 0, 0
-    for leaf in leaves:
-        flat = leaf.reshape(-1)
-        n, pos = int(flat.size), 0
-        while n - pos > 0:
-            if not cur:
-                cur_start = off + pos
-            take = min(n - pos, max_chunk - cur_n)
-            cur.append(flat[pos:pos + take]
-                       if (pos or take < n) else flat)
-            cur_n += take
-            pos += take
-            if cur_n >= max_chunk:
+    with phase("fed_sketch_encode"):
+        leaves = jax.tree_util.tree_leaves(gtree)
+        if getattr(cs, "_use_pallas_encode", lambda: False)():
+            flat = jnp.concatenate([l.reshape(-1) for l in leaves])
+            return cs.encode_accum(table, flat, 0, scale=scale, token=token)
+        if max_chunk <= 0:
+            max_chunk = _encode_chunk_max(int(getattr(cs, "d", 0)))
+        chunks = []          # (static start, [flat leaf pieces])
+        cur, cur_n, cur_start, off = [], 0, 0, 0
+        for leaf in leaves:
+            flat = leaf.reshape(-1)
+            n, pos = int(flat.size), 0
+            while n - pos > 0:
+                if not cur:
+                    cur_start = off + pos
+                take = min(n - pos, max_chunk - cur_n)
+                cur.append(flat[pos:pos + take]
+                           if (pos or take < n) else flat)
+                cur_n += take
+                pos += take
+                if cur_n >= max_chunk:
+                    chunks.append((cur_start, cur))
+                    cur, cur_n = [], 0
+            off += n
+            if cur_n >= min_chunk:
                 chunks.append((cur_start, cur))
                 cur, cur_n = [], 0
-        off += n
-        if cur_n >= min_chunk:
+        if cur:
             chunks.append((cur_start, cur))
-            cur, cur_n = [], 0
-    if cur:
-        chunks.append((cur_start, cur))
-    for start, pieces in reversed(chunks):
-        vals = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
-        table = cs.encode_accum(table, vals, start, scale=scale,
-                                token=token)
-    return table
+        for start, pieces in reversed(chunks):
+            vals = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+            table = cs.encode_accum(table, vals, start, scale=scale,
+                                    token=token)
+        return table
 
 
 def fused_encode_blockers(cfg: FedConfig, signals: bool = False) -> list:
@@ -418,10 +420,11 @@ def make_forward_grad(
         # eligible) instead of forcing a dense g back into existence.
         if cfg.weight_decay != 0:
             if fused_encode:
-                g = cs.encode_accum(
-                    g, params_vec, 0,
-                    scale=cfg.weight_decay / cfg.num_workers,
-                    token=loss_sum)
+                with phase("fed_sketch_encode"):
+                    g = cs.encode_accum(
+                        g, params_vec, 0,
+                        scale=cfg.weight_decay / cfg.num_workers,
+                        token=loss_sum)
             else:
                 g = g + (cfg.weight_decay / cfg.num_workers) * params_vec
         stats = None
@@ -483,7 +486,8 @@ def make_forward_grad(
                 g = cs.clip(g, cfg.max_grad_norm)
         elif cfg.mode == "sketch" and not defer_encode:
             assert cs is not None, "sketch mode requires the runtime's sketch"
-            table = cs.encode(g)
+            with phase("fed_sketch_encode"):
+                table = cs.encode(g)
             if cfg.max_grad_norm is not None and not cfg.sketch_dense_clip:
                 # reference semantics: clip the TABLE (fed_worker.py:318)
                 table = cs.clip(table, cfg.max_grad_norm)
@@ -600,8 +604,9 @@ def make_fused_grad(
             wd_scale = ((cfg.weight_decay / cfg.num_workers)
                         * n_per_client.sum())
             if fused_encode:
-                g = cs.encode_accum(g, params_vec, 0, scale=wd_scale,
-                                    token=sums[0].sum())
+                with phase("fed_sketch_encode"):
+                    g = cs.encode_accum(g, params_vec, 0, scale=wd_scale,
+                                        token=sums[0].sum())
             else:
                 g = g + wd_scale * params_vec
         denom = jnp.maximum(n_per_client, 1.0)

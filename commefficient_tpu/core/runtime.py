@@ -45,6 +45,7 @@ from commefficient_tpu.ops.sketch import make_sketch_impl
 from commefficient_tpu.telemetry import tracing
 from commefficient_tpu.telemetry.clients import (CLIENT_GRAD_KEYS,
                                                  summarize_per_client)
+from commefficient_tpu.telemetry.profiling import phase
 from commefficient_tpu.telemetry.signals import round_signals
 
 
@@ -966,6 +967,35 @@ class FedRuntime:
             body, jnp.zeros(thresholds.shape, jnp.int32), blocks)
         return counts
 
+    def _download_ledger(self, state: FedState, client_ids: jax.Array):
+        """The dispatch-time half of the byte ledger (``track_bytes``),
+        shared by the sync round and the cohort: what each of the
+        round's clients downloads (coordinates changed since its last
+        round) and uploads, per slot and scattered over the client
+        universe, and the clients' new last-round marks. Returns
+        ``(download_bytes, upload_bytes, down_slot, up_slot,
+        client_last_round)``."""
+        with phase("fed_byte_ledger"):
+            thresholds = state.client_last_round[client_ids]
+            counts = self._download_coord_counts(state.coord_last_update,
+                                                 thresholds)
+            # per-SLOT byte vectors kept alive for the client_stats
+            # quantiles (telemetry/clients.py) — the scatters below are
+            # the same data keyed by client id over the whole universe
+            down_slot = 4.0 * counts.astype(jnp.float32)
+            # exact wire-dtype payload (cfg.upload_wire_bytes): the f32
+            # wire keeps the pre-wire 4*upload_floats constant
+            up_slot = jnp.full(client_ids.shape, self._upload_bytes,
+                               jnp.float32)
+            download_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
+                client_ids].set(down_slot)
+            upload_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
+                client_ids].set(up_slot)
+            client_last_round = state.client_last_round.at[client_ids].set(
+                state.step)
+        return download_bytes, upload_bytes, down_slot, up_slot, \
+            client_last_round
+
     # ------------------------------------------------------- server dispatch
 
     def _apply_server_update(self, state: FedState, agg: jax.Array,
@@ -1062,10 +1092,11 @@ class FedRuntime:
             def blk8(part, step):
                 return self._int8_reduce_scatter(part[0], step)
 
-            return shard_map(blk8, mesh=self.mesh,
-                             in_specs=(P(ax, None, None), P()),
-                             out_specs=P(None, ax),
-                             check_vma=False)(partials, step)
+            with phase("fed_table_reduce"):
+                return shard_map(blk8, mesh=self.mesh,
+                                 in_specs=(P(ax, None, None), P()),
+                                 out_specs=P(None, ax),
+                                 check_vma=False)(partials, step)
 
         def blk(part):
             p = part[0]
@@ -1076,10 +1107,114 @@ class FedRuntime:
             return lax.psum_scatter(p, ax, scatter_dimension=1,
                                     tiled=True)
 
-        return shard_map(blk, mesh=self.mesh,
-                         in_specs=P(ax, None, None),
-                         out_specs=P(None, ax),
-                         check_vma=False)(partials)
+        with phase("fed_table_reduce"):
+            return shard_map(blk, mesh=self.mesh,
+                             in_specs=P(ax, None, None),
+                             out_specs=P(None, ax),
+                             check_vma=False)(partials)
+
+    def _mesh_aggregate(self, agg: jax.Array, n_total: jax.Array, step,
+                        defer_reduce: bool = False):
+        """The cross-chip aggregation of a client block (called INSIDE
+        its shard_map): the client sum over every mesh axis and the
+        datum count over the clients axis — ONE implementation for the
+        sync round and the async/split cohort (the ``_transmit_tail``
+        discipline: the bit-identity contracts ride on both paths
+        tracing exactly these ops). ``defer_reduce`` is the cohort's
+        --decode_overlap + sharded-server case. Returns ``(agg,
+        n_total)``."""
+        cfg = self.cfg
+        td = self._table_dtype
+        with phase("fed_table_reduce"):
+            # the aggregation spans every mesh axis: clients sum across
+            # the clients axis, and (in seq mode) each client's partial
+            # per-shard gradients sum across the seq axis — one fused
+            # collective either way
+            all_axes = tuple(self.mesh.axis_names)
+            if agg.ndim == 1:
+                # dense modes: reduce_scatter the client sum so each
+                # device receives only its d_pad/n shard of the summed
+                # gradient — the server update then runs fully sharded.
+                # (The ICI analogue of encode-before-reduce for dense
+                # payloads; reference reduce: fed_aggregator.py:326-332)
+                agg = lax.psum_scatter(
+                    jnp.pad(agg, (0, self.d_pad - cfg.grad_size)),
+                    all_axes, scatter_dimension=0, tiled=True)
+            elif defer_reduce:
+                # --decode_overlap + sharded server: the table reduce
+                # MOVES into the decode executable — the cohort ends at
+                # this device's LOCAL partial table (stacked on the
+                # clients axis, zero wire traffic), so the metrics sync
+                # completes without waiting any ICI collective and the
+                # reduce-scatter runs under round t+1's staging (see
+                # _reduce_partials; the bf16 wire rounding travels WITH
+                # the collective)
+                agg = agg[None]
+            elif self._sharded_server:
+                # sharded server tail: reduce-SCATTER over table
+                # columns replaces the replicated table psum (the
+                # dense-mode analogue above) — each device receives
+                # only its c/n column shard of the summed table, the
+                # (r, c) replicated result never exists, and the
+                # momentum/EF tail runs on the shards
+                # (core/server.sharded_sketch_server_update). The
+                # bfloat16 wire covers this collective exactly like
+                # the psum it replaces (the barrier pins the payload
+                # dtype against XLA hoisting the f32 convert back
+                # through the reduce); the int8 wire replaces the
+                # reduce itself with the quantized all_to_all +
+                # shard-local dequantize-accumulate.
+                if self._int8_wire:
+                    agg = self._int8_reduce_scatter(agg, step)
+                elif td != jnp.float32:
+                    agg = lax.optimization_barrier(lax.psum_scatter(
+                        agg.astype(td), self._axis,
+                        scatter_dimension=1, tiled=True))
+                    agg = agg.astype(jnp.float32)
+                else:
+                    agg = lax.psum_scatter(agg, self._axis,
+                                           scatter_dimension=1,
+                                           tiled=True)
+            else:
+                # sketch tables are already the compressed payload: one
+                # table-sized psum (analogue of encode-before-NCCL);
+                # --sketch_dtype bfloat16 halves this payload — the
+                # multichip bandwidth lever (accumulation inside the
+                # collective is then bf16 too; measured impact in
+                # tests/test_parallel.py + README)
+                if td != jnp.float32 and agg.ndim == 2:
+                    # the barrier pins the collective's payload dtype:
+                    # without it XLA hoists the f32 convert back
+                    # through the all-reduce and the wire stays f32
+                    agg = lax.optimization_barrier(
+                        lax.psum(agg.astype(td), all_axes))
+                    agg = agg.astype(jnp.float32)
+                else:
+                    agg = lax.psum(agg, all_axes)
+            if self._seq_axis is not None:
+                # shard_map autodiff with vma checking off transposes
+                # psum to psum, so each seq shard's gradient comes out
+                # scaled (every differentiable path in the seq-sharded
+                # loss crosses exactly ONE psum — the LM token mean or
+                # the MC logit reduction; verified uniform by
+                # tests/test_seqparallel.py's round equivalence). The
+                # cross-shard sum above therefore over-counts by a
+                # factor that DEPENDS ON THE JAX VERSION's transpose
+                # rule (as of jax 0.9 with check_vma=False it is
+                # seq_shards; with vma checking on it would be 1).
+                # Rather than hard-code a jax internal, the factor is
+                # MEASURED at runtime init by differentiating a known
+                # seq-sharded function on this mesh under the same
+                # check_vma setting (_probe_seq_grad_scale) — a jax
+                # upgrade that changes the transpose changes the probe
+                # identically. tests/test_seqparallel.py::
+                # test_seq_sharded_round_matches_dense stays as the
+                # end-to-end guard.
+                agg = agg / self._seq_grad_scale
+            # datum counts are identical on every seq shard (the mask
+            # replicates over seq) — sum over clients only
+            n_total = lax.psum(n_total, self._axis)
+        return agg, n_total
 
     # ------------------------------------------------------------- round step
 
@@ -1105,46 +1240,32 @@ class FedRuntime:
         down_slot = up_slot = None
         client_last_round = state.client_last_round
         if cfg.track_bytes:
-            thresholds = state.client_last_round[client_ids]
-            counts = self._download_coord_counts(state.coord_last_update,
-                                                 thresholds)
-            # per-SLOT byte vectors kept alive for the client_stats
-            # quantiles (telemetry/clients.py) — the scatter below is the
-            # same data keyed by client id over the whole universe
-            down_slot = 4.0 * counts.astype(jnp.float32)
-            # exact wire-dtype payload (cfg.upload_wire_bytes): the f32
-            # wire keeps the pre-wire 4*upload_floats constant
-            up_slot = jnp.full((num_workers,), self._upload_bytes,
-                               jnp.float32)
-            download_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
-                client_ids].set(down_slot)
-            upload_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
-                client_ids].set(up_slot)
-            client_last_round = state.client_last_round.at[client_ids].set(
-                state.step)
+            download_bytes, upload_bytes, down_slot, up_slot, \
+                client_last_round = self._download_ledger(state, client_ids)
 
         # ---- per-client weights (download path)
         client_weights = state.client_weights
-        if cfg.do_topk_down:
-            stale = state.client_weights[client_ids]
-            ps_true = state.ps_weights[: cfg.grad_size]
-            used_weights = jax.vmap(
-                lambda w: client_lib.topk_down_weights(
-                    cfg, ps_true, w))(stale)
-            client_weights = state.client_weights.at[client_ids].set(
-                used_weights)
-            params_axis = 0
-        else:
-            # all clients read the current PS weights
-            # (reference fed_worker.py:159)
-            used_weights = state.ps_weights
-            params_axis = None
+        with phase("fed_client_step"):
+            if cfg.do_topk_down:
+                stale = state.client_weights[client_ids]
+                ps_true = state.ps_weights[: cfg.grad_size]
+                used_weights = jax.vmap(
+                    lambda w: client_lib.topk_down_weights(
+                        cfg, ps_true, w))(stale)
+                client_weights = state.client_weights.at[client_ids].set(
+                    used_weights)
+                params_axis = 0
+            else:
+                # all clients read the current PS weights
+                # (reference fed_worker.py:159)
+                used_weights = state.ps_weights
+                params_axis = None
 
-        # ---- per-client persistent rows
-        vel_rows = (state.client_velocities[client_ids]
-                    if state.client_velocities is not None else None)
-        err_rows = (state.client_errors[client_ids]
-                    if state.client_errors is not None else None)
+            # ---- per-client persistent rows
+            vel_rows = (state.client_velocities[client_ids]
+                        if state.client_velocities is not None else None)
+            err_rows = (state.client_errors[client_ids]
+                        if state.client_errors is not None else None)
 
         # ---- client compute + aggregation
         # (reference fed_worker.py:131,138 + fed_aggregator.py:329-332)
@@ -1172,8 +1293,9 @@ class FedRuntime:
                 # column slice of all round rows; ONE all_to_all turns it
                 # into the (W/n, d_row_pad) full rows of its local clients
                 def rows_to_compute(x):
-                    full = lax.all_to_all(x, self._axis, split_axis=0,
-                                          concat_axis=1, tiled=True)
+                    with phase("fed_table_reduce"):
+                        full = lax.all_to_all(x, self._axis, split_axis=0,
+                                              concat_axis=1, tiled=True)
                     return full[:, : cfg.grad_size]
                 if vel_rows is not None:
                     vel_rows = rows_to_compute(vel_rows)
@@ -1255,7 +1377,8 @@ class FedRuntime:
                     # already exists here, this just extends its lifetime
                     # to the round step's tail)
                     sig_dense = agg
-                agg = cs.encode(agg)
+                with phase("fed_sketch_encode"):
+                    agg = cs.encode(agg)
             if wire and self._axis is None and agg.ndim == 2:
                 agg = agg.astype(td).astype(jnp.float32)
             elif (self._int8_wire and self._axis is None
@@ -1270,84 +1393,7 @@ class FedRuntime:
                                       salt=0)
             n_total = n_valid.sum()
             if self._axis is not None:
-                # the aggregation spans every mesh axis: clients sum across
-                # the clients axis, and (in seq mode) each client's partial
-                # per-shard gradients sum across the seq axis — one fused
-                # collective either way
-                all_axes = tuple(self.mesh.axis_names)
-                if agg.ndim == 1:
-                    # dense modes: reduce_scatter the client sum so each
-                    # device receives only its d_pad/n shard of the summed
-                    # gradient — the server update then runs fully sharded.
-                    # (The ICI analogue of encode-before-reduce for dense
-                    # payloads; reference reduce: fed_aggregator.py:326-332)
-                    agg = lax.psum_scatter(
-                        jnp.pad(agg, (0, self.d_pad - cfg.grad_size)),
-                        all_axes, scatter_dimension=0, tiled=True)
-                elif self._sharded_server:
-                    # sharded server tail: reduce-SCATTER over table
-                    # columns replaces the replicated table psum (the
-                    # dense-mode analogue above) — each device receives
-                    # only its c/n column shard of the summed table, the
-                    # (r, c) replicated result never exists, and the
-                    # momentum/EF tail runs on the shards
-                    # (core/server.sharded_sketch_server_update). The
-                    # bfloat16 wire covers this collective exactly like
-                    # the psum it replaces (the barrier pins the payload
-                    # dtype against XLA hoisting the f32 convert back
-                    # through the reduce); the int8 wire replaces the
-                    # reduce itself with the quantized all_to_all +
-                    # shard-local dequantize-accumulate.
-                    if self._int8_wire:
-                        agg = self._int8_reduce_scatter(agg, step)
-                    elif td != jnp.float32:
-                        agg = lax.optimization_barrier(lax.psum_scatter(
-                            agg.astype(td), self._axis,
-                            scatter_dimension=1, tiled=True))
-                        agg = agg.astype(jnp.float32)
-                    else:
-                        agg = lax.psum_scatter(agg, self._axis,
-                                               scatter_dimension=1,
-                                               tiled=True)
-                else:
-                    # sketch tables are already the compressed payload: one
-                    # table-sized psum (analogue of encode-before-NCCL);
-                    # --sketch_dtype bfloat16 halves this payload — the
-                    # multichip bandwidth lever (accumulation inside the
-                    # collective is then bf16 too; measured impact in
-                    # tests/test_parallel.py + README)
-                    if td != jnp.float32 and agg.ndim == 2:
-                        # the barrier pins the collective's payload dtype:
-                        # without it XLA hoists the f32 convert back
-                        # through the all-reduce and the wire stays f32
-                        agg = lax.optimization_barrier(
-                            lax.psum(agg.astype(td), all_axes))
-                        agg = agg.astype(jnp.float32)
-                    else:
-                        agg = lax.psum(agg, all_axes)
-                if self._seq_axis is not None:
-                    # shard_map autodiff with vma checking off transposes
-                    # psum to psum, so each seq shard's gradient comes out
-                    # scaled (every differentiable path in the seq-sharded
-                    # loss crosses exactly ONE psum — the LM token mean or
-                    # the MC logit reduction; verified uniform by
-                    # tests/test_seqparallel.py's round equivalence). The
-                    # cross-shard sum above therefore over-counts by a
-                    # factor that DEPENDS ON THE JAX VERSION's transpose
-                    # rule (as of jax 0.9 with check_vma=False it is
-                    # seq_shards; with vma checking on it would be 1).
-                    # Rather than hard-code a jax internal, the factor is
-                    # MEASURED at runtime init by differentiating a known
-                    # seq-sharded function on this mesh under the same
-                    # check_vma setting (_probe_seq_grad_scale) — a jax
-                    # upgrade that changes the transpose changes the probe
-                    # identically. tests/test_seqparallel.py::
-                    # test_seq_sharded_round_matches_dense stays as the
-                    # end-to-end guard.
-                    agg = agg / self._seq_grad_scale
-                # datum counts are identical on every seq shard (the mask
-                # replicates over seq) — sum over clients only
-                n_total = lax.psum(n_total, self._axis)
+                agg, n_total = self._mesh_aggregate(agg, n_total, step)
             vel_out, err_out = out.velocity, out.error
             if client_finite is not None:
                 # a struck client's persistent local rows must not absorb
@@ -1367,8 +1413,9 @@ class FedRuntime:
                 def rows_to_home(x):
                     xp = jnp.pad(
                         x, ((0, 0), (0, self.d_row_pad - cfg.grad_size)))
-                    return lax.all_to_all(xp, self._axis, split_axis=1,
-                                          concat_axis=0, tiled=True)
+                    with phase("fed_table_reduce"):
+                        return lax.all_to_all(xp, self._axis, split_axis=1,
+                                              concat_axis=0, tiled=True)
                 if vel_out is not None:
                     vel_out = rows_to_home(vel_out)
                 if err_out is not None:
@@ -1442,25 +1489,29 @@ class FedRuntime:
                                      check_vma=False)
 
         step_arg = state.step if self._int8_wire else None
-        agg, n_total, vel_new, err_new, results, n_valid, sig_dense, \
-            client_grad_stats, client_finite, defense_stats, cur_med = \
-            client_block(used_weights, batch, mask, vel_rows, err_rows,
-                         client_rngs, lr, adv_slot, ref_thresh, step_arg,
-                         cs)
+        with phase("fed_client_step"):
+            agg, n_total, vel_new, err_new, results, n_valid, sig_dense, \
+                client_grad_stats, client_finite, defense_stats, cur_med = \
+                client_block(used_weights, batch, mask, vel_rows, err_rows,
+                             client_rngs, lr, adv_slot, ref_thresh, step_arg,
+                             cs)
         out = client_lib.ClientOut(None, vel_new, err_new, results, n_valid,
                                    client_grad_stats)
-        total = jnp.maximum(n_total, 1.0)
-        agg = agg / total
+        with phase("fed_server_tail"):
+            total = jnp.maximum(n_total, 1.0)
+            agg = agg / total
         if sig_dense is not None:
             # same normalization as agg: the signals compare like with like
-            sig_dense = sig_dense / total
+            with phase("fed_signals"):
+                sig_dense = sig_dense / total
 
-        # ---- server update (mode + topology dispatch: the sharded
-        # sketch tail on an eligible mesh, core/server.py's replicated
-        # rules otherwise — ONE implementation shared with the split/
-        # async server tails, see _apply_server_update)
-        update, Vvel, Verr, sup_mask = self._apply_server_update(
-            state, agg, lr, server_rng, cs)
+        with phase("fed_server_tail"):
+            # ---- server update (mode + topology dispatch: the sharded
+            # sketch tail on an eligible mesh, core/server.py's replicated
+            # rules otherwise — ONE implementation shared with the split/
+            # async server tails, see _apply_server_update)
+            update, Vvel, Verr, sup_mask = self._apply_server_update(
+                state, agg, lr, server_rng, cs)
 
         # ---- compression-signal health (telemetry/signals.py): on-device
         # scalars fetched asynchronously alongside the loss — computed
@@ -1468,12 +1519,13 @@ class FedRuntime:
         signals = None
         sig_vel_new, sig_err_new = state.sig_Vvelocity, state.sig_Verror
         if self._signals:
-            signals, sig_vel_new, sig_err_new = round_signals(
-                cfg, agg=agg, update=update,
-                Vvel_prev=state.Vvelocity, Verr_prev=state.Verror,
-                Vvel_new=Vvel, Verr_new=Verr, cs=cs,
-                dense_agg=sig_dense,
-                sig_vel=state.sig_Vvelocity, sig_err=state.sig_Verror)
+            with phase("fed_signals"):
+                signals, sig_vel_new, sig_err_new = round_signals(
+                    cfg, agg=agg, update=update,
+                    Vvel_prev=state.Vvelocity, Verr_prev=state.Verror,
+                    Vvel_new=Vvel, Verr_new=Verr, cs=cs,
+                    dense_agg=sig_dense,
+                    sig_vel=state.sig_Vvelocity, sig_err=state.sig_Verror)
 
         # ---- layer-wise attribution (telemetry/layer_signals.py):
         # per-group reductions of the same pre-padding quantities the
@@ -1493,123 +1545,130 @@ class FedRuntime:
             err_dense = (Verr if dense
                          else sig_err_new if sig_err_new is not None
                          else None)
-            err_pre = None
-            if cfg.signals_exact:
-                # the SAME dense pre-feedback error round_signals'
-                # topk_overlap selects against (signals.py documents
-                # the two availability paths) — recomputed here from
-                # the pre-update state so the modules stay decoupled
-                rho = cfg.virtual_momentum
-                if state.sig_Verror is not None and sig_dense is not None:
-                    err_pre = (state.sig_Verror + sig_dense
-                               + rho * state.sig_Vvelocity)
-                elif cfg.mode == "true_topk" or (cfg.mode == "sketch"
-                                                 and dense):
-                    err_pre = (state.Verror + agg
-                               + rho * state.Vvelocity)[: cfg.grad_size]
-            layer_signals = layer_group_signals(
-                cfg, gid=gid, n_groups=self.group_spec.n_groups,
-                update=update, grad_dense=grad_dense,
-                err_dense=err_dense, err_pre=err_pre)
+            with phase("fed_layer_signals"):
+                err_pre = None
+                if cfg.signals_exact:
+                    # the SAME dense pre-feedback error round_signals'
+                    # topk_overlap selects against (signals.py documents
+                    # the two availability paths) — recomputed here from
+                    # the pre-update state so the modules stay decoupled
+                    rho = cfg.virtual_momentum
+                    if state.sig_Verror is not None and sig_dense is not None:
+                        err_pre = (state.sig_Verror + sig_dense
+                                   + rho * state.sig_Vvelocity)
+                    elif cfg.mode == "true_topk" or (cfg.mode == "sketch"
+                                                     and dense):
+                        err_pre = (state.Verror + agg
+                                   + rho * state.Vvelocity)[: cfg.grad_size]
+                layer_signals = layer_group_signals(
+                    cfg, gid=gid, n_groups=self.group_spec.n_groups,
+                    update=update, grad_dense=grad_dense,
+                    err_dense=err_dense, err_pre=err_pre)
 
         # ---- per-client population stats (telemetry/clients.py): quantile
         # summaries along the client axis, riding the same async metrics
         # fetch as the loss — per-client vectors never leave the device
         client_stats = None
         if self._client_stats:
-            per_client = {"loss": out.results[0]}
-            if out.stats is not None:
-                per_client.update(out.stats)
-            else:
-                # fused path: no per-client gradient exists (see __init__
-                # _client_grad_stats) — NaN quantiles, never fake zeros
-                nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
-                per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
-            if cfg.track_bytes:
-                per_client["upload_bytes"] = up_slot
-                per_client["download_bytes"] = down_slot
-            rep = None
-            if self.mesh is not None:
-                # one W-sized all-gather for the WHOLE summary: without
-                # the replication constraint every per-key quantile
-                # lowers to its own tiny collectives (launch-count
-                # pathology, see summarize_per_client)
-                rep_sh = NamedSharding(self.mesh, P())
+            with phase("fed_client_stats"):
+                per_client = {"loss": out.results[0]}
+                if out.stats is not None:
+                    per_client.update(out.stats)
+                else:
+                    # fused path: no per-client gradient exists (see __init__
+                    # _client_grad_stats) — NaN quantiles, never fake zeros
+                    nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
+                    per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
+                if cfg.track_bytes:
+                    per_client["upload_bytes"] = up_slot
+                    per_client["download_bytes"] = down_slot
+                rep = None
+                if self.mesh is not None:
+                    # one W-sized all-gather for the WHOLE summary: without
+                    # the replication constraint every per-key quantile
+                    # lowers to its own tiny collectives (launch-count
+                    # pathology, see summarize_per_client)
+                    rep_sh = NamedSharding(self.mesh, P())
 
-                def rep(x, _sh=rep_sh):
-                    return lax.with_sharding_constraint(x, _sh)
-            client_stats = summarize_per_client(per_client, out.n_valid,
-                                                replicate_fn=rep)
+                    def rep(x, _sh=rep_sh):
+                        return lax.with_sharding_constraint(x, _sh)
+                client_stats = summarize_per_client(per_client, out.n_valid,
+                                                    replicate_fn=rep)
 
-        if self.d_pad != cfg.grad_size:
-            if update.shape[0] == cfg.grad_size:
-                # sketch decode produces a true-d update; pad to the
-                # server's sharded length
-                update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
-            else:
-                # keep the padding coordinates exactly zero (server-side DP
-                # noise would otherwise drift them and pollute the
-                # changed-coordinate byte accounting)
-                update = jnp.where(
-                    jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
-        ps_weights = state.ps_weights - update
+        with phase("fed_server_tail"):
+            if self.d_pad != cfg.grad_size:
+                if update.shape[0] == cfg.grad_size:
+                    # sketch decode produces a true-d update; pad to the
+                    # server's sharded length
+                    update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
+                else:
+                    # keep the padding coordinates exactly zero (server-side
+                    # DP noise would otherwise drift them and pollute the
+                    # changed-coordinate byte accounting)
+                    update = jnp.where(
+                        jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
+            ps_weights = state.ps_weights - update
 
-        # ---- write back per-client rows
-        client_velocities = state.client_velocities
-        if out.velocity is not None and client_velocities is not None:
-            new_rows = out.velocity
-            if cfg.mode == "true_topk" and sup_mask is not None:
-                # momentum factor masking on participating clients' local
-                # velocities (intended behavior of fed_aggregator.py:528-533)
-                # — the server mask is in padded space; rows are at true d
-                # single-device, at d_row_pad in the mesh home layout
-                # (padding coords are identically 0 and where() keeps them 0)
-                sm = sup_mask[: cfg.grad_size]
-                if self._rows_cols:
-                    sm = jnp.pad(sm, (0, self.d_row_pad - cfg.grad_size))
-                new_rows = jnp.where(sm[None, :], 0.0, new_rows)
-            client_velocities = client_velocities.at[client_ids].set(new_rows)
-        client_errors = state.client_errors
-        if out.error is not None and client_errors is not None:
-            client_errors = client_errors.at[client_ids].set(out.error)
+            # ---- write back per-client rows
+            client_velocities = state.client_velocities
+            if out.velocity is not None and client_velocities is not None:
+                new_rows = out.velocity
+                if cfg.mode == "true_topk" and sup_mask is not None:
+                    # momentum factor masking on participating clients'
+                    # local velocities (intended behavior of
+                    # fed_aggregator.py:528-533) — the server mask is in
+                    # padded space; rows are at true d single-device, at
+                    # d_row_pad in the mesh home layout (padding coords are
+                    # identically 0 and where() keeps them 0)
+                    sm = sup_mask[: cfg.grad_size]
+                    if self._rows_cols:
+                        sm = jnp.pad(sm, (0, self.d_row_pad - cfg.grad_size))
+                    new_rows = jnp.where(sm[None, :], 0.0, new_rows)
+                client_velocities = client_velocities.at[client_ids].set(
+                    new_rows)
+            client_errors = state.client_errors
+            if out.error is not None and client_errors is not None:
+                client_errors = client_errors.at[client_ids].set(out.error)
 
         # ---- byte accounting: record which coordinates changed this round
         coord_last_update = state.coord_last_update
         if cfg.track_bytes:
-            coord_last_update = jnp.where(
-                update != 0, state.step, state.coord_last_update)
+            with phase("fed_byte_ledger"):
+                coord_last_update = jnp.where(
+                    update != 0, state.step, state.coord_last_update)
 
-        # device-side divergence detection: record the FIRST round where a
-        # client loss, the aggregated gradient, or the weight update went
-        # non-finite (fused isfinite+reduce; a NaN gradient does not always
-        # survive the top-k select into the update, and the reference's
-        # host check is on the loss, cv_train.py:222-224)
-        bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
-        if self._quarantine:
-            # per-client nonfinites were zeroed OUT of the aggregate in
-            # the client block (their losses too) — only a round with no
-            # finite DATA-CARRYING client left, or nonfinite SERVER
-            # state, still aborts. A nonfinite flag can only come from a
-            # live slot (benched/masked placeholders upload finite
-            # zeros), so "fully-nonfinite round" == some client went
-            # nonfinite AND no finite client with data remains
-            # (n_valid is post-zeroing: > 0 iff live AND finite)
-            bad = bad | ((~client_finite).any()
-                         & ~(out.n_valid > 0).any())
-        else:
-            bad = bad | ~jnp.isfinite(out.results[0]).all()
-        nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                              state.nan_round)
+        with phase("fed_server_tail"):
+            # device-side divergence detection: record the FIRST round
+            # where a client loss, the aggregated gradient, or the weight
+            # update went non-finite (fused isfinite+reduce; a NaN gradient
+            # does not always survive the top-k select into the update, and
+            # the reference's host check is on the loss, cv_train.py:222-224)
+            bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
+            if self._quarantine:
+                # per-client nonfinites were zeroed OUT of the aggregate in
+                # the client block (their losses too) — only a round with no
+                # finite DATA-CARRYING client left, or nonfinite SERVER
+                # state, still aborts. A nonfinite flag can only come from a
+                # live slot (benched/masked placeholders upload finite
+                # zeros), so "fully-nonfinite round" == some client went
+                # nonfinite AND no finite client with data remains
+                # (n_valid is post-zeroing: > 0 iff live AND finite)
+                bad = bad | ((~client_finite).any()
+                             & ~(out.n_valid > 0).any())
+            else:
+                bad = bad | ~jnp.isfinite(out.results[0]).all()
+            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
+                                  state.nan_round)
 
-        # normclip rolling reference: this round's median per-datum norm
-        # enters the ring AFTER the round used the PAST medians — the
-        # attack round cannot vouch for its own normality
-        defense_ref = state.defense_ref
-        if self._defense_ring:
-            defense_ref = state.defense_ref.at[
-                jnp.mod(state.step, cfg.defense_window)].set(cur_med)
+            # normclip rolling reference: this round's median per-datum norm
+            # enters the ring AFTER the round used the PAST medians — the
+            # attack round cannot vouch for its own normality
+            defense_ref = state.defense_ref
+            if self._defense_ring:
+                defense_ref = state.defense_ref.at[
+                    jnp.mod(state.step, cfg.defense_window)].set(cur_med)
 
-        defense = self._defense_scalars(defense_stats, client_finite)
+            defense = self._defense_scalars(defense_stats, client_finite)
 
         new_state = FedState(
             ps_weights=ps_weights,
@@ -1717,18 +1776,8 @@ class FedRuntime:
         down_slot = up_slot = None
         client_last_round = state.client_last_round
         if cfg.track_bytes:
-            thresholds = state.client_last_round[client_ids]
-            counts = self._download_coord_counts(state.coord_last_update,
-                                                 thresholds)
-            down_slot = 4.0 * counts.astype(jnp.float32)
-            up_slot = jnp.full((num_workers,), self._upload_bytes,
-                               jnp.float32)
-            download_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
-                client_ids].set(down_slot)
-            upload_bytes = jnp.zeros(self.num_clients, jnp.float32).at[
-                client_ids].set(up_slot)
-            client_last_round = state.client_last_round.at[client_ids].set(
-                state.step)
+            download_bytes, upload_bytes, down_slot, up_slot, \
+                client_last_round = self._download_ledger(state, client_ids)
 
         adv_slot = (self._adv_universe[client_ids]
                     if self._adversary else None)
@@ -1775,7 +1824,8 @@ class FedRuntime:
                 agg = t_agg
             if (self._defer_encode and not self._dense_preimage
                     and not self._fused_encode):
-                agg = cs.encode(agg)
+                with phase("fed_sketch_encode"):
+                    agg = cs.encode(agg)
             if wire and self._axis is None and agg.ndim == 2:
                 agg = agg.astype(td).astype(jnp.float32)
             elif (self._int8_wire and self._axis is None
@@ -1788,46 +1838,9 @@ class FedRuntime:
                                       salt=0)
             n_total = n_valid.sum()
             if self._axis is not None:
-                all_axes = tuple(self.mesh.axis_names)
-                if agg.ndim == 1:
-                    agg = lax.psum_scatter(
-                        jnp.pad(agg, (0, self.d_pad - cfg.grad_size)),
-                        all_axes, scatter_dimension=0, tiled=True)
-                elif self._reduce_in_decode:
-                    # --decode_overlap + sharded server: the table
-                    # reduce MOVES into the decode executable — the
-                    # cohort ends at this device's LOCAL partial table
-                    # (stacked on the clients axis, zero wire traffic),
-                    # so the metrics sync completes without waiting any
-                    # ICI collective and the reduce-scatter runs under
-                    # round t+1's staging (see _reduce_partials; the
-                    # bf16 wire rounding travels WITH the collective)
-                    agg = agg[None]
-                elif self._sharded_server:
-                    # same reduce-scattered table collective as the
-                    # sync round's client block (bf16 barrier-pinned;
-                    # int8 = the quantized all_to_all reduce)
-                    if self._int8_wire:
-                        agg = self._int8_reduce_scatter(agg, step)
-                    elif td != jnp.float32:
-                        agg = lax.optimization_barrier(lax.psum_scatter(
-                            agg.astype(td), self._axis,
-                            scatter_dimension=1, tiled=True))
-                        agg = agg.astype(jnp.float32)
-                    else:
-                        agg = lax.psum_scatter(agg, self._axis,
-                                               scatter_dimension=1,
-                                               tiled=True)
-                else:
-                    if td != jnp.float32 and agg.ndim == 2:
-                        agg = lax.optimization_barrier(
-                            lax.psum(agg.astype(td), all_axes))
-                        agg = agg.astype(jnp.float32)
-                    else:
-                        agg = lax.psum(agg, all_axes)
-                if self._seq_axis is not None:
-                    agg = agg / self._seq_grad_scale
-                n_total = lax.psum(n_total, self._axis)
+                agg, n_total = self._mesh_aggregate(
+                    agg, n_total, step,
+                    defer_reduce=self._reduce_in_decode)
             return agg, n_total, results, n_valid, stats, \
                 client_finite, defense_stats, cur_med
 
@@ -1871,53 +1884,56 @@ class FedRuntime:
                                      in_specs=in_specs, out_specs=out_specs,
                                      check_vma=False)
 
-        agg, n_total, results, n_valid, grad_stats, client_finite, \
-            defense_stats, cur_med = client_block(
-                state.ps_weights, batch, mask, client_rngs, lr, adv_slot,
-                ref_thresh, state.step if self._int8_wire else None, cs)
+        with phase("fed_client_step"):
+            agg, n_total, results, n_valid, grad_stats, client_finite, \
+                defense_stats, cur_med = client_block(
+                    state.ps_weights, batch, mask, client_rngs, lr, adv_slot,
+                    ref_thresh, state.step if self._int8_wire else None, cs)
 
         client_stats = None
         if self._client_stats:
-            per_client = {"loss": results[0]}
-            if grad_stats is not None:
-                per_client.update(grad_stats)
+            with phase("fed_client_stats"):
+                per_client = {"loss": results[0]}
+                if grad_stats is not None:
+                    per_client.update(grad_stats)
+                else:
+                    nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
+                    per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
+                if cfg.track_bytes:
+                    per_client["upload_bytes"] = up_slot
+                    per_client["download_bytes"] = down_slot
+                rep = None
+                if self.mesh is not None:
+                    rep_sh = NamedSharding(self.mesh, P())
+
+                    def rep(x, _sh=rep_sh):
+                        return lax.with_sharding_constraint(x, _sh)
+                client_stats = summarize_per_client(per_client, n_valid,
+                                                    replicate_fn=rep)
+
+        with phase("fed_server_tail"):
+            # dispatch-side divergence detection: a poisoned cohort sum must
+            # be flagged before it can merge into the buffer
+            bad = ~jnp.isfinite(agg).all()
+            if self._quarantine:
+                # same "fully-nonfinite" semantics as the sync round: a
+                # benched/masked placeholder slot never vouches for a cohort
+                # whose every live upload diverged
+                bad = bad | ((~client_finite).any() & ~(n_valid > 0).any())
             else:
-                nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
-                per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
-            if cfg.track_bytes:
-                per_client["upload_bytes"] = up_slot
-                per_client["download_bytes"] = down_slot
-            rep = None
-            if self.mesh is not None:
-                rep_sh = NamedSharding(self.mesh, P())
+                bad = bad | ~jnp.isfinite(results[0]).all()
+            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
+                                  state.nan_round)
 
-                def rep(x, _sh=rep_sh):
-                    return lax.with_sharding_constraint(x, _sh)
-            client_stats = summarize_per_client(per_client, n_valid,
-                                                replicate_fn=rep)
+            defense_ref = state.defense_ref
+            if self._defense_ring:
+                # at cohort (dispatch) granularity the ring keys off the
+                # server version — commits between dispatches share a slot,
+                # which only shortens the effective window, never corrupts it
+                defense_ref = state.defense_ref.at[
+                    jnp.mod(state.step, cfg.defense_window)].set(cur_med)
 
-        # dispatch-side divergence detection: a poisoned cohort sum must
-        # be flagged before it can merge into the buffer
-        bad = ~jnp.isfinite(agg).all()
-        if self._quarantine:
-            # same "fully-nonfinite" semantics as the sync round: a
-            # benched/masked placeholder slot never vouches for a cohort
-            # whose every live upload diverged
-            bad = bad | ((~client_finite).any() & ~(n_valid > 0).any())
-        else:
-            bad = bad | ~jnp.isfinite(results[0]).all()
-        nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                              state.nan_round)
-
-        defense_ref = state.defense_ref
-        if self._defense_ring:
-            # at cohort (dispatch) granularity the ring keys off the
-            # server version — commits between dispatches share a slot,
-            # which only shortens the effective window, never corrupts it
-            defense_ref = state.defense_ref.at[
-                jnp.mod(state.step, cfg.defense_window)].set(cur_med)
-
-        defense = self._defense_scalars(defense_stats, client_finite)
+            defense = self._defense_scalars(defense_stats, client_finite)
 
         new_state = state.replace(rng=rng, client_last_round=client_last_round,
                                   nan_round=nan_round,
@@ -1959,25 +1975,28 @@ class FedRuntime:
         apart). Returns ``(replace_fields, update, Vvel, Verr)``; the
         caller owns ``rng`` advancement and any buffer handling."""
         cfg = self.cfg
-        update, Vvel, Verr, _sup_mask = self._apply_server_update(
-            state, agg, lr, server_rng, cs)
+        with phase("fed_server_tail"):
+            update, Vvel, Verr, _sup_mask = self._apply_server_update(
+                state, agg, lr, server_rng, cs)
 
-        if self.d_pad != cfg.grad_size:
-            if update.shape[0] == cfg.grad_size:
-                update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
-            else:
-                update = jnp.where(
-                    jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
-        ps_weights = state.ps_weights - update
+            if self.d_pad != cfg.grad_size:
+                if update.shape[0] == cfg.grad_size:
+                    update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
+                else:
+                    update = jnp.where(
+                        jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
+            ps_weights = state.ps_weights - update
 
         coord_last_update = state.coord_last_update
         if cfg.track_bytes:
-            coord_last_update = jnp.where(
-                update != 0, state.step, state.coord_last_update)
+            with phase("fed_byte_ledger"):
+                coord_last_update = jnp.where(
+                    update != 0, state.step, state.coord_last_update)
 
-        bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
-        nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                              state.nan_round)
+        with phase("fed_server_tail"):
+            bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
+            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
+                                  state.nan_round)
         fields = dict(
             ps_weights=ps_weights,
             Vvelocity=Vvel,
@@ -1994,8 +2013,9 @@ class FedRuntime:
         code to the sync round), apply it to the weights, and reset the
         buffer. ``step`` advances here: it is the server version."""
         rng, server_rng = jax.random.split(state.rng)
-        total = jnp.maximum(state.async_buffer_n, 1.0)
-        agg = state.async_buffer / total
+        with phase("fed_server_tail"):
+            total = jnp.maximum(state.async_buffer_n, 1.0)
+            agg = state.async_buffer / total
         fields, update, Vvel, Verr = self._server_tail_fields(
             state, agg, lr, server_rng, cs)
         new_state = state.replace(
@@ -2006,12 +2026,13 @@ class FedRuntime:
         # commit health scalars for the async_round telemetry event: the
         # post-commit EF-accumulator norms are the staleness-divergence
         # signal telemetry/health.py watches
-        metrics = {
-            "update_norm": jnp.linalg.norm(update),
-            "error_norm": jnp.linalg.norm(Verr),
-            "velocity_norm": jnp.linalg.norm(Vvel),
-            "buffer_n": state.async_buffer_n,
-        }
+        with phase("fed_signals"):
+            metrics = {
+                "update_norm": jnp.linalg.norm(update),
+                "error_norm": jnp.linalg.norm(Verr),
+                "velocity_norm": jnp.linalg.norm(Vvel),
+                "buffer_n": state.async_buffer_n,
+            }
         return new_state, metrics
 
     def _decode_step(self, state: FedState, cohort_sum: jax.Array,
@@ -2040,7 +2061,8 @@ class FedRuntime:
             # quantization draws match the monolithic round's bitwise.
             cohort_sum = self._reduce_partials(
                 cohort_sum, state.step if self._int8_wire else None)
-        agg = cohort_sum / jnp.maximum(n_total, 1.0)
+        with phase("fed_server_tail"):
+            agg = cohort_sum / jnp.maximum(n_total, 1.0)
         fields, _update, _Vvel, _Verr = self._server_tail_fields(
             state, agg, lr, server_rng, cs)
         return state.replace(rng=rng, **fields)

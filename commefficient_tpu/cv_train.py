@@ -361,7 +361,11 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
     tracer = util = None
     monitor = recorder = ledger = None
     if telemetry is not None:
-        tracer = tracing.install()
+        # under --profile_rounds the spans also enter the profiler's own
+        # trace (prefix "fed:"), on the same clock as the device's ops
+        tracer = tracing.install(tracing.SpanTracer(annotate=(
+            (lambda name: jax.profiler.TraceAnnotation("fed:" + name))
+            if prof.enabled else None)))
         util = UtilizationTracker(telemetry, peak_flops=cfg.peak_flops,
                                   peak_hbm_gbps=cfg.peak_hbm_gbps,
                                   watcher=telemetry.watcher(),

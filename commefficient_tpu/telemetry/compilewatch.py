@@ -25,6 +25,38 @@ from typing import Any, Callable, Dict
 
 import jax
 
+# latest compiled executable per watched name, process-wide: whoever has
+# no handle on the runtime (a metric reader, an operator's notebook) reads
+# the HLO of the program that is running — same pattern as
+# tracing.current(). The executable object only: no copy, no text kept.
+LATEST: Dict[str, Any] = {}
+
+
+def latest(name: str) -> Any:
+    """The executable a ``JitWatcher`` compiled last under ``name``
+    (``"round_step"``, ...), or None."""
+    return LATEST.get(name)
+
+
+def _compile(lowered) -> Any:
+    """``lowered.compile()`` with the persistent cache keyed on the
+    instructions' metadata too. JAX strips it from the key by default,
+    so a cache shared with another checkout hands back an executable
+    that computes the same but carries THAT checkout's ``op_name``
+    paths: the round's phase names (profiling.PHASES) that a reader of
+    ``latest()`` and the profile viewer's name-scope rows rely on would
+    be stale or missing (tried: a scope renamed between two processes
+    came back under its old name). The price is a recompile of the
+    watched executables — not of anything else — when their source
+    lines move."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    prev = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update(flag, prev)
+
 
 def _signature(args) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten(args)
@@ -98,7 +130,7 @@ class JitWatcher:
                     t0 = time.perf_counter()
                     lowered = fn.lower(*args)
                     t1 = time.perf_counter()
-                    compiled = lowered.compile()
+                    compiled = _compile(lowered)
                     t2 = time.perf_counter()
                 except Exception:
                     # un-lowerable input (or an AOT-unsupported transform
@@ -107,7 +139,7 @@ class JitWatcher:
                     emit(len(cache), 0.0, 0.0, {}, fallback=True)
                     return fn(*args)
                 cache[key] = compiled
-                self.executables[name] = compiled
+                self.executables[name] = LATEST[name] = compiled
                 emit(len(cache), t1 - t0, t2 - t1,
                      _cost_analysis(compiled))
                 # collective ledger of the fresh executable (count/kind/

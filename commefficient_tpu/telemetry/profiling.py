@@ -6,11 +6,42 @@ jax profiler trace over an arbitrary round range of the run. Rounds are
 1-based and the window is inclusive — the default "2:4" captures rounds
 2, 3 and 4, exactly the old behavior (skipping round 1 keeps the first
 compile out of the trace).
+
+``phase(name)`` names the round's phases INSIDE the compiled program:
+a ``jax.named_scope`` whose name lands in the ``op_name`` metadata of
+every HLO instruction traced under it (and in the profile viewer's name
+scope rows), so device time can be read per phase from the executable's
+own HLO. Trace-time only: nothing runs on the host's path of a round.
 """
 
 from __future__ import annotations
 
 from typing import Callable, Optional, Tuple
+
+# The round's phases, flat and prefixed so that none can collide with a
+# jitted function's name in an op_name path (``jit(topk)``, ``jit(sort)``).
+# Where two nest (the encode and the table reduce sit inside the client
+# step) the INNERMOST names the instruction; nothing else nests.
+PHASES = (
+    "fed_client_step",      # client_block: forward/backward, local rows, sum
+    "fed_sketch_encode",    # every sketch encode of client gradients
+    "fed_table_reduce",     # the cross-chip aggregation (mesh only)
+    "fed_server_tail",      # normalize, momentum/EF, decode, top-k, apply
+    "fed_signals",          # telemetry/signals.py round_signals
+    "fed_layer_signals",    # telemetry/layer_signals.py per-group masses
+    "fed_client_stats",     # telemetry/clients.py quantile summaries
+    "fed_byte_ledger",      # track_bytes: download counts, last-update maps
+)
+
+
+def phase(name: str):
+    """``jax.named_scope(name)`` for one of ``PHASES``; any other name is
+    a typo that would silently drop its operations out of every per-phase
+    metric, so it raises."""
+    if name not in PHASES:
+        raise ValueError(f"unknown round phase {name!r}; one of {PHASES}")
+    import jax
+    return jax.named_scope(name)
 
 
 def parse_profile_rounds(spec: str) -> Tuple[int, int]:

@@ -74,7 +74,7 @@ class _Span:
     exception inside the span still produces the span, with the time it
     actually took."""
 
-    __slots__ = ("_tracer", "_name", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str):
         self._tracer = tracer
@@ -82,11 +82,17 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self._depth = self._tracer._enter_depth()
+        annotate = self._tracer._annotate
+        self._ann = annotate(self._name) if annotate else None
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer._record(self._name, self._t0, t1 - self._t0,
                              self._depth)
         return False
@@ -108,11 +114,18 @@ class SpanTracer:
     ``dropped`` instead of growing without limit. ``pop_dropped()``
     returns-and-resets that counter, so each ``span`` event reports the
     drops of ITS window — per-event counts sum to the true total.
+
+    ``annotate`` is an optional factory ``name -> context manager`` that
+    every span enters beside its own clock reads. The drivers pass
+    ``jax.profiler.TraceAnnotation`` under ``--profile_rounds``, which
+    puts the program's host spans on the profiler's clock, next to the
+    device's operations (this module itself stays free of jax).
     """
 
     enabled = True
 
-    def __init__(self, max_spans: int = 100_000):
+    def __init__(self, max_spans: int = 100_000, annotate=None):
+        self._annotate = annotate
         self.t0_wall = time.time()
         self.t0 = time.perf_counter()
         self.max_spans = max_spans

@@ -38,6 +38,9 @@ def test_cache_dir_from_environment_is_left_alone(monkeypatch, tmp_path,
                 == str(tmp_path / "env"))
     assert cache_dir_calls == []
     assert not (tmp_path / "flag").exists()
+    # the operator's directory is made if it is not there yet: JAX does
+    # not make it, and every write to it would fail with a warning
+    assert (tmp_path / "env").is_dir()
 
 
 def test_cache_dir_default_is_fixed_in_checkout(monkeypatch, cache_dir_calls):

@@ -115,6 +115,42 @@ def test_span_buffer_cap_counts_drops():
     assert tr.pop_dropped() == 0
 
 
+def test_spans_enter_the_annotation_factory_beside_their_clock():
+    """``SpanTracer(annotate=...)``: every span enters the factory's
+    context manager (the drivers pass jax.profiler.TraceAnnotation under
+    --profile_rounds), nested in span order, closed on an exception too;
+    without a factory nothing is entered."""
+    log = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, exc[0]))
+
+    tr = SpanTracer(annotate=lambda name: Recording("fed:" + name))
+    with tr.span("round"):
+        with tr.span("data_fetch"):
+            pass
+    with pytest.raises(KeyError):
+        with tr.span("boom"):
+            raise KeyError("x")
+    assert log == [("enter", "fed:round"), ("enter", "fed:data_fetch"),
+                   ("exit", "fed:data_fetch", None),
+                   ("exit", "fed:round", None),
+                   ("enter", "fed:boom"), ("exit", "fed:boom", KeyError)]
+    assert [s["name"] for s in tr.drain()] == ["data_fetch", "round",
+                                               "boom"]
+    plain = SpanTracer()
+    with plain.span("quiet"):
+        pass
+    assert len(log) == 6 and len(plain.drain()) == 1
+
+
 def test_null_tracer_is_free_and_default():
     """With no tracer installed (the --no_telemetry state), span() must
     return one shared no-op object — no allocation, no clock reads —
