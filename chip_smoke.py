@@ -116,7 +116,8 @@ def check_on_chip(stage, runtime, state, n_devices=1):
     ``main(on_finish=...)`` once training has returned."""
     import jax
     from commefficient_tpu.ops.circulant_pallas import (DECODE_KERNEL_NAME,
-                                                        ENCODE_KERNEL_NAME)
+                                                        ENCODE_KERNEL_NAME,
+                                                        encode_hbm_bytes)
     from commefficient_tpu.telemetry.collectives import ledger_from_hlo
 
     platforms = {d.platform for leaf in jax.tree_util.tree_leaves(state)
@@ -129,6 +130,10 @@ def check_on_chip(stage, runtime, state, n_devices=1):
     say(f"{stage}: sketch {cs.r} x {cs.c} over d = {cs.d} (m = {cs.m} "
         f"blocks), kernel path {cs.kernel_path}; compiled round holds "
         f"{n_mosaic} Mosaic custom call(s)")
+    moved = encode_hbm_bytes(cs.c, cs.r, cs.m)
+    say(f"{stage}: one encode call moves {moved} HBM bytes by its "
+        f"BlockSpecs, {moved / (4 * (cs.d + cs.r * cs.c)):.3f} x the "
+        "vector and the table once each")
     if n_devices == 1:
         assert n_mosaic >= 2, n_mosaic
         assert ENCODE_KERNEL_NAME in hlo and DECODE_KERNEL_NAME in hlo

@@ -194,13 +194,11 @@ class CirculantSketch:
         return self._pallas_eligible()
 
     def _use_pallas_encode(self) -> bool:
-        # ON when eligible (round 4): with the fused-clients round (ONE
-        # encode of the summed gradient per round), the pallas encode
-        # measured 429 -> 385 ms on the flagship GPT-2 round (76.5k ->
-        # 85.2k tok/s) vs the XLA static-roll path. (Under the old
-        # per-client vmap encode the two were ~equal, which is why this
-        # began opt-in.) Kept as a separate seam from decode in case the
-        # two policies ever diverge again.
+        # ON when eligible: one call of the one-pass kernel measured
+        # 0.57 ms at d=25.5M and 2.05 ms at d=124M on a v5e
+        # (ops/circulant_pallas.py v5) where the static-roll path pays
+        # r·m roll ops (~26 ms at d=124M). Kept as a separate seam from
+        # decode in case the two policies ever diverge.
         return self._pallas_eligible()
 
     def encode(self, vec: jax.Array) -> jax.Array:

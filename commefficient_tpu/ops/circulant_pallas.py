@@ -16,7 +16,7 @@ Design history (all numbers measured the same way):
   residual cost is the DYNAMIC ``pltpu.roll`` itself (Mosaic lowers a
   dynamic lane rotate as a multi-stage shift network; a 10-roll/step
   ablation costs +100 ms over the 23 ms copy floor).
-- v4 (this file) eliminates rotates entirely: shifts are restricted to
+- v4 eliminates rotates entirely: shifts are restricted to
   multiples of 1024 = 8 sublanes × 128 lanes (``make_circulant_sketch``
   applies that granularity whenever c % 1024 == 0 — see the statistics
   note there), so every span of a conceptual roll starts on a vreg
@@ -25,6 +25,21 @@ Design history (all numbers measured the same way):
   address arithmetic, no data movement beyond the copy itself.
   Measured: decode 21 ms (6× over the roll path), with the whole table
   loaded into VMEM once (constant index map).
+- v5 (this file, PR 27) is v4 with the encode's grid turned inside out.
+  v4's encode ran a grid (lane tiles, blocks) and DMA'd one whole
+  wrap-padded block per step, so the d-long input crossed HBM once per
+  lane tile: 163 times at c = 500,736 = 3·163·1024 (largest aligned
+  tile ≤ 65,536 is 3,072), 16.75 GB and 22.13 ms a call at d = 25.5M;
+  8 times at c = 2^19, 4.49 GB and 5.94 ms at d = 124.4M — both the
+  kernel's own traffic at HBM speed. Now the grid is (blocks,): block b
+  is fetched once, the whole (r, c) table is the resident accumulator
+  (constant index map, written back once) and the lane tiles are a
+  ``fori_loop`` inside the kernel; ``encode_hbm_bytes`` is the
+  traffic figure that would have shown the fault. Same additions in the
+  same order: the table is bit-identical to v4's. Measured a call, v5e:
+  0.57 ms at c = 500,736, d = 25.5M and 2.05 ms at c = 2^19,
+  d = 124.4M, bound by the VPU's sign hash (r·m·c murmur evaluations),
+  not by HBM. The decode is v4's, untouched.
 
 Exactness vs the roll path is asserted in interpret mode by
 tests/test_ops.py and against numpy on the TPU at flagship scale.
@@ -59,31 +74,61 @@ _GOLDEN = 0x9E3779B9
 # (8 sublanes x 128 lanes) into the row — the no-rotate enabler
 SHIFT_ALIGN = 1024
 
-# decode keeps the wrap-padded (r, c/128 + ct/128, 128) table resident in
-# VMEM: cap its footprint (table_vmem_bytes). Checked on a v5e under jax
-# 0.9.0 / libtpu 0.0.34 (PR 21): both kernels compile under Mosaic's
-# default scoped-VMEM limit at 10.1 MB (r=5, c=500,736) and at 11.8 MB
-# (r=5, c=524,288), so no vmem_limit_bytes is passed. A larger budget
-# has not met the compiler.
+# both kernels keep the table resident in VMEM, the decode wrap-padded
+# as its input (table_vmem_bytes, capped here), the encode as its
+# accumulator. Checked on a v5e under jax 0.9.0 / libtpu 0.0.34: the
+# decode compiles under Mosaic's default scoped-VMEM limit at 10.1 MB
+# (r=5, c=500,736) and 11.8 MB (r=5, c=524,288), PR 21. The encode,
+# which Pallas gives two buffers of its table and of its input block,
+# asks for them with vmem_limit_bytes (_encode_vmem_limit): 28.3 MB and
+# 29.4 MB at those two geometries ran on the chip (PR 27), and the most
+# any sketch under this budget asks for, 54.5 MB at r=1, c=3,140,608,
+# compiles (tests/test_tpu_compile.py) against the core's 128 MiB.
 TABLE_VMEM_BUDGET = 12 << 20
 
-# lane-tile width of the streamed output/input spans
+# lane-tile width of the decode's streamed output spans (a DMA unit)
 _CT_MAX = 65536
 
+# lane-tile width of the encode's in-kernel loop. Not a DMA unit: one
+# (row, tile) step loads a span, hashes its signs and adds it into the
+# resident table, so the tile only has to keep that working set near
+# the vregs (8192 lanes = 8 vregs a span)
+_ENCODE_CT_MAX = 8192
 
-def _lane_tile(c: int) -> int:
+
+def _lane_tile(c: int, cap: int | None = None) -> int:
     """Largest divisor of c that is a multiple of SHIFT_ALIGN and ≤
-    _CT_MAX. Callers guarantee c % SHIFT_ALIGN == 0, so SHIFT_ALIGN
-    itself is always a valid fallback."""
+    ``cap`` (default _CT_MAX). Callers guarantee c % SHIFT_ALIGN == 0,
+    so SHIFT_ALIGN itself is always a valid fallback."""
+    cap = _CT_MAX if cap is None else cap
     for n in range(1, c // SHIFT_ALIGN + 1):
-        if c % n == 0 and (c // n) % SHIFT_ALIGN == 0 and c // n <= _CT_MAX:
+        if c % n == 0 and (c // n) % SHIFT_ALIGN == 0 and c // n <= cap:
             return c // n
     raise ValueError(f"c={c} has no {SHIFT_ALIGN}-aligned lane tile")
+
+
+def _encode_tile(c: int) -> int:
+    return _lane_tile(c, _ENCODE_CT_MAX)
 
 
 def table_vmem_bytes(c: int, r: int) -> int:
     """Bytes of the decode kernel's resident wrap-padded f32 table."""
     return 4 * r * (c + _lane_tile(c))
+
+
+def encode_hbm_bytes(c: int, r: int, m: int) -> int:
+    """HBM bytes one ``pallas_encode`` call moves by its own BlockSpecs:
+    each of the m wrap-padded input blocks fetched once, the (r, c)
+    table written back once. Against the d + r·c floats the algorithm
+    needs, it says how many passes over the input the grid makes."""
+    return 4 * (m * (c + _encode_tile(c)) + r * c)
+
+
+def _encode_vmem_limit(c: int, r: int) -> int:
+    """Scoped-VMEM request of the encode: Pallas holds two buffers of
+    the resident table and of the streamed input block; 4 MiB over that
+    for Mosaic's own temporaries."""
+    return 2 * 4 * (r * c + c + _encode_tile(c)) + (4 << 20)
 
 
 def _signs2d(start, sub, key):
@@ -96,18 +141,6 @@ def _signs2d(start, sub, key):
     h = _mix32(idx * key + _U32(_GOLDEN))
     # Mosaic can't cast uint32 -> f32 directly; the top bit is 0/1 so an
     # int32 hop is exact
-    return 1.0 - 2.0 * (h >> 31).astype(jnp.int32).astype(jnp.float32)
-
-
-def _signs2d_modc(base, q, c, sub, key):
-    """Signs for input coordinates base + ((q + u) mod c), u the flat
-    vreg-layout offset — the encode span crosses the block's mod-c seam
-    at most once, so one conditional subtract realizes the mod."""
-    pos = (q
-           + 128 * lax.broadcasted_iota(jnp.int32, (sub, 128), 0)
-           + lax.broadcasted_iota(jnp.int32, (sub, 128), 1))
-    pos = pos - jnp.where(pos >= c, c, 0)
-    h = _mix32((base + pos).astype(_U32) * key + _U32(_GOLDEN))
     return 1.0 - 2.0 * (h >> 31).astype(jnp.int32).astype(jnp.float32)
 
 
@@ -127,19 +160,40 @@ def _decode_kernel(shifts_ref, keys_ref, t_ref, out_ref, *, c, r, ct):
 
 
 def _encode_kernel(shifts_ref, keys_ref, v_ref, out_ref, *, c, r, ct):
-    t, b = pl.program_id(0), pl.program_id(1)
+    b = pl.program_id(0)
     sub = ct // 128
 
     @pl.when(b == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    for j in range(r):
-        # table[j, t·ct + u] += sign(input) · v_b[(t·ct + u − s) mod c]
-        q = (t * ct + c - shifts_ref[j, b]) % c
-        span = v_ref[0, pl.ds(q // 128, sub)]            # (sub, 128)
-        out_ref[0, j] += _signs2d_modc(b * c, q, c, sub,
-                                       keys_ref[j]) * span
+    # table[j, i] += sign(b·c + (i − s) mod c) · v_b[(i − s) mod c]: lane
+    # tile t of row j reads the span that starts back[j] + t·ct (mod c)
+    # into the block
+    back = [c - shifts_ref[j, b] for j in range(r)]       # in (0, c]
+    keys = [keys_ref[j] for j in range(r)]
+    lanes = (128 * lax.broadcasted_iota(jnp.int32, (sub, 128), 0)
+             + lax.broadcasted_iota(jnp.int32, (sub, 128), 1))
+
+    def tile(t, carry):
+        for j in range(r):
+            q = t * ct + back[j]
+            q = q - jnp.where(q >= c, c, 0)
+            span = v_ref[0, pl.ds(pl.multiple_of(q // 128, 8), sub)]
+            # the span crosses the block's mod-c seam at most once, so
+            # one conditional subtract realizes the mod
+            pos = q + lanes
+            pos = pos - jnp.where(pos >= c, c, 0)
+            h = _mix32((b * c + pos).astype(_U32) * keys[j] + _U32(_GOLDEN))
+            # ±1 · span is span with the hash's top bit xored into its
+            # sign: the same murmur stream as CirculantSketch._sign_of
+            signed = lax.bitcast_convert_type(
+                lax.bitcast_convert_type(span, _U32) ^ (h & _U32(1 << 31)),
+                jnp.float32)
+            out_ref[j, pl.ds(pl.multiple_of(t * sub, 8), sub)] += signed
+        return carry
+
+    lax.fori_loop(0, c // ct, tile, 0)
 
 
 # stable kernel names: what a compiled round's HLO and a device trace
@@ -159,31 +213,32 @@ def pallas_encode(vec_padded, shifts, sign_keys, *, c, r, m,
                   interpret=False):
     """(m*c,) padded fp32 vector -> (r, c) table. ``shifts``: (r, m) int32
     multiples of SHIFT_ALIGN; ``sign_keys``: (r,) uint32."""
-    ct = _lane_tile(c)
-    sub, csub, nct = ct // 128, c // 128, c // ct
+    ct = _encode_tile(c)
+    sub, csub = ct // 128, c // 128
     blocks = _wrap_pad(
         vec_padded.astype(jnp.float32).reshape(m, csub, 128), sub)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        # lane-tiles outer, vector blocks inner: each inner step streams
-        # one whole wrap-padded block (ONE DMA) and accumulates all r
-        # rows of the resident (1, r, sub, 128) table tile
-        grid=(nct, m),
+        # one step a vector block: block b crosses HBM -> VMEM once (ONE
+        # DMA) and is added into all r rows of the table, which a
+        # constant index map keeps resident for all m steps and writes
+        # back once. The steps accumulate, so the axis is sequential
+        grid=(m,),
         in_specs=[pl.BlockSpec((1, csub + sub, 128),
-                               lambda t, b, *_: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, r, sub, 128),
-                               lambda t, b, *_: (t, 0, 0, 0)),
+                               lambda b, *_: (b, 0, 0))],
+        out_specs=pl.BlockSpec((r, csub, 128), lambda b, *_: (0, 0, 0)),
     )
     out = pl.pallas_call(
         functools.partial(_encode_kernel, c=c, r=r, ct=ct),
-        out_shape=jax.ShapeDtypeStruct((nct, r, sub, 128), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((r, csub, 128), jnp.float32),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_encode_vmem_limit(c, r)),
         interpret=interpret,
         name=ENCODE_KERNEL_NAME,
     )(shifts, sign_keys, blocks)
-    # (nct, r, sub, 128) -> (r, c): element (t, j, s, l) is
-    # table[j, t·ct + s·128 + l]
-    return out.transpose(1, 0, 2, 3).reshape(r, c)
+    return out.reshape(r, c)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "r", "m", "interpret"))

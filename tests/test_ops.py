@@ -361,3 +361,83 @@ class TestCirculantSketch:
                                 r=cs.r, m=cs.m, interpret=True)[: cs.d]
         np.testing.assert_allclose(np.asarray(d_pl),
                                    np.asarray(cs.decode(t_roll)), atol=1e-5)
+
+    @staticmethod
+    def _encode_in_block_order(cs, v):
+        """Plain reference of the encode kernel's arithmetic: every table
+        cell is 0 + term(b=0) + term(b=1) + ..., float32 adds in block
+        order, term(b) the signed block rolled by its shift."""
+        m, c = cs.m, cs.c
+        vp = np.zeros(m * c, np.float32)
+        vp[:cs.d] = v
+        table = np.zeros((cs.r, c), np.float32)
+        for j in range(cs.r):
+            signs = np.asarray(cs._signs(j))
+            for b in range(m):
+                table[j] += np.roll(signs[b] * vp[b * c:(b + 1) * c],
+                                    cs.shifts[j][b])
+        return table
+
+    # (c, d, cap on the encode's tile, tile it must pick, seam shifts);
+    # every case has its own m: pallas_encode is jit-cached on (c, r, m,
+    # interpret) and a collision would reuse another case's tile
+    @pytest.mark.parametrize("c,d,cap,tile,seam", [
+        (2048, 7000, None, 2048, False),     # one tile
+        (2048, 13000, 1024, 1024, False),    # two tiles
+        # c = 500,736 = 3 * 163 * 1024 in miniature: a non-power-of-two
+        # c whose tile is 3,072; spans cross tile edges and the mod-c seam
+        (9216, 40000, None, 3072, False),
+        (9216, 30000, None, 3072, True),     # shifts 0 and c - 1024 in a row
+    ], ids=["one_tile", "two_tiles", "c9216_tile3072", "shift_0_and_c-1024"])
+    def test_pallas_encode_one_pass_bit_exact(self, monkeypatch, c, d, cap,
+                                              tile, seam):
+        """The one-pass encode (table resident, lane tiles looped inside
+        the kernel) against the roll path, and bit for bit against the
+        block-order reference: the same float32 additions in the same
+        order as the (tile, block) grid it replaced."""
+        import dataclasses
+        from commefficient_tpu.ops import circulant as circ
+        from commefficient_tpu.ops import circulant_pallas as cp
+        if cap is not None:
+            monkeypatch.setattr(cp, "_ENCODE_CT_MAX", cap)
+        assert cp._encode_tile(c) == tile
+        cs = circ.make_circulant_sketch(d=d, c=c, r=5, seed=c + d)
+        if seam:
+            cs = dataclasses.replace(cs, shifts=tuple(
+                (0, c - 1024) + row[2:] for row in cs.shifts))
+        # some span wraps mod c and, where a tile is more than one shift
+        # step, some span starts inside a tile
+        assert any(s for row in cs.shifts for s in row)
+        assert tile == cp.SHIFT_ALIGN or any(
+            s % tile for row in cs.shifts for s in row)
+        rng = np.random.RandomState(d)
+        v = rng.randn(d).astype(np.float32)
+        vp = jnp.pad(jnp.asarray(v), (0, cs.m * c - d))
+        t_pl = np.asarray(cp.pallas_encode(
+            vp, jnp.asarray(cs.shifts, jnp.int32), cs.sign_keys, c=c,
+            r=cs.r, m=cs.m, interpret=True))
+        np.testing.assert_allclose(t_pl, np.asarray(cs.encode(v)),
+                                   atol=1e-4)
+        np.testing.assert_array_equal(t_pl,
+                                      self._encode_in_block_order(cs, v))
+
+    @pytest.mark.parametrize("c,r,d", [
+        (500736, 5, 25504026),     # rn50_sketch_8x64
+        (524288, 5, 124444416),    # gpt2_sketch_8x8x2x256
+    ])
+    def test_encode_hbm_bytes_is_one_pass(self, c, r, d):
+        """The encode's own BlockSpecs move the input once and the table
+        once; wrap padding is all that separates that from what the
+        algorithm needs. The (lane tile, block) grid this replaced
+        fetched every block once per lane tile: 150x and 8.8x."""
+        from commefficient_tpu.ops import circulant_pallas as cp
+        from perfbench.harness.arith import sketch_encode_bytes
+        m = -(-d // c)
+        ct = cp._encode_tile(c)
+        moved = cp.encode_hbm_bytes(c, r, m)
+        assert moved == 4 * (m * (c + ct) + r * c)
+        need = sketch_encode_bytes(d, r, c)
+        assert 1.0 <= moved / need < 1.2
+        pt = cp._lane_tile(c)
+        per_tile_grid = 4 * ((c // pt) * m * (c + pt) + r * c)
+        assert per_tile_grid / need > 8
