@@ -73,6 +73,11 @@ class FedRuntime:
                  seq_spec: Optional[Dict[str, int]] = None):
         flat, unravel = ravel_params(params)
         cfg = cfg.replace(grad_size=int(flat.size))
+        # a loss that reports more than (loss, one metric) says so itself
+        # (losses.make_laguna_loss: the expert layers' counters)
+        n_results = getattr(loss_fn_train, "num_results", None)
+        if n_results is not None:
+            cfg = cfg.replace(num_results_train=int(n_results))
         if (cfg.mode == "sketch" and cfg.sketch_impl == "circ"
                 and not cfg.exact_num_cols):
             # TPU-efficient sketch width (config.auto_num_cols): align to
@@ -782,10 +787,13 @@ class FedRuntime:
 
     def _state_template(self):
         """Structure-only FedState (no allocation) for sharding layout."""
-        return jax.eval_shape(self._make_state, 0, self.initial_weights)
+        return jax.eval_shape(self._make_state, jax.random.PRNGKey(0),
+                              self.initial_weights)
 
     def init_state(self, seed: Optional[int] = None) -> FedState:
-        seed = self.cfg.seed if seed is None else seed
+        # the key is made here from the Python integer: as a jit argument
+        # a seed past 2**31 overflows the int32 it would be parsed into
+        rng = jax.random.PRNGKey(self.cfg.seed if seed is None else seed)
         if self._state_sharding is not None:
             # create the state directly in its sharded layout — no single
             # device ever holds the full per-client arrays. The weights are
@@ -793,10 +801,10 @@ class FedRuntime:
             # into the HLO shipped to the compiler (0.5 GB at GPT-2 scale)
             return jax.jit(self._make_state,
                            out_shardings=self._state_sharding)(
-                               seed, self.initial_weights)
-        return self._make_state(seed, self.initial_weights)
+                               rng, self.initial_weights)
+        return self._make_state(rng, self.initial_weights)
 
-    def _make_state(self, seed, initial_weights) -> FedState:
+    def _make_state(self, rng, initial_weights) -> FedState:
         cfg = self.cfg
         # Server-side transmitted-space state lives at the mesh-padded
         # length so it shards evenly (see __init__). Per-client dense rows
@@ -826,7 +834,7 @@ class FedRuntime:
             Vvelocity=zeros_tx,
             Verror=jnp.zeros_like(zeros_tx),
             step=jnp.zeros((), jnp.int32),
-            rng=jax.random.PRNGKey(seed),
+            rng=rng,
             client_velocities=maybe((n,) + client_tx,
                                     cfg.needs_client_velocities),
             client_errors=maybe((n,) + client_tx, cfg.needs_client_errors),

@@ -339,8 +339,12 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
           lr_mult: Optional[jax.Array] = None, loggers=(), timer=None,
           ckpt_mgr=None, start_epoch: int = 0, writer=None, schedule=None,
           telemetry=None, model_flops_per_round: Optional[float] = None,
-          resume_info=None, guard=None):
-    """The shared driver loop. Returns ``(state, summary)``; ``summary``
+          resume_info=None, guard=None, round_counters=()):
+    """The shared driver loop. ``round_counters`` names what the training
+    loss returns after (loss, accuracy): the round's telemetry event
+    carries their means over the round's items under ``moe``
+    (models/laguna.MOE_COUNTERS; a non-zero ``dropped`` raises).
+    Returns ``(state, summary)``; ``summary``
     is None when the run ended before its schedule — a preemption drain
     (an orderly handoff) or an abort (non-finite update, alert abort,
     quarantine exhausted). A caller that must tell the two apart passes
@@ -879,11 +883,20 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
                             ids = np.asarray(rnd.client_ids)
                             down_clients = [float(x) for x in down_all[ids]]
                             up_clients = [float(x) for x in up_all[ids]]
+                        moe = None
+                        if round_counters:
+                            moe = {name: float((r * nv).sum() / tot)
+                                   for name, r in zip(round_counters,
+                                                      res[2:])}
+                            if moe.get("dropped"):
+                                raise RuntimeError(
+                                    f"round {global_round}: the expert "
+                                    f"dispatch dropped tokens: {moe}")
                         telemetry.round_event(
                             rnd=global_round, epoch=epoch + 1, lr=float(lr),
                             loss=float((res[0] * nv).sum() / tot),
                             acc=float((res[acc_idx] * nv).sum() / tot),
-                            n_valid=float(nv.sum()),
+                            n_valid=float(nv.sum()), moe=moe,
                             download_bytes=down_total,
                             upload_bytes=up_total,
                             host_s=host_s,

@@ -8,6 +8,13 @@ final perplexity/accuracy evaluation (test_gpt2, 149).
 
 Run:  python -m commefficient_tpu.gpt2_train --mode sketch \
           --error_type virtual --num_workers 4 --local_batch_size -1 ...
+
+``--model laguna --model_checkpoint <config.json>`` trains
+``models/laguna.LagunaLM`` (window and full attention, a routed expert
+layer that holds a share of its experts) from a ``config.json`` in the
+published key set instead: next-token cross-entropy on every non-pad token
+of the same PersonaChat packing, through the same runtime, store, sampler
+and pipeline.
 """
 
 from __future__ import annotations
@@ -31,9 +38,13 @@ from commefficient_tpu.cv_train import (
     setup_checkpointing,
     train as shared_train,
 )
-from commefficient_tpu.data.fed_persona import FedPERSONA, get_tokenizer
-from commefficient_tpu.losses import make_gpt2_train_loss, make_gpt2_val_loss
+from commefficient_tpu.data.fed_persona import (FedPERSONA, HashTokenizer,
+                                                get_tokenizer)
+from commefficient_tpu.losses import (make_gpt2_train_loss,
+                                      make_gpt2_val_loss, make_laguna_loss)
+from commefficient_tpu.models import laguna as laguna_lib
 from commefficient_tpu.models.gpt2 import (
+    NUM_SPECIAL_TOKENS,
     GPT2Config,
     GPT2DoubleHeads,
     gpt2_model_flops,
@@ -62,6 +73,20 @@ def build_gpt2(cfg: FedConfig, tokenizer):
                           remat=cfg.do_remat,
                           remat_policy=cfg.remat_policy)
     return GPT2DoubleHeads(gcfg, attn_impl=resolve_attn(cfg.attn_impl)), gcfg
+
+
+def build_laguna(cfg: FedConfig):
+    """(model, LagunaConfig, tokenizer) from ``--model_checkpoint``, a
+    ``config.json`` in the published key set (it may state a chip's share,
+    see ``LagunaConfig.from_hf``). No public tokenizer is at hand offline:
+    words hash into the configuration's vocabulary less the five special
+    tokens, which take its last rows."""
+    lcfg = laguna_lib.LagunaConfig.from_json(
+        cfg.model_checkpoint, compute_dtype=jnp.dtype(cfg.compute_dtype),
+        remat=cfg.do_remat)
+    model = laguna_lib.LagunaLM(
+        lcfg, attn_impl=resolve_attn(cfg.attn_impl, grouped=True))
+    return model, lcfg, HashTokenizer(lcfg.vocab_size - NUM_SPECIAL_TOKENS)
 
 
 def make_gpt2_schedule(cfg: FedConfig):
@@ -154,7 +179,11 @@ def main(argv=None, *, on_finish=None):
     cfg = cfg.replace(dataset_name="PERSONA")
 
     timer = Timer()
-    tokenizer = get_tokenizer(cfg.model_checkpoint)
+    laguna = cfg.model == "laguna"
+    if laguna:
+        model, gcfg, tokenizer = build_laguna(cfg)
+    else:
+        tokenizer = get_tokenizer(cfg.model_checkpoint)
     max_seq_len = cfg.max_seq_len or (64 if cfg.do_test else 280)
     train_ds = FedPERSONA(cfg.dataset_dir, train=True, do_iid=cfg.do_iid,
                           num_clients=cfg.num_clients, tokenizer=tokenizer,
@@ -171,18 +200,27 @@ def main(argv=None, *, on_finish=None):
                         personality_permutations=cfg.personality_permutations)
     cfg = cfg.replace(num_clients=train_ds.num_clients)
 
-    model, gcfg = build_gpt2(cfg, tokenizer)
     sample = train_ds.gather(np.zeros((1,), np.int64))
-    params = model.init(jax.random.PRNGKey(cfg.seed),
-                        jnp.asarray(sample["input_ids"]),
-                        jnp.asarray(sample["mc_token_ids"]),
-                        jnp.asarray(sample["token_type_ids"]))
-    loaded = load_hf_weights(params, gcfg, cfg.model_checkpoint)
-    if loaded is not None:
-        params = loaded
-        print("loaded pretrained GPT-2 weights")
+    if laguna:
+        params = jax.jit(model.init)(jax.random.PRNGKey(cfg.seed),
+                                     jnp.asarray(sample["input_ids"]))
+        print(f"laguna: {gcfg.num_hidden_layers} layers, experts "
+              f"{gcfg.experts_held[0]}-{gcfg.experts_held[1] - 1} of "
+              f"{gcfg.num_experts} held, vocabulary {gcfg.vocab_size}; "
+              "training from scratch")
     else:
-        print("WARNING: no local pretrained GPT-2; training from scratch")
+        model, gcfg = build_gpt2(cfg, tokenizer)
+        params = model.init(jax.random.PRNGKey(cfg.seed),
+                            jnp.asarray(sample["input_ids"]),
+                            jnp.asarray(sample["mc_token_ids"]),
+                            jnp.asarray(sample["token_type_ids"]))
+        loaded = load_hf_weights(params, gcfg, cfg.model_checkpoint)
+        if loaded is not None:
+            params = loaded
+            print("loaded pretrained GPT-2 weights")
+        else:
+            print("WARNING: no local pretrained GPT-2; training from "
+                  "scratch")
 
     # long-context configuration: --mesh_axes clients,seq runs every
     # client's model with the sequence sharded over the "seq" axis (ring
@@ -192,7 +230,17 @@ def main(argv=None, *, on_finish=None):
     mesh = build_mesh(cfg)
     seq_shards = (mesh.shape["seq"]
                   if mesh is not None and "seq" in mesh.axis_names else 1)
-    if seq_shards > 1:
+    round_counters = ()
+    if laguna:
+        if seq_shards > 1:
+            raise ValueError("--model laguna has no sequence-parallel "
+                             "attention; drop the seq mesh axis")
+        pad_id = tokenizer.convert_tokens_to_ids("<pad>")
+        loss_train = make_laguna_loss(model, pad_id, lm_chunk=cfg.lm_chunk)
+        loss_val = make_laguna_loss(model, pad_id, lm_chunk=cfg.lm_chunk,
+                                    counters=False)
+        round_counters = laguna_lib.MOE_COUNTERS
+    elif seq_shards > 1:
         if max_seq_len % seq_shards:
             raise ValueError(
                 f"the seq mesh axis size ({seq_shards}) must divide "
@@ -212,7 +260,8 @@ def main(argv=None, *, on_finish=None):
                                           lm_chunk=cfg.lm_chunk)
     # validation always runs the dense model (same param pytree); on a
     # mesh the val batch shards over all devices (runtime._val_step_sharded)
-    loss_val = make_gpt2_val_loss(model, lm_chunk=cfg.lm_chunk)
+    if not laguna:
+        loss_val = make_gpt2_val_loss(model, lm_chunk=cfg.lm_chunk)
     runtime = FedRuntime(cfg, params, loss_train, loss_val,
                          num_clients=train_ds.num_clients,
                          mesh=mesh,
@@ -223,7 +272,7 @@ def main(argv=None, *, on_finish=None):
           f"initialized in {timer():.2f}s")
 
     ckpt_mgr, start_epoch, restored, resume_info = setup_checkpointing(
-        cfg, runtime, "gpt2_doubleheads")
+        cfg, runtime, "laguna" if laguna else "gpt2_doubleheads")
     if restored is not None:
         state = restored
 
@@ -250,7 +299,9 @@ def main(argv=None, *, on_finish=None):
     # gpt2_model_flops); tokens/round = W x B x candidates x seq
     round_tokens = (cfg.num_workers * runtime.batch_size
                     * cfg.num_candidates * max_seq_len)
-    round_flops = gpt2_model_flops(gcfg, round_tokens, max_seq_len)
+    model_flops = (laguna_lib.laguna_model_flops if laguna
+                   else gpt2_model_flops)
+    round_flops = model_flops(gcfg, round_tokens, max_seq_len)
     tsv = TSVLogger()
     guard = PreemptGuard(cfg.preempt_grace)
     try:
@@ -262,7 +313,8 @@ def main(argv=None, *, on_finish=None):
                                       writer=make_writer(cfg, logdir=logdir),
                                       telemetry=telemetry,
                                       model_flops_per_round=round_flops,
-                                      resume_info=resume_info, guard=guard)
+                                      resume_info=resume_info, guard=guard,
+                                      round_counters=round_counters)
     finally:
         if telemetry is not None:
             telemetry.close()
@@ -272,8 +324,9 @@ def main(argv=None, *, on_finish=None):
     if summary is not None:
         nll = summary["test_loss"]
         print(f"final val nll {nll:.4f} ppl {math.exp(min(nll, 20)):.2f} "
-              f"mc acc {summary['test_acc']:.4f}")
-    if cfg.do_checkpoint and summary is not None:
+              f"{'token' if laguna else 'mc'} acc "
+              f"{summary['test_acc']:.4f}")
+    if cfg.do_checkpoint and summary is not None and not laguna:
         # reference parity: weights + config + tokenizer, reloadable
         # without this run's code in hand (fed_aggregator.py:208-211)
         save_pretrained(os.path.join(cfg.checkpoint_path,

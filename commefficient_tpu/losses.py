@@ -23,7 +23,7 @@ def _cast(tree, dtype):
         else t, tree)
 
 
-def _chunked_lm_nll(hidden, wte, labels, m, chunk):
+def _chunked_lm_nll(hidden, wte, labels, m, chunk, with_acc=False):
     """Shifted LM cross-entropy without ever materializing the full
     (tokens, vocab) logits: scan the sequence in ``chunk``-token slices,
     projecting + log-softmaxing each slice and accumulating the masked
@@ -32,7 +32,9 @@ def _chunked_lm_nll(hidden, wte, labels, m, chunk):
     is O(chunk·V) — the enabler for microbatch ≥ 8 at the 32k-token GPT-2
     round (the full fp32 logits + cotangent were ~1.6 GB per microbatch
     step). fp32 accumulation; bitwise-equivalent math to the dense path
-    up to sum reordering (asserted by tests/test_models.py)."""
+    up to sum reordering (asserted by tests/test_models.py). ``wte`` is
+    the (V, E) output head, tied or not. ``with_acc``: also return the
+    share of labelled tokens whose largest logit is the label."""
     h = hidden[..., :-1, :]                           # (B, C, S-1, E)
     lab = labels[..., 1:]                             # (B, C, S-1)
     B, C, T, E = h.shape
@@ -45,19 +47,24 @@ def _chunked_lm_nll(hidden, wte, labels, m, chunk):
     lab = lab.reshape(B, C, nch, chunk).transpose(2, 0, 1, 3)
 
     def body(carry, inp):
-        num, den = carry
+        num, den, *hits = carry
         hc, lc = inp                                  # (B, C, chunk, ...)
         tok_valid = ((lc != -100) * m[:, None, None]).astype(jnp.float32)
         logits = (hc @ wte.T.astype(hc.dtype)).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits)
         nll = -jnp.take_along_axis(
             logp, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
+        if with_acc:
+            hits = [hits[0]
+                    + ((jnp.argmax(logits, -1) == lc) * tok_valid).sum()]
         return (num + (nll * tok_valid).sum(),
-                den + tok_valid.sum()), None
+                den + tok_valid.sum(), *hits), None
 
-    (num, den), _ = lax.scan(jax.checkpoint(body),
-                             (jnp.zeros(()), jnp.zeros(())), (h, lab))
-    return num / jnp.maximum(den, 1.0)
+    (num, den, *hits), _ = lax.scan(
+        jax.checkpoint(body), (jnp.zeros(()),) * (3 if with_acc else 2),
+        (h, lab))
+    den = jnp.maximum(den, 1.0)
+    return (num / den, hits[0] / den) if with_acc else num / den
 
 
 def _gpt2_losses(model, params, batch, mask, seq_axis=None, seq_shards=1,
@@ -163,6 +170,34 @@ def make_gpt2_val_loss(model, seq_axis=None, seq_shards: int = 1,
             seq_shards=seq_shards, lm_chunk=lm_chunk)
         return lm_loss, (acc,)
 
+    return loss_fn
+
+
+def make_laguna_loss(model, pad_id: int, lm_chunk: int = 128,
+                     counters: bool = True):
+    """Next-token cross-entropy for ``models/laguna.LagunaLM`` on the
+    ``core/client`` contract: labels are ``input_ids`` shifted by one, pad
+    positions carry none; the vocabulary projection goes through the same
+    chunked scan as GPT-2's, with the model's untied head. ``batch``
+    leaves are (items, candidates, S) as PERSONA packs them; every
+    candidate is a sequence. Metrics: (token accuracy,) and, with
+    ``counters`` (the training loss), the expert layers'
+    ``models.laguna.MOE_COUNTERS`` after it."""
+    from commefficient_tpu.models.laguna import MOE_COUNTERS
+
+    def loss_fn(params, batch, mask):
+        ids = batch["input_ids"]
+        hidden, head, moe = model.apply(params, ids, ids != pad_id)
+        labels = jnp.where(ids == pad_id, -100, ids)
+        chunk = lm_chunk if lm_chunk > 0 else ids.shape[-1]
+        loss, acc = _chunked_lm_nll(hidden, head, labels,
+                                    mask.astype(jnp.float32), chunk,
+                                    with_acc=True)
+        extra = tuple(lax.stop_gradient(moe[k]) for k in MOE_COUNTERS)
+        return loss, (acc,) + (extra if counters else ())
+
+    # how many results the round carries for this loss (FedRuntime reads it)
+    loss_fn.num_results = 2 + (len(MOE_COUNTERS) if counters else 0)
     return loss_fn
 
 

@@ -24,6 +24,7 @@ from commefficient_tpu.models.resnets import (
     wide_resnet50_2,
     wide_resnet101_2,
 )
+from commefficient_tpu.models.laguna import LagunaConfig, LagunaLM
 
 _REGISTRY = {
     "ResNet9": ResNet9,
@@ -41,6 +42,9 @@ _REGISTRY = {
     "resnext101_32x8d": resnext101_32x8d,
     "wide_resnet50_2": wide_resnet50_2,
     "wide_resnet101_2": wide_resnet101_2,
+    # a language model: built from a config.json by gpt2_train
+    # (--model laguna --model_checkpoint <config.json>), not by cv_train
+    "laguna": LagunaLM,
 }
 
 MODEL_NAMES = sorted(_REGISTRY)
@@ -55,4 +59,5 @@ def get_model(name: str):
             f"unknown model {name!r}; choices: {MODEL_NAMES}") from None
 
 
-__all__ = ["get_model", "MODEL_NAMES"] + list(_REGISTRY)
+__all__ = ["get_model", "MODEL_NAMES", "LagunaConfig", "LagunaLM"] + [
+    n for n in _REGISTRY if n != "laguna"]

@@ -161,6 +161,72 @@ def auto_causal_attention(q, k, v, dropout_rng=None):
     return dense_causal_attention(q, k, v)
 
 
+def dense_grouped_attention(q, k, v, window=None):
+    """Plain causal grouped-query attention: q (..., S, H, D), k and v
+    (..., S, KV, D) with H a multiple of KV -> (..., S, H, D). Query head
+    i reads KV head i // (H / KV). ``window``: key j is visible to query
+    i iff 0 <= i - j < window (None: every earlier key). fp32 softmax."""
+    S, H, D = q.shape[-3:]
+    KV = k.shape[-2]
+    qg = q.reshape(q.shape[:-2] + (KV, H // KV, D))
+    logits = jnp.einsum("...qkgd,...skd->...kgqs", qg, k).astype(
+        jnp.float32) / math.sqrt(D)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    mask = j <= i
+    if window is not None:
+        mask &= i - j < window
+    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+    out = jnp.einsum("...kgqs,...skd->...qkgd", probs.astype(v.dtype), v)
+    return out.reshape(q.shape)
+
+
+GROUPED_ATTN_BLOCK = 512
+
+
+def splash_grouped_attention(q, k, v, window=None):
+    """The same through the TPU's blocked Pallas kernel
+    (jax.experimental.pallas.ops.tpu.splash_attention, its multi-query
+    form mapped over the KV heads): no (H, S, S) scores in HBM, and the
+    blocks the mask removes entirely (above the diagonal; on a window
+    layer also below the band) are never visited, forward or backward.
+    Off the TPU, or where S is not a multiple of the block, the plain
+    path."""
+    S, H, D = q.shape[-3:]
+    KV = k.shape[-2]
+    blk = GROUPED_ATTN_BLOCK
+    if jax.default_backend() != "tpu" or S % blk:
+        return dense_grouped_attention(q, k, v, window)
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    one = (sm.CausalMask((S, S)) if window is None
+           else sm.LocalMask((S, S), window_size=(window - 1, 0), offset=0))
+    sizes = sk.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
+        block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
+        block_kv_dq=blk)
+    kernel = sk.make_splash_mqa_single_device(
+        sm.MultiHeadMask([one] * (H // KV)), block_sizes=sizes)
+    qb = (q * (1.0 / math.sqrt(D))).astype(q.dtype).reshape(
+        (-1, S, KV, H // KV, D)).transpose(0, 2, 3, 1, 4)
+    kb = k.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
+    vb = v.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
+    out = jax.vmap(jax.vmap(kernel))(qb, kb, vb)      # (B, KV, G, S, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(q.shape)
+
+
+def auto_grouped_attention(q, k, v, window=None):
+    """The blocked kernel from S = 1024 up (as ``auto_causal_attention``;
+    at S = 4096 the plain path's scores are 3.2 GB a sequence)."""
+    if q.shape[-3] >= 1024:
+        return splash_grouped_attention(q, k, v, window)
+    return dense_grouped_attention(q, k, v, window)
+
+
+GROUPED_ATTN_IMPLS = {"dense": dense_grouped_attention,
+                      "flash": splash_grouped_attention,
+                      "auto": auto_grouped_attention}
+
+
 ATTN_IMPLS = {"dense": dense_causal_attention,
               # explicit flash requests warn when the eligibility check
               # falls back to dense (auto's fallbacks stay silent: its
@@ -171,13 +237,15 @@ ATTN_IMPLS = {"dense": dense_causal_attention,
               "auto": auto_causal_attention}
 
 
-def resolve_attn(name: str) -> Callable:
-    """Config-string -> attention callable (config.py --attn_impl)."""
+def resolve_attn(name: str, grouped: bool = False) -> Callable:
+    """Config-string -> attention callable (config.py --attn_impl);
+    ``grouped``: the window- and GQA-aware entries ``(q, k, v, window)``."""
+    impls = GROUPED_ATTN_IMPLS if grouped else ATTN_IMPLS
     try:
-        return ATTN_IMPLS[name]
+        return impls[name]
     except KeyError:
         raise ValueError(f"unknown attn_impl {name!r}: "
-                         f"want one of {sorted(ATTN_IMPLS)}") from None
+                         f"want one of {sorted(impls)}") from None
 
 
 class Block(nn.Module):

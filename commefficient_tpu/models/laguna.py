@@ -1,0 +1,383 @@
+"""Laguna: a decoder with window and full attention layers of different
+head counts and a routed expert layer that holds a share of its experts.
+
+Built from the lists of the published ``config.json`` (``layer_types``,
+``num_attention_heads_per_layer``, ``mlp_layer_types``): pre-RMSNorm
+blocks, grouped-query attention (H query heads over 8 KV heads of 128)
+with a per-head sigmoid gate on its output, rotary positions (YaRN on the
+first half of the head on full layers, plain on window layers), a causal
+window of ``sliding_window`` keys on the window layers, SwiGLU, and from
+layer 1 on a 256-way softmax router over experts of width 512 with one
+shared expert, untied input and output embeddings.
+
+The expert layer is told which experts it holds (``experts_held``, a
+range of ids): the router keeps its published width, routing is over all
+experts, and the layer adds only what its own experts give. On one chip
+nothing stands in for the absent ones; with every expert held it is the
+whole layer. The layer is dropless with static shapes: every held expert
+is applied to every token in one batched product over the held experts,
+weighted by the router (zero where the token did not choose it).
+
+Router product, softmax and top-k are float32 at highest precision: under
+bfloat16 the choice of experts flips against a float32 reference.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+from typing import Any, Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from commefficient_tpu.models.gpt2 import auto_grouped_attention
+from commefficient_tpu.telemetry.profiling import phase
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+# what the model reports beside the loss, per microbatch (core/client.py
+# averages them over a client's items): tokens per held expert over the
+# sparse layers, the share of the routed slots that land on held experts,
+# and the slots of held experts the dispatch could not take (always 0)
+MOE_COUNTERS = ("tokens_per_expert_min", "tokens_per_expert_mean",
+                "tokens_per_expert_max", "held_share", "dropped")
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One entry of the published ``rope_parameters``."""
+    rope_theta: float = 10000.0
+    rope_type: str = "default"
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    @classmethod
+    def from_dict(cls, d):
+        names = {f.name for f in dataclasses.fields(cls)}
+        return cls(**{k: v for k, v in d.items() if k in names})
+
+
+@dataclasses.dataclass(frozen=True)
+class LagunaConfig:
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 40
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    rms_norm_eps: float = 1e-6
+    num_experts: int = 256            # the router's width, as published
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    sliding_window: int = 512
+    moe_routed_scaling_factor: float = 2.5
+    layer_types: Tuple[str, ...] = ()
+    mlp_layer_types: Tuple[str, ...] = ()
+    num_attention_heads_per_layer: Tuple[int, ...] = ()
+    full_rope: RopeSpec = RopeSpec()
+    sliding_rope: RopeSpec = RopeSpec()
+    # ids [lo, hi) of the experts this chip holds in every sparse layer
+    experts_held: Tuple[int, int] = (0, 256)
+    compute_dtype: Any = jnp.bfloat16
+    remat: bool = False
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held[1] - self.experts_held[0]
+
+    @classmethod
+    def from_hf(cls, hf: dict, **overrides) -> "LagunaConfig":
+        """From a ``config.json`` in the published key set. A file may
+        state a chip's share beside it: ``experts_held`` ([lo, hi) ids)
+        and, where its ``num_experts`` counts the held ones,
+        ``num_experts_published`` (the router's width). The per-layer
+        lists may be longer than ``num_hidden_layers``: the leading layers
+        are taken. ``overrides`` are this class's own fields."""
+        hf = {**hf, **{k: v for k, v in overrides.items() if k in hf}}
+        L = int(hf["num_hidden_layers"])
+        n_experts = int(hf.get("num_experts_published", hf["num_experts"]))
+        rope = hf["rope_parameters"]
+        heads = hf.get("num_attention_heads_per_layer") or [
+            hf["num_attention_heads"]] * L
+        kw = {f.name: hf[f.name] for f in dataclasses.fields(cls)
+              if f.name in hf}
+        kw.update(
+            num_experts=n_experts,
+            layer_types=tuple(hf["layer_types"][:L]),
+            mlp_layer_types=tuple(hf["mlp_layer_types"][:L]),
+            num_attention_heads_per_layer=tuple(int(h) for h in heads[:L]),
+            full_rope=RopeSpec.from_dict(rope[FULL]),
+            sliding_rope=RopeSpec.from_dict(rope[SLIDING]),
+            experts_held=hf.get("experts_held", (0, n_experts)))
+        kw.update(overrides)
+        kw["experts_held"] = tuple(int(i) for i in kw["experts_held"])
+        return cls(**kw)
+
+    @classmethod
+    def from_json(cls, path: str, **overrides) -> "LagunaConfig":
+        with open(path) as f:
+            return cls.from_hf(json.load(f), **overrides)
+
+
+def rope_tables(spec: RopeSpec, head_dim: int, positions):
+    """(cos, sin), each (S, rotary_dim/2) float32. ``yarn`` follows
+    ``transformers``' ``_compute_yarn_parameters``: interpolated and
+    extrapolated inverse frequencies blended by a linear ramp between the
+    correction dimensions of ``beta_fast`` and ``beta_slow``, cos and sin
+    scaled by ``attention_factor``."""
+    dim = int(head_dim * spec.partial_rotary_factor)
+    base = float(spec.rope_theta)
+    pos_freqs = base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inv_freq, scale = 1.0 / pos_freqs, 1.0
+    if spec.rope_type == "yarn":
+        factor = float(spec.factor)
+        scale = (spec.attention_factor if spec.attention_factor is not None
+                 else (0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0))
+        orig = spec.original_max_position_embeddings
+
+        def correction_dim(rotations):
+            return (dim * math.log(orig / (rotations * 2 * math.pi))
+                    / (2 * math.log(base)))
+
+        low = max(math.floor(correction_dim(spec.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(spec.beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                        / (high - low), 0.0, 1.0)
+        extrapolation = 1.0 - ramp
+        inv_freq = (inv_freq / factor * (1.0 - extrapolation)
+                    + inv_freq * extrapolation)
+    elif spec.rope_type != "default":
+        raise ValueError(f"unknown rope_type {spec.rope_type!r}")
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rope(x, cos, sin):
+    """Rotate the first ``2 * cos.shape[-1]`` dimensions of x (..., S, H,
+    D) in the half-split convention (``rotate_half``); the rest pass."""
+    half = cos.shape[-1]
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
+    return out.astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return xf * lax.rsqrt(var + self.eps) * scale
+
+
+def _dense(features, dt, name):
+    return nn.Dense(features, use_bias=False, dtype=dt, name=name,
+                    kernel_init=nn.initializers.normal(0.02))
+
+
+class SwiGLU(nn.Module):
+    width: int
+    out: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        g = _dense(self.width, self.dtype, "gate_proj")(x)
+        u = _dense(self.width, self.dtype, "up_proj")(x)
+        return _dense(self.out, self.dtype, "down_proj")(nn.silu(g) * u)
+
+
+class ExpertLayer(nn.Module):
+    """Router over all experts, the held experts' part of the sum.
+
+    Every held expert is applied to every token, as one batched product
+    over the held experts, and its output weighted by what the router
+    gave it there: zero where the token did not choose it. No token can be
+    dropped, the shapes are static and so is the time. A dispatch that
+    sorts the routed slots by expert and runs grouped products over the
+    rows in use does a thirty-second of these operations when routing is
+    uniform, and it was built first (PERF.md, PR 28): with a dropless
+    guarantee its time follows the router, whose choices for the tokens of
+    one sequence are strongly correlated, and the round's time moved by 2%
+    from seed to seed. With as many experts held as a token chooses, every
+    token on every held expert is also that dispatch's worst case.
+    ``valid`` (the shape of xn less its last axis) marks the positions
+    that are tokens; the others are given nothing and counted nowhere."""
+    cfg: LagunaConfig
+
+    @nn.compact
+    def __call__(self, xn, valid=None):
+        cfg = self.cfg
+        dt = cfg.compute_dtype
+        E, I = cfg.hidden_size, cfg.moe_intermediate_size
+        lo, hi = cfg.experts_held
+        G, k = cfg.n_held, cfg.num_experts_per_tok
+        lead = xn.shape[:-1]
+        x = xn.reshape(-1, E)                                # (T, E) f32
+        T = x.shape[0]
+        init = nn.initializers.normal(0.02)
+        w_r = self.param("router", init, (E, cfg.num_experts))
+        w_gate = self.param("experts_gate", init, (G, E, I)).astype(dt)
+        w_up = self.param("experts_up", init, (G, E, I)).astype(dt)
+        w_down = self.param("experts_down", init, (G, I, E)).astype(dt)
+
+        logits = jnp.dot(x, w_r.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        top_w = top_p / top_p.sum(-1, keepdims=True)         # (T, k)
+
+        held = (top_e >= lo) & (top_e < hi)
+        if valid is not None:
+            held &= valid.reshape(-1, 1)
+        # (G, T): the router's weight of held expert g on token t, or 0
+        chosen = held[None] & (top_e[None] - lo
+                               == jnp.arange(G)[:, None, None])
+        w = (top_w[None] * chosen).sum(-1)
+        xc = x.astype(dt)
+        h = (nn.silu(jnp.einsum("te,gei->gti", xc, w_gate))
+             * jnp.einsum("te,gei->gti", xc, w_up))
+        # weighted before the down projection, which then sums over the
+        # held experts in float32: no (G, T, E) array
+        y = jnp.einsum("gti,gie->te", h * w[..., None].astype(dt), w_down,
+                       preferred_element_type=jnp.float32)
+        y = y * cfg.moe_routed_scaling_factor
+        tokens = chosen.any(-1).sum(-1)                      # (G,)
+        n_held_slots = held.sum()
+        counts = {"tokens": tokens.astype(jnp.float32),
+                  "held_share": n_held_slots / jnp.float32(T * k),
+                  # routed slots of held experts that got no product
+                  "dropped": (n_held_slots - tokens.sum()).astype(
+                      jnp.float32)}
+        return y.reshape(lead + (E,)).astype(dt), counts
+
+
+class LagunaBlock(nn.Module):
+    cfg: LagunaConfig
+    layer: int
+    attn_impl: Callable = auto_grouped_attention
+
+    @nn.compact
+    def __call__(self, x, positions, valid=None):
+        cfg, i = self.cfg, self.layer
+        dt = cfg.compute_dtype
+        H, KV, D = (cfg.num_attention_heads_per_layer[i],
+                    cfg.num_key_value_heads, cfg.head_dim)
+        sliding = cfg.layer_types[i] == SLIDING
+        rope = cfg.sliding_rope if sliding else cfg.full_rope
+
+        h = RMSNorm(cfg.rms_norm_eps, name="input_norm")(x).astype(dt)
+        heads = lambda t, n: t.reshape(t.shape[:-1] + (n, D))
+        q = heads(_dense(H * D, dt, "q_proj")(h), H)
+        k = heads(_dense(KV * D, dt, "k_proj")(h), KV)
+        v = heads(_dense(KV * D, dt, "v_proj")(h), KV)
+        gate = jax.nn.sigmoid(
+            _dense(H, dt, "g_proj")(h).astype(jnp.float32))
+        cos, sin = rope_tables(rope, D, positions)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        with phase("fed_attention"):
+            o = self.attn_impl(
+                q, k, v, window=cfg.sliding_window if sliding else None)
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+        x = x + _dense(cfg.hidden_size, dt, "o_proj")(
+            o.reshape(o.shape[:-2] + (H * D,)))
+
+        hn = RMSNorm(cfg.rms_norm_eps, name="post_norm")(x)
+        if cfg.mlp_layer_types[i] == "dense":
+            y = SwiGLU(cfg.intermediate_size, cfg.hidden_size, dt,
+                       name="mlp")(hn.astype(dt))
+            counts = None
+        else:
+            with phase("fed_moe"):
+                y, counts = ExpertLayer(cfg, name="moe")(hn, valid)
+            y = y + SwiGLU(cfg.shared_expert_intermediate_size,
+                           cfg.hidden_size, dt,
+                           name="shared_expert")(hn.astype(dt))
+        return x + y, counts
+
+
+class LagunaLM(nn.Module):
+    """``input_ids`` (..., S) -> (hidden (..., S, E) float32 after the
+    final norm, the (V, E) output head, the expert layers' counters).
+    The vocabulary projection is the loss's (``losses._chunked_lm_nll``).
+    ``valid`` (..., S) marks the positions that are tokens (None: all).
+    Padding follows the tokens, attention is causal and the loss puts no
+    label on padding, so what any layer computes at a padded position
+    reaches neither the loss nor a gradient: the expert layers skip them."""
+
+    cfg: LagunaConfig
+    attn_impl: Callable = auto_grouped_attention
+
+    @nn.compact
+    def __call__(self, input_ids, valid=None):
+        cfg = self.cfg
+        embed = self.param("embed_tokens", nn.initializers.normal(0.02),
+                           (cfg.vocab_size, cfg.hidden_size))
+        head = self.param("lm_head", nn.initializers.normal(0.02),
+                          (cfg.vocab_size, cfg.hidden_size))
+        positions = jnp.arange(input_ids.shape[-1])
+        x = embed[input_ids].astype(cfg.compute_dtype)
+        block_cls = (nn.remat(LagunaBlock, static_argnums=())
+                     if cfg.remat else LagunaBlock)
+        per_layer = []
+        for i in range(cfg.num_hidden_layers):
+            x, counts = block_cls(cfg, i, self.attn_impl,
+                                  name=f"layers_{i}")(x, positions, valid)
+            if counts is not None:
+                per_layer.append(counts)
+        hidden = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        return hidden, head, moe_counters(per_layer)
+
+
+def moe_counters(per_layer):
+    """The ``MOE_COUNTERS`` of one forward pass, over held experts and
+    sparse layers; zeros for a model without a sparse layer."""
+    if not per_layer:
+        return {name: jnp.zeros(()) for name in MOE_COUNTERS}
+    tokens = jnp.stack([c["tokens"] for c in per_layer])     # (layers, G)
+    return {
+        "tokens_per_expert_min": tokens.min(),
+        "tokens_per_expert_mean": tokens.mean(),
+        "tokens_per_expert_max": tokens.max(),
+        "held_share": jnp.stack([c["held_share"] for c in per_layer]).mean(),
+        "dropped": jnp.stack([c["dropped"] for c in per_layer]).sum(),
+    }
+
+
+def laguna_model_flops(cfg: LagunaConfig, tokens: int, S: int) -> float:
+    """Forward + backward operations for ``tokens`` positions in sequences
+    of S (2 per multiply-add, backward twice the forward, recomputation
+    not counted): the parameters that act on a position (a held expert at
+    its expected ``top-k x held / experts`` hits), scores and values over
+    min(S, window) keys on window layers and over the causal half on full
+    layers, and the output head; the embedding lookup is not a product."""
+    E, D, KV = cfg.hidden_size, cfg.head_dim, cfg.num_key_value_heads
+    per_tok = cfg.vocab_size * E
+    expert = 3 * E * cfg.moe_intermediate_size
+    for i in range(cfg.num_hidden_layers):
+        H = cfg.num_attention_heads_per_layer[i]
+        per_tok += E * (2 * H * D + 2 * KV * D + H)
+        keys = (min(S, cfg.sliding_window)
+                if cfg.layer_types[i] == SLIDING else S / 2)
+        per_tok += 2 * H * D * keys
+        if cfg.mlp_layer_types[i] == "dense":
+            per_tok += 3 * E * cfg.intermediate_size
+        else:
+            per_tok += (E * cfg.num_experts
+                        + 3 * E * cfg.shared_expert_intermediate_size
+                        + expert * cfg.num_experts_per_tok * cfg.n_held
+                        / cfg.num_experts)
+    return 3.0 * 2.0 * per_tok * tokens

@@ -450,16 +450,6 @@ def make_circulant_sketch(d: int, c: int, r: int, num_blocks: int = 1,
     coordinates still never collide, in either construction.)"""
     rng = np.random.RandomState(seed)
     m = -(-d // c)
-    if m > CirculantSketch._UNROLL_MAX_BLOCKS:
-        import warnings
-        warnings.warn(
-            f"circulant sketch with m = ceil(d/c) = {m} blocks exceeds "
-            f"_UNROLL_MAX_BLOCKS={CirculantSketch._UNROLL_MAX_BLOCKS}: "
-            "encode/decode fall back from static rolls to a "
-            "take_along_axis gather, which is ~100x slower on TPU "
-            "(measured 2,673 ms/op at d=124M in the gather regime vs "
-            "26 ms static-roll encode). Increase num_cols so that "
-            "d/num_cols <= 512.", stacklevel=2)
     if c % 1024 == 0:
         shifts = tuple(
             tuple(int(s) * 1024 for s in rng.randint(0, c // 1024, size=m))
@@ -471,6 +461,18 @@ def make_circulant_sketch(d: int, c: int, r: int, num_blocks: int = 1,
                             dtype=np.uint64).astype(np.uint32) | 1
     cs = CirculantSketch(jnp.asarray(sign_keys), shifts, d=d, c=c, r=r,
                          num_blocks=num_blocks, pallas=pallas)
+    if m > CirculantSketch._UNROLL_MAX_BLOCKS and cs.kernel_path == "xla":
+        # the Pallas kernels take m as a grid length and serve any m
+        import warnings
+        warnings.warn(
+            f"circulant sketch with m = ceil(d/c) = {m} blocks exceeds "
+            f"_UNROLL_MAX_BLOCKS={CirculantSketch._UNROLL_MAX_BLOCKS} on "
+            "the XLA path: encode/decode fall back from static rolls to a "
+            "take_along_axis gather, which is ~100x slower on TPU "
+            "(measured 2,673 ms/op at d=124M in the gather regime vs "
+            "26 ms static-roll encode). Increase num_cols so that "
+            "d/num_cols <= 512, or pick a geometry the Pallas kernels "
+            f"serve ({cs.pallas_blocker()}).", stacklevel=2)
     if pallas == "on" and jax.default_backend() == "tpu":
         blocker = cs.pallas_blocker()
         if blocker is not None:

@@ -20,6 +20,7 @@ wrapper into pass-through mode for that function, logging one final
 
 from __future__ import annotations
 
+import re
 import time
 from typing import Any, Callable, Dict
 
@@ -28,8 +29,33 @@ import jax
 # latest compiled executable per watched name, process-wide: whoever has
 # no handle on the runtime (a metric reader, an operator's notebook) reads
 # the HLO of the program that is running — same pattern as
-# tracing.current(). The executable object only: no copy, no text kept.
+# tracing.current(). The executable object only (behind ``WatchedText``):
+# no copy, no text kept.
 LATEST: Dict[str, Any] = {}
+
+# a Pallas kernel's ``kernel_metadata`` frontend attribute is printed as
+# JSON with line breaks, which puts the ``metadata={op_name=...}`` of
+# that custom call on a line of its own
+_BROKEN_LINE = re.compile(r'\n(?="|\}\})')
+
+
+class WatchedText:
+    """A watched executable as its readers hold it: the executable's own
+    attributes, and ``as_text()`` with every instruction on ONE line.
+    Readers join device events to phases line by line
+    (perfbench/harness/phase_reader.py); an instruction that spans three
+    lines (jax's splash attention kernels do, see ``_BROKEN_LINE``) would
+    lose its ``op_name`` to them and count with whatever calls its
+    computation."""
+
+    def __init__(self, compiled):
+        self._compiled = compiled
+
+    def __getattr__(self, name):
+        return getattr(self._compiled, name)
+
+    def as_text(self) -> str:
+        return _BROKEN_LINE.sub(" ", self._compiled.as_text())
 
 
 def latest(name: str) -> Any:
@@ -139,7 +165,8 @@ class JitWatcher:
                     emit(len(cache), 0.0, 0.0, {}, fallback=True)
                     return fn(*args)
                 cache[key] = compiled
-                self.executables[name] = LATEST[name] = compiled
+                self.executables[name] = LATEST[name] = WatchedText(
+                    compiled)
                 emit(len(cache), t1 - t0, t2 - t1,
                      _cost_analysis(compiled))
                 # collective ledger of the fresh executable (count/kind/

@@ -138,6 +138,12 @@ def _coarse_name(comps: List[str], ndim: int) -> str:
         if "head" in c or c in ("classifier", "score", "logits"):
             return "head"
     top = comps[0] if comps else "params"
+    if last.startswith("experts_"):
+        # a routed expert layer's stacked expert matrices
+        # (models/laguna.py): block-sparse by client, a third to a half
+        # of d: their share of the k sent coordinates beside their share
+        # of d is ``topk_count`` beside ``sizes`` of this group
+        return f"{top}/experts"
     if ndim <= 1 or last in ("bias", "scale", "b", "g"):
         return f"{top}/norm-bias"
     return top

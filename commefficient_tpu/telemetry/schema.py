@@ -20,7 +20,7 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, Iterable, List, Tuple
 
-SCHEMA_VERSION = 11
+SCHEMA_VERSION = 12
 # streams written by older code stay readable: v1 lacks the span /
 # utilization event types (added in v2), v2 lacks client_stats / alert
 # (added in v3), v3 lacks async_round (added in v4), v4 lacks defense
@@ -38,11 +38,13 @@ SCHEMA_VERSION = 11
 # layer-wise compression attribution stream, added in v10 — a new type,
 # no vintage-gated field additions), v10 lacks the population event
 # type and the client_stats `estimated` flag (population-scale sketch
-# observability, added in v11 — FIELDS_SINCE_V11), but each is
+# observability, added in v11 — FIELDS_SINCE_V11), v11 lacks the round
+# event's `moe` counters (the routed expert layers of models/laguna.py,
+# added in v12 — FIELDS_SINCE_V12), but each is
 # otherwise a subset of its successor — so the validator accepts any
 # supported manifest version. A version it does not know is the error,
 # not a version merely older than current.
-SUPPORTED_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10,
+SUPPORTED_SCHEMA_VERSIONS = (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11,
                              SCHEMA_VERSION)
 TELEMETRY_BASENAME = "telemetry.jsonl"
 
@@ -123,6 +125,11 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
         "host_s": _num,               # host batch assembly
         "dispatch_s": _num,           # jitted-call return (async dispatch)
         "device_s": _num,             # block_until_ready remainder
+        # schema v12: counters of the routed expert layers over the
+        # round's items (tokens_per_expert_min / _mean / _max over held
+        # experts and sparse layers, held_share of the tokens x top-k
+        # routed slots, dropped: always 0); null without such a layer
+        "moe": _opt_dict,
     },
     # per-epoch validation record (mirrors the console table row);
     # loss/acc metrics are null if non-finite (e.g. a NaN val sweep that
@@ -548,6 +555,16 @@ FIELDS_SINCE_V11: Dict[str, Tuple[str, ...]] = {
 }
 
 
+# fields ADDED in schema v12 (the routed expert layers' counters on the
+# round event) — same vintage-gated requirement
+FIELDS_SINCE_V12: Dict[str, Tuple[str, ...]] = {
+    "round": ("moe",),
+}
+
+MOE_COUNTER_FIELDS = ("tokens_per_expert_min", "tokens_per_expert_mean",
+                      "tokens_per_expert_max", "held_share", "dropped")
+
+
 def validate_event(obj: Any,
                    version: int = SCHEMA_VERSION) -> List[str]:
     """Return a list of problems with one decoded event (empty = valid).
@@ -575,6 +592,7 @@ def validate_event(obj: Any,
     v8_only = FIELDS_SINCE_V8.get(kind, ())
     v9_only = FIELDS_SINCE_V9.get(kind, ())
     v11_only = FIELDS_SINCE_V11.get(kind, ())
+    v12_only = FIELDS_SINCE_V12.get(kind, ())
     for field, pred in spec.items():
         if field not in obj:
             if version < 6 and field in v6_only:
@@ -587,11 +605,17 @@ def validate_event(obj: Any,
                 continue
             if version < 11 and field in v11_only:
                 continue
+            if version < 12 and field in v12_only:
+                continue
             problems.append(f"{kind}: missing field {field!r}")
         elif not pred(obj[field]):
             problems.append(
                 f"{kind}: field {field!r} fails its type check "
                 f"(got {type(obj[field]).__name__})")
+    if kind == "round" and isinstance(obj.get("moe"), dict):
+        for field in MOE_COUNTER_FIELDS:
+            if not _num(obj["moe"].get(field)):
+                problems.append(f"round: moe.{field} is not a number")
     return problems
 
 

@@ -1,0 +1,12 @@
+"""moe_ms: the routed expert layer (scope fed_moe, nested in the client
+step). Nothing where the program names no such phase."""
+
+from perfbench.harness import phase_reader
+
+PHASE = "fed_moe"
+
+
+def read(ctx):
+    if PHASE not in phase_reader.program_phases():
+        return None
+    return phase_reader.phase_ms(ctx, (PHASE,))

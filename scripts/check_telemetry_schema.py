@@ -170,6 +170,11 @@ _SAMPLE_OVERRIDES = {
     "cm_delta": 0.0183,
     "hh_k": 256,
     "sample_size": 4096,
+    # round (schema v12): the routed expert layers' counters of one
+    # round at one chip's share, 8 of 256 experts held (models/laguna.py)
+    "moe": {"tokens_per_expert_min": 96.0, "tokens_per_expert_mean": 128.4,
+            "tokens_per_expert_max": 171.0, "held_share": 0.0313,
+            "dropped": 0.0},
     # alert: a fired statistical rule
     "rule": "loss_spike",
     "severity": "warn",

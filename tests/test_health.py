@@ -276,8 +276,9 @@ def test_alert_events_written_and_schema_valid(tmp_path):
     # forwarded by event(), alert written back into the same stream
     for i, loss in enumerate([2.0] * 12 + [50.0], start=1):
         tel.event("round", round=i, epoch=1, lr=0.1, loss=loss, acc=0.5,
-                  n_valid=4.0, download_bytes=None, upload_bytes=None,
-                  host_s=0.0, dispatch_s=0.0, device_s=0.0)
+                  n_valid=4.0, moe=None, download_bytes=None,
+                  upload_bytes=None, host_s=0.0, dispatch_s=0.0,
+                  device_s=0.0)
     tel.write_summary(aborted=False, n_rounds=13)
     tel.close()
     assert validate_file(tel.path) == []

@@ -300,6 +300,24 @@ class TestCirculantSketch:
                                    np.asarray(cs.decode(t_gather)),
                                    atol=1e-5)
 
+    @pytest.mark.parametrize("path,warns", [("xla", True),
+                                            ("pallas", False)])
+    def test_block_count_warning_is_the_xla_paths(self, monkeypatch, path,
+                                                  warns):
+        """Past _UNROLL_MAX_BLOCKS the XLA path falls to a gather and says
+        so; the Pallas kernels take m as a grid length (m = 744 in the
+        d = 3.9e8 cell) and the warning would be false there."""
+        import warnings
+        from commefficient_tpu.ops import circulant as circ
+        monkeypatch.setattr(circ.CirculantSketch, "_UNROLL_MAX_BLOCKS", 8)
+        monkeypatch.setattr(circ.CirculantSketch, "kernel_path",
+                            property(lambda self: path))
+        with warnings.catch_warnings(record=True) as seen:
+            warnings.simplefilter("always")
+            circ.make_circulant_sketch(d=119, c=2, r=3, seed=3)   # m = 60
+        said = [w for w in seen if "_UNROLL_MAX_BLOCKS" in str(w.message)]
+        assert bool(said) is warns, [str(w.message) for w in seen]
+
     def test_aligned_shift_granularity(self):
         """c % 1024 == 0 => shifts are multiples of 1024 (the pallas
         no-rotate enabler); unaligned c keeps full-range shifts."""

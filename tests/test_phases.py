@@ -17,7 +17,8 @@ from commefficient_tpu.config import FedConfig
 from commefficient_tpu.core import FedRuntime
 from commefficient_tpu.parallel import make_mesh
 from commefficient_tpu.telemetry import compilewatch
-from commefficient_tpu.telemetry.profiling import PHASES, phase
+from commefficient_tpu.telemetry.profiling import (MODEL_PHASES, PHASES,
+                                                   phase)
 from perfbench.harness import phase_reader, readers, spec
 
 W, B, D_IN, D_OUT = 4, 4, 6, 3
@@ -80,6 +81,7 @@ def phases_of(table):
 def test_default_round_names_every_phase_of_its_mode(mode):
     _rt, table = run_round(mode)
     want = set(PHASES) - {"fed_table_reduce"}       # mesh only
+    want -= set(MODEL_PHASES)      # this model has no such layers
     if mode != "sketch":
         want -= {"fed_sketch_encode"}
     assert phases_of(table) == want
@@ -112,7 +114,7 @@ def test_innermost_scope_names_the_instruction():
 def test_unknown_phase_raises():
     with pytest.raises(ValueError, match="nonsense"):
         phase("nonsense")
-    assert len(set(PHASES)) == len(PHASES) == 8
+    assert len(set(PHASES)) == len(PHASES) == 10
     assert all(p.startswith("fed_") for p in PHASES)
     with phase("fed_signals"):                    # a known one is a scope
         pass

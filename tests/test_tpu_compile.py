@@ -36,10 +36,11 @@ def one_chip():
 @pytest.mark.parametrize("c,r,m,widest", [
     (500736, 5, 51, False),
     (524288, 5, 238, False),
+    (524288, 5, 744, False),
     (3140608, 1, 3, True),
     (627712, 5, 4, True),
-], ids=["rn50_sketch_8x64", "gpt2_sketch_8x8x2x256", "widest_r1",
-        "widest_r5"])
+], ids=["rn50_sketch_8x64", "gpt2_sketch_8x8x2x256",
+        "laguna_sketch_8x1x4096", "widest_r1", "widest_r5"])
 def test_encode_compiles_with_table_resident(one_chip, c, r, m, widest):
     assert cp.table_vmem_bytes(c, r) <= cp.TABLE_VMEM_BUDGET
     if widest:
@@ -53,3 +54,17 @@ def test_encode_compiles_with_table_resident(one_chip, c, r, m, widest):
     # the table is the kernel's one output, whole: no lane-tile axis is
     # left in the grid for the input to be streamed along
     assert f"f32[{r},{c // 128},128]" in hlo
+
+
+def test_decode_compiles_past_the_xla_paths_block_limit(one_chip):
+    """d = 389,634,048 over c = 524,288 is m = 744 blocks, past the XLA
+    path's _UNROLL_MAX_BLOCKS: the Pallas kernels take m as a grid length
+    (the encode at this m is a case above)."""
+    c, r, m = 524288, 5, 744
+    args = (jax.ShapeDtypeStruct((r, c), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((r, m), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((r,), jnp.uint32, sharding=one_chip))
+    hlo = cp.pallas_decode.lower(*args, c=c, r=r, m=m).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert cp.DECODE_KERNEL_NAME in hlo
+    assert f"f32[{m * c}]" in hlo

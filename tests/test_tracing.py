@@ -288,7 +288,7 @@ def test_span_and_utilization_schema_roundtrip(tmp_path):
     ut = next(e for e in events if e["event"] == "utilization")
     assert ut["straggler_spread"] == pytest.approx(1.0)
     man = events[0]
-    assert man["schema"] == SCHEMA_VERSION == 11
+    assert man["schema"] == SCHEMA_VERSION == 12
 
 
 def test_v1_streams_stay_readable():
