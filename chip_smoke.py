@@ -138,10 +138,11 @@ def check_on_chip(stage, runtime, state, n_devices=1):
         assert n_mosaic >= 2, n_mosaic
         assert ENCODE_KERNEL_NAME in hlo and DECODE_KERNEL_NAME in hlo
     else:
-        # on a mesh the server tail decodes per shard with the range
-        # gather (core/server.sharded_sketch_server_update): the encode
-        # kernel is the one Mosaic call left in the round
+        # on a mesh the sharded server tail decodes its range with the
+        # decode kernel too (core/server.sharded_sketch_server_update);
+        # the replicated tail, under GSPMD, with XLA rolls
         assert n_mosaic >= 1 and ENCODE_KERNEL_NAME in hlo, n_mosaic
+        assert (DECODE_KERNEL_NAME in hlo) == runtime._server_tail_pallas
         for name in ("ps_weights", "Vvelocity", "Verror"):
             span = len(getattr(state, name).sharding.device_set)
             assert span == n_devices, (name, span, n_devices)

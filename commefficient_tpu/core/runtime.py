@@ -341,12 +341,13 @@ class FedRuntime:
                 "unavailable for this configuration (use auto to fall "
                 "back to the replicated tail instead):\n  "
                 + "\n  ".join(ss_problems))
-        # The replicated server tail on a mesh decodes under GSPMD, and
+        # The REPLICATED server tail on a mesh decodes under GSPMD, and
         # a Mosaic kernel cannot be partitioned automatically (the TPU
         # lowering raises "Mosaic kernels cannot be automatically
         # partitioned. Please wrap the call in a shard_map." — met on
-        # four v5e chips, PR 21). That tail's decode takes the XLA rolls;
-        # the client block's encode, inside shard_map, keeps its kernel.
+        # four v5e chips, PR 21). That tail's decode takes the XLA rolls.
+        # What runs inside shard_map keeps its kernels: the client
+        # block's encode, and the sharded tail's range decode (below).
         self._server_tail_xla = (
             cfg.mode == "sketch" and cfg.sketch_impl == "circ"
             and mesh is not None and not self._sharded_server
@@ -362,6 +363,25 @@ class FedRuntime:
             print("sketch kernel path, server tail: xla (replicated tail "
                   "on a mesh runs under GSPMD, which cannot partition a "
                   "Mosaic call)")
+        # The SHARDED tail runs inside shard_map, so its range decode
+        # (CirculantSketch.decode_range) takes the Pallas decode kernel
+        # over the blocks that cover the chip's d_pad/n coordinates
+        # wherever the kernels serve the sketch, and the gather form
+        # elsewhere. Every round or none: this line is its hit share.
+        self._server_tail_pallas = False
+        if self._sharded_server and cfg.sketch_impl == "circ":
+            blocker = self.cs.pallas_blocker()
+            self._server_tail_pallas = blocker is None
+            if blocker is None:
+                from commefficient_tpu.ops.circulant_pallas import (
+                    range_cover_blocks)
+                nb = range_cover_blocks(
+                    self.cs.c, self.d_pad // mesh.shape[self._axis])
+                print("sketch kernel path, server tail: pallas (range "
+                      f"decode, {nb} of {self.cs.m} blocks a chip)")
+            else:
+                print("sketch kernel path, server tail: xla (gather "
+                      f"form: {blocker})")
         # --decode_overlap composition: the table reduce itself MOVES
         # into the decode executable — the cohort ends at each device's
         # LOCAL partial table, so the round's metrics sync completes

@@ -335,16 +335,36 @@ class CirculantSketch:
 
         ``start`` may be a TRACED scalar (the sharded server tail's
         ``axis_index``-dependent slice, core/server.py) — the static
-        per-block shifts cannot be selected at trace time then, so this
-        runs the ``decode_at`` gather form (the ONE shared bucket/sign
-        definition) chunk by chunk: peak memory O(r * chunk), no
-        (d,)-sized buffer. Same estimate values as the static-roll
-        decode — rolls and gathers move the same table cells.
+        per-block shifts cannot be selected at trace time then. Which
+        form runs is decided where ``decode`` decides it, from what the
+        code sees (backend, c, shifts: ``pallas_blocker``):
+
+        - the Pallas kernels serve the sketch (the TPU): the decode
+          kernel over the whole blocks that cover the range, first
+          block a prefetched scalar, then one ``dynamic_slice``
+          (ops/circulant_pallas.pallas_decode_range) — 14 of 51 blocks
+          a chip at d = 25.5M over four chips;
+        - otherwise (the CPU, ``--pallas off``, unaligned c, one
+          block): the ``decode_at`` gather form (the ONE shared
+          bucket/sign definition) chunk by chunk: peak memory
+          O(r * chunk), no (d,)-sized buffer. On the TPU that is one
+          element at a time (9.4 ns each, 295 ms a round at that d).
+
+        Same estimate values either way — kernel, rolls and gathers
+        move the same table cells through the same median.
         """
         assert table.shape == self.table_shape, (table.shape,
                                                  self.table_shape)
         assert length >= 1, length
         start = jnp.asarray(start, jnp.int32)
+        if self._use_pallas_decode():
+            from commefficient_tpu.ops.circulant_pallas import (
+                pallas_decode_range)
+            ests = pallas_decode_range(
+                table, jnp.asarray(self.shifts, jnp.int32), self.sign_keys,
+                start, c=self.c, r=self.r, length=length)
+            idx = start + jnp.arange(length, dtype=jnp.int32)
+            return jnp.where(idx < self.d, ests, 0.0)
         bl = min(self.c, length)
         nb = -(-length // bl)
         base = jnp.arange(bl, dtype=jnp.int32)

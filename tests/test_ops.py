@@ -380,6 +380,83 @@ class TestCirculantSketch:
         np.testing.assert_allclose(np.asarray(d_pl),
                                    np.asarray(cs.decode(t_roll)), atol=1e-5)
 
+    # (start, length) at c = 3072 in three lane tiles of 1024, d = 16,000,
+    # m = 6, so m*c = 18,432; only this test decodes at c = 3072, so the
+    # jit cache (keyed on c, r, nb, interpret) holds no other tile for it
+    @pytest.mark.parametrize("start,length", [
+        (3072, 6144),      # block-aligned: the cover's third block is slack
+        (4000, 5000),      # starts and ends in mid-block
+        (0, 16000),        # the whole vector: blocks 0..5 and one past
+        (13000, 5600),     # crosses d, then m*c: the cover runs to block 7
+        (17000, 40),       # every coordinate >= d
+    ], ids=["block_aligned", "mid_block", "whole_d", "cover_past_block_m",
+            "all_past_d"])
+    def test_pallas_range_decode_bit_exact(self, monkeypatch, start, length):
+        """``decode_range`` on the Pallas entry (whole covering blocks
+        through the decode kernel, first block a traced scalar, one
+        slice; interpret mode here) against the gather form and the
+        static-roll ``decode``: the same bits on every coordinate < d,
+        exactly 0 from d on — also where the cover reads past block
+        m - 1 of the shift table."""
+        import functools
+        from commefficient_tpu.ops import circulant as circ
+        from commefficient_tpu.ops import circulant_pallas as cp
+        monkeypatch.setattr(cp, "_CT_MAX", 1024)
+        cs = circ.make_circulant_sketch(d=16000, c=3072, r=5, seed=29)
+        assert cs.m == 6 and cp._lane_tile(cs.c) == 1024
+        rng = np.random.RandomState(29)
+        table = cs.encode(jnp.asarray(rng.randn(cs.d).astype(np.float32)))
+        want = np.zeros(start + length, np.float32)
+        want[:cs.d] = np.asarray(cs.decode(table))[:start + length]
+        want = want[start:]
+        gather = np.asarray(jax.jit(
+            lambda t, s: cs.decode_range(t, s, length))(table,
+                                                        jnp.int32(start)))
+        np.testing.assert_array_equal(gather, want)
+        monkeypatch.setattr(circ.CirculantSketch, "_use_pallas_decode",
+                            lambda self: True)
+        monkeypatch.setattr(cp, "pallas_decode_range", functools.partial(
+            cp.pallas_decode_range, interpret=True))
+        f = jax.jit(lambda t, s: cs.decode_range(t, s, length))
+        text = f.lower(table, jnp.int32(start)).as_text()
+        assert "gather" not in text and "dynamic_slice" in text
+        kernel = np.asarray(f(table, jnp.int32(start)))
+        np.testing.assert_array_equal(kernel, want)
+        assert cp.range_cover_blocks(cs.c, length) == max(
+            (o + length - 1) // cs.c + 1 for o in range(cs.c))
+
+    @pytest.mark.parametrize("c,d,cap", [
+        (2048, 9500, None),      # one lane tile, m = 5
+        (4096, 30000, 1024),     # four lane tiles, m = 8
+    ], ids=["one_tile", "four_tiles"])
+    def test_pallas_whole_decode_is_the_block_entry_at_zero(
+            self, monkeypatch, c, d, cap):
+        """``pallas_decode`` is ``pallas_decode_blocks`` at first block 0
+        over all m blocks — one kernel — and equals the static rolls bit
+        for bit; a traced first block > 0 gives that stretch of it."""
+        from commefficient_tpu.ops import circulant as circ
+        from commefficient_tpu.ops import circulant_pallas as cp
+        if cap is not None:
+            monkeypatch.setattr(cp, "_CT_MAX", cap)
+        cs = circ.make_circulant_sketch(d=d, c=c, r=5, seed=c + d)
+        rng = np.random.RandomState(d)
+        table = cs.encode(jnp.asarray(rng.randn(d).astype(np.float32)))
+        shifts = jnp.asarray(cs.shifts, jnp.int32)
+        whole = np.asarray(cp.pallas_decode(
+            table, shifts, cs.sign_keys, c=c, r=cs.r, m=cs.m,
+            interpret=True))
+        blocks = np.asarray(cp.pallas_decode_blocks(
+            table, shifts, cs.sign_keys, 0, c=c, r=cs.r, nb=cs.m,
+            interpret=True))
+        np.testing.assert_array_equal(whole, blocks)
+        np.testing.assert_array_equal(whole[:d], np.asarray(cs.decode(table)))
+        two = jax.jit(lambda t, b: cp.pallas_decode_blocks(
+            t, shifts, cs.sign_keys, b, c=c, r=cs.r, nb=2, interpret=True))
+        for b in (1, cs.m - 2):
+            np.testing.assert_array_equal(
+                np.asarray(two(table, jnp.int32(b))),
+                whole[b * c:(b + 2) * c])
+
     @staticmethod
     def _encode_in_block_order(cs, v):
         """Plain reference of the encode kernel's arithmetic: every table

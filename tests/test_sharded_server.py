@@ -129,6 +129,54 @@ def test_decode_range_bf16_wire_table(impl):
     assert np.array_equal(got, full[64:464]), impl
 
 
+def _kernel_range_decode(monkeypatch):
+    """Steer ``CirculantSketch.decode_range`` onto the Pallas entry the
+    TPU takes, in interpret mode: the CPU has no Mosaic."""
+    import functools
+    from commefficient_tpu.ops import circulant as circ
+    from commefficient_tpu.ops import circulant_pallas as cp
+    monkeypatch.setattr(circ.CirculantSketch, "_use_pallas_decode",
+                        lambda self: True)
+    monkeypatch.setattr(cp, "pallas_decode_range", functools.partial(
+        cp.pallas_decode_range, interpret=True))
+
+
+# d = 10,199 over c = 2,048 is m = 5 blocks; the shards' edges fall in
+# mid-block, and the last shards' covers run past block 4
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_kernel_range_decode_inside_shard_map(monkeypatch, n):
+    """The sharded tail's step 3 as the TPU runs it: every device
+    decodes the whole blocks that cover its ``axis_index``-dependent
+    range with the decode kernel (interpret mode) and slices its
+    d_pad/n coordinates out; the concatenated shards are the full
+    decode bit for bit, and the mesh padding reads exactly 0."""
+    from commefficient_tpu.ops.circulant_pallas import range_cover_blocks
+    from jax.sharding import PartitionSpec as P
+    d, c = 10199, 2048
+    d_pad = -(-d // n) * n
+    blk = d_pad // n
+    rng = np.random.RandomState(n)
+    cs = make_circulant_sketch(d, c, 5, seed=n)
+    table = cs.encode(jnp.asarray(rng.randn(d), jnp.float32))
+    full = np.asarray(cs.decode(table))
+    assert (n - 1) * blk // c + range_cover_blocks(c, blk) > cs.m
+    _kernel_range_decode(monkeypatch)
+    mesh = make_mesh((n,), ("clients",))
+
+    def block(t, cs):
+        i = jax.lax.axis_index("clients")
+        return cs.decode_range(t, i * blk, blk)
+
+    fn = jax.jit(shard_map(
+        block, mesh=mesh, in_specs=(P(), jax.tree.map(lambda _: P(), cs)),
+        out_specs=P("clients"), check_vma=False))
+    assert "gather" not in fn.lower(table, cs).as_text()
+    got = np.asarray(fn(table, cs))
+    assert got.shape == (d_pad,)
+    assert np.array_equal(got[:d], full)
+    assert (got[d:] == 0).all()
+
+
 # ------------------------------------------------------- top-k merge
 
 
@@ -204,9 +252,8 @@ def test_merge_rejects_insufficient_candidates():
 # ------------------------------------------------- round-level parity
 
 
-def _params_and_loss():
+def _params_and_loss(D=24, C=10):
     key = jax.random.PRNGKey(0xABCD)
-    D, C = 24, 10
     P_mat = jax.random.normal(jax.random.fold_in(key, 1), (D, C),
                               jnp.float32)
 
@@ -252,10 +299,11 @@ def _sketch_cfg(**kw):
 SHARDED_W_RTOL, SHARDED_W_ATOL = 1e-4, 1e-6
 
 
-def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None, w_first=None):
+def _run_rounds(cfg, n_rounds=4, lr=0.1, adapter=None, w_first=None,
+                dims=(24, 10)):
     """``w_first``, when a list, receives the flat weights after round 1
     (momentum is still zero there, so nothing can contract)."""
-    params, loss_fn, batch_for = _params_and_loss()
+    params, loss_fn, batch_for = _params_and_loss(*dims)
     mesh = make_mesh((8,), ("clients",))
     rt = FedRuntime(cfg, params, loss_fn, num_clients=cfg.num_clients,
                     mesh=mesh)
@@ -299,6 +347,65 @@ def test_sharded_round_matches_replicated(variant):
     np.testing.assert_allclose(losses_s, losses_r, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(w_s, w_r, rtol=SHARDED_W_RTOL,
                                atol=SHARDED_W_ATOL)
+
+
+@pytest.mark.parametrize("variant", [
+    {},                                   # zero-EF
+    {"sketch_ef": "subtract"},
+])
+def test_sharded_round_kernel_decode_matches_gather_form(monkeypatch,
+                                                         variant):
+    """The sharded tail with the decode kernel under it (interpret
+    mode; d = 2,400 over c = 1,024, eight shards of 300 in mid-block)
+    trains like the same tail on the gather form, and like the
+    replicated tail: the estimates are the same bits, so round 1 is
+    bitwise and the later rounds hold to SHARDED_W_*TOL."""
+    kw = dict(num_cols=1024, exact_num_cols=True, k=40, **variant)
+    dims = (120, 20)
+    runs = {}
+    for name in ("gather", "replicated", "kernel"):
+        if name == "kernel":
+            _kernel_range_decode(monkeypatch)
+        w1 = []
+        cfg = _sketch_cfg(sketch_sharded_server="off"
+                          if name == "replicated" else "auto", **kw)
+        rt, losses, w = _run_rounds(cfg, w_first=w1, dims=dims)
+        assert rt._sharded_server is (name != "replicated")
+        assert rt.cs.m == 3 and rt.cs.c == 1024
+        runs[name] = (w1[0], losses, w)
+    for other in ("gather", "replicated"):
+        assert (runs["kernel"][0] == runs[other][0]).all(), other
+        assert (runs["kernel"][1][:2] == runs[other][1][:2]).all(), other
+        np.testing.assert_allclose(runs["kernel"][2], runs[other][2],
+                                   rtol=SHARDED_W_RTOL, atol=SHARDED_W_ATOL)
+    assert np.abs(runs["kernel"][2]).max() > 0
+
+
+@pytest.mark.parametrize("served", [False, True])
+def test_runtime_says_which_decode_the_sharded_tail_takes(monkeypatch,
+                                                          capsys, served):
+    """Decided once at __init__, printed beside ``sketch kernel path``
+    and kept as ``_server_tail_pallas``: the range decode on the Pallas
+    kernel wherever the kernels serve the sketch, the gather form with
+    its blocker elsewhere (here: the CPU)."""
+    from commefficient_tpu.ops import circulant as circ
+    if served:
+        monkeypatch.setattr(circ.CirculantSketch, "pallas_blocker",
+                            lambda self: None)
+    params, loss_fn, _ = _params_and_loss(120, 20)
+    cfg = _sketch_cfg(num_cols=1024, exact_num_cols=True)
+    rt = FedRuntime(cfg, params, loss_fn, num_clients=cfg.num_clients,
+                    mesh=make_mesh((8,), ("clients",)))
+    out = capsys.readouterr().out
+    assert rt._sharded_server and rt._server_tail_pallas is served
+    assert not rt._server_tail_xla
+    if served:
+        # shards of 300 coordinates, c = 1,024: at most 2 blocks of 3
+        assert ("sketch kernel path, server tail: pallas (range decode, "
+                "2 of 3 blocks a chip)") in out
+    else:
+        assert ("sketch kernel path, server tail: xla (gather form: "
+                "backend is 'cpu', not 'tpu')") in out
 
 
 def test_sharded_round_per_param_lr_vector():

@@ -12,20 +12,26 @@ the worker given this file does.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from commefficient_tpu.ops import circulant_pallas as cp
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -68,3 +74,78 @@ def test_decode_compiles_past_the_xla_paths_block_limit(one_chip):
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     assert cp.DECODE_KERNEL_NAME in hlo
     assert f"f32[{m * c}]" in hlo
+
+
+@pytest.mark.parametrize("c,r,m", [
+    (500736, 5, 51),
+    (524288, 5, 238),
+    (524288, 5, 744),
+], ids=["rn50_sketch_8x64", "gpt2_sketch_8x8x2x256",
+        "laguna_sketch_8x1x4096"])
+def test_whole_decode_compiles_on_the_block_range_kernel(one_chip, c, r, m):
+    """The one-chip cells' decode is the block-range kernel at first
+    block 0 over all m blocks: one Mosaic call under the same name, the
+    first block one more prefetched scalar."""
+    args = (jax.ShapeDtypeStruct((r, c), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((r, m), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((r,), jnp.uint32, sharding=one_chip))
+    hlo = cp.pallas_decode.lower(*args, c=c, r=r, m=m).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert cp.DECODE_KERNEL_NAME in hlo
+    ct = cp._lane_tile(c)
+    assert f"f32[{m},{c // ct},{ct // 128},128]" in hlo
+    assert "s32[1]" in hlo
+
+
+def test_sharded_server_tail_decodes_with_the_kernel(topo, monkeypatch):
+    """``sharded_sketch_server_update`` under ``shard_map`` over the four
+    described chips at the mesh cell's geometry (d = 25,504,026,
+    c = 500,736, r = 5): the range decode is ONE Mosaic call named
+    ``circulant_sketch_decode`` over 14 of the 51 blocks, and no gather
+    produces a c-long span (the gather form scanned 13 chunks of five
+    such gathers, 295 of a 372 ms round on the chips). The backend is
+    steered here, not in the program: the code under test asks
+    ``jax.default_backend()`` and this process holds the CPU."""
+    from jax import shard_map
+    from commefficient_tpu.config import FedConfig
+    from commefficient_tpu.core.server import sharded_sketch_server_update
+    from commefficient_tpu.ops.circulant import make_circulant_sketch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d, c, r, n = 25504026, 500736, 5, 4
+    d_pad = -(-d // n) * n
+    cfg = FedConfig(mode="sketch", error_type="virtual", local_momentum=0.0,
+                    virtual_momentum=0.9, k=50000, num_rows=r, num_cols=c,
+                    approx_topk=True)
+    cs = make_circulant_sketch(d, c, r, seed=42)
+    assert cs.pallas_blocker() is None and cs.m == 51
+    nb = cp.range_cover_blocks(c, d_pad // n)
+    assert nb == 14
+    mesh = Mesh(np.array(topo.devices), ("clients",))
+
+    def blk(agg, vvel, verr, lr, cs):
+        return sharded_sketch_server_update(
+            cfg, agg, vvel, verr, lr, cs, axis="clients", n_shards=n,
+            d_pad=d_pad)
+
+    tab = P(None, "clients")
+    fn = jax.jit(shard_map(
+        blk, mesh=mesh,
+        in_specs=(tab, tab, tab, P(), jax.tree.map(lambda _: P(), cs)),
+        out_specs=(P("clients"), tab, tab), check_vma=False))
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    t = sds((r, c), jnp.float32, tab)
+    hlo = fn.lower(t, t, t, sds((), jnp.float32, P()),
+                   jax.tree.map(lambda a: sds(a.shape, a.dtype, P()), cs)
+                   ).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and cp.DECODE_KERNEL_NAME in calls[0]
+    ct = cp._lane_tile(c)
+    assert f"f32[{nb},{c // ct},{ct // 128},128]" in calls[0]
+    spans = [line for line in hlo.splitlines()
+             if " gather(" in line and f"f32[{c}]" in line]
+    assert not spans, spans[:2]
