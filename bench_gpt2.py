@@ -45,7 +45,7 @@ NOMINAL_SINGLE_GPU_TOK_PER_SEC = 4500.0
 
 def run(remat: bool = True, telemetry=None, profiler=None, *,
         remat_policy: str = "", microbatch: int = 8, lm_chunk: int = 128,
-        fused_encode: str = "auto", decode_overlap: bool = False,
+        fused_encode: str = "auto",
         n_rounds: int = 8, compile_cache=None,
         wire_dtype: str = "float32", dryrun: bool = False) -> dict:
     """Build, warm up and time the GPT-2 round; returns the result dict.
@@ -69,16 +69,6 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
     temp at the flagship scale), "off" (the pre-fusion round — the
     A/B arm whose ledger DOCUMENTS the dense materialization), or "on"
     (fail fast if ineligible).
-
-    ``decode_overlap=True`` times the SPLIT round (--decode_overlap,
-    core/pipeline.DecodeOverlapRound: cohort + decode executables,
-    bit-identical losses) and records BOTH executables' memory ledgers
-    — the cohort ledger is where the fused encode's temp win is
-    measurable at all (in the monolithic round the server decode's own
-    dense (d,) buffers share temp slots with the client scan across
-    disjoint lifetimes, so the executable's PEAK barely moves), and
-    the decode running while the host stages round t+1 is ROADMAP
-    item 1's second half.
 
     ``dryrun=True`` shrinks the model (GPT2Config.small) and the round
     shape so every arm runs in seconds on the CPU container — the sweep
@@ -139,7 +129,6 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
                     num_clients=100, track_bytes=False, approx_topk=True,
                     num_results_train=2, lm_chunk=lm_chunk,
                     sketch_fused_encode=fused_encode,
-                    decode_overlap=decode_overlap,
                     wire_dtype=wire_dtype, **sketch_kw)
     if compile_cache is not None:  # "" = disable (true cold start)
         cfg = cfg.replace(compilation_cache_dir=compile_cache)
@@ -155,11 +144,7 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
     mask = jnp.ones((W, B), bool)
     ids = jnp.arange(W, dtype=jnp.int32)
 
-    bench_rt = runtime
-    if decode_overlap:
-        from commefficient_tpu.core import DecodeOverlapRound
-        bench_rt = DecodeOverlapRound(runtime)
-    dt, metrics, phases = timed_rounds(bench_rt, (ids, batch, mask, 0.1),
+    dt, metrics, phases = timed_rounds(runtime, (ids, batch, mask, 0.1),
                                        warmup=1, rounds=n_rounds, desc="gpt2",
                                        profiler=profiler)
     warmup_s = phases.pop("warmup_s", None)
@@ -188,49 +173,19 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
     # compile cache. NOTE the same scan caveat as flops: XLA's
     # bytes-accessed counts each scan body once, so the measured
     # arithmetic intensity is an UPPER bound for the scanned round.
-    nbytes = mledger = decode_ledger = None
     if telemetry is not None:
         w = telemetry.watcher()
-        if decode_overlap:
-            # headline ledger = the CLIENT executable (where the fused
-            # encode's temp win lives); the server half rides alongside
-            parts = [w.bytes.get("cohort_step"), w.bytes.get("decode_step")]
-            nbytes = sum(p for p in parts if p) or None
-            mledger = w.memory.get("cohort_step")
-            decode_ledger = w.memory.get("decode_step")
-        else:
-            nbytes = w.bytes.get("round_step")
-            mledger = w.memory.get("round_step")
+        nbytes = w.bytes.get("round_step")
+        mledger = w.memory.get("round_step")
     else:
-        def round_cost():
-            from commefficient_tpu.telemetry.memory_ledger import \
-                ledger_from_compiled
-
-            def _cost(compiled):
-                return (compiled.cost_analysis().get("bytes accessed"),
-                        ledger_from_compiled(compiled))
-
-            lr = jnp.asarray(0.1, jnp.float32)
-            if decode_overlap:
-                b1, l1 = _cost(runtime._cohort.lower(
-                    runtime.init_state(), ids, batch, mask, lr,
-                    runtime.cs).compile())
-                # shapes only — this path must stay compile-only (a
-                # real cohort execution is the dominant cost of a round)
-                s_shape, p_shape = jax.eval_shape(
-                    runtime._cohort, runtime.init_state(), ids, batch,
-                    mask, lr, runtime.cs)
-                b2, l2 = _cost(runtime._decode_jit.lower(
-                    s_shape, p_shape["sum"],
-                    jax.ShapeDtypeStruct((), jnp.float32),
-                    runtime._prep_lr(0.1), runtime.cs).compile())
-                return (((b1 or 0) + (b2 or 0)) or None, l1, l2)
-            compiled = runtime._round.lower(
-                runtime.init_state(), ids, batch, mask, lr,
-                runtime.cs, runtime._gid).compile()
-            return _cost(compiled) + (None,)
-
-        nbytes, mledger, decode_ledger = round_cost()
+        from commefficient_tpu.telemetry.memory_ledger import \
+            ledger_from_compiled
+        compiled = runtime._round.lower(
+            runtime.init_state(), ids, batch, mask,
+            jnp.asarray(0.1, jnp.float32), runtime.cs,
+            runtime._gid).compile()
+        nbytes = compiled.cost_analysis().get("bytes accessed")
+        mledger = ledger_from_compiled(compiled)
     from commefficient_tpu.telemetry.utilization import roofline_fields
     from bench_common import peak_hbm_gbps as _peak_hbm
     roof = roofline_fields(
@@ -261,15 +216,11 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
         "input_wait_frac": round(phases["host_s"] / dt, 6),
         "roofline": roof,
         "memory_ledger": mledger,
-        # present only under decode_overlap: the server half's ledger
-        # (the headline memory_ledger is then the COHORT executable)
-        "memory_ledger_decode": decode_ledger,
         "dryrun": dryrun,
         # the sweep knobs this arm ran under (scripts/gpt2_mfu_sweep.py)
         "config": {"remat": remat, "remat_policy": remat_policy,
                    "microbatch": microbatch, "lm_chunk": lm_chunk,
-                   "fused_encode": fused_encode,
-                   "decode_overlap": decode_overlap},
+                   "fused_encode": fused_encode},
     }
     if telemetry is not None:
         from commefficient_tpu.telemetry.utilization import emit_from_totals
@@ -358,7 +309,8 @@ def ledger_ab(dryrun: bool = False) -> dict:
                         num_clients=100, track_bytes=False,
                         approx_topk=True, num_results_train=2,
                         lm_chunk=min(128, S), sketch_fused_encode=fe,
-                        decode_overlap=True, telemetry=False, **sketch_kw)
+                        async_agg=True, max_inflight=1, buffer_goal=1,
+                        telemetry=False, **sketch_kw)
         runtime = FedRuntime(
             cfg, params, make_gpt2_train_loss(model, lm_chunk=cfg.lm_chunk),
             num_clients=cfg.num_clients)

@@ -388,8 +388,7 @@ class FedConfig:
     # --signals_exact. Emitted as schema-v10 `layer_signals` events at
     # the signals cadence; "off" compiles the group machinery out
     # entirely (round HLO byte-identical, tested). Gated exactly like
-    # signals: --no_signals / --no_telemetry / async / decode_overlap
-    # drop it too. Cost: one (d_pad,) int32 group-id map resident on
+    # signals: --no_signals / --no_telemetry / async drop it too. Cost: one (d_pad,) int32 group-id map resident on
     # device (sharded on a mesh — the same O(d) class as the byte
     # accounting's coord_last_update) plus a few segment reductions.
     signal_groups: str = "coarse"
@@ -583,19 +582,6 @@ class FedConfig:
     # - "off": never (the pre-fusion round, bit-identical HLO).
     # See README "Fused sketch encode" for the soundness matrix.
     sketch_fused_encode: str = "auto"
-    # Split the federated round into two executables — the client block
-    # (cohort compute + table sum) and the server block (decode /
-    # top-k uncompress + weight update) — so the server decode of round
-    # t is dispatched as its own program and runs while the host (and
-    # the input pipeline) stage round t+1's client block, and a
-    # record-cadence metrics sync completes when the CLIENT half
-    # finishes instead of waiting out the decode. Losses are
-    # bit-identical to the monolithic round (dryrun-asserted; the split
-    # reuses the async cohort/commit machinery at K=1/M=1, which PR 6
-    # proved bitwise). Same soundness constraints as --async_agg (no
-    # per-client persistent rows, no topk_down) — unsound combos fail
-    # fast. Mutually exclusive with --async_agg (which already splits).
-    decode_overlap: bool = False
     # Sharded sketch SERVER tail (core/server.py
     # sharded_sketch_server_update): on a mesh, replace the round's
     # replicated table psum with a psum_scatter over table columns
@@ -723,12 +709,6 @@ class FedConfig:
                 f"{self.mode} has no sketch server tail to shard); drop "
                 "the flag or use --sketch_sharded_server auto (a no-op "
                 "off sketch mode)")
-        if self.decode_overlap and self.async_agg:
-            raise ValueError(
-                "--decode_overlap and --async_agg are mutually exclusive: "
-                "async buffered aggregation already splits the round into "
-                "cohort and commit executables (and adds buffering "
-                "semantics on top). Drop one of the flags.")
         if self.signal_groups not in ("coarse", "leaf", "off"):
             raise ValueError(
                 f"--signal_groups {self.signal_groups!r} not in "
@@ -1339,12 +1319,6 @@ def add_args(parser: argparse.ArgumentParser, default_lr: Optional[float] = None
                         "carry; the dense (d,) gradient sum never hits "
                         "HBM): auto = when sound, on = require (fail "
                         "fast otherwise), off = the pre-fusion round")
-    p.add_argument("--decode_overlap", action="store_true",
-                   help="split the round into client and server-decode "
-                        "executables so the PS decode of round t runs "
-                        "while round t+1's client block is staged "
-                        "(bit-identical losses; same soundness "
-                        "constraints as --async_agg)")
     p.add_argument("--sketch_sharded_server", choices=("auto", "on", "off"),
                    default="auto",
                    help="shard the sketch server tail over the mesh "

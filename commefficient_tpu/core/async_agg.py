@@ -124,10 +124,15 @@ def staleness_weight(rule: str, staleness: float, alpha: float = 0.5
                      f"choices: {DISCOUNT_RULES}")
 
 
-def _split_round_problems(cfg: FedConfig) -> List[str]:
-    """Why a configuration cannot run its round as separate client/server
-    executables (the cohort step carries no per-client persistent-row or
-    topk_down plumbing — shared by --async_agg and --decode_overlap)."""
+def validate_async_combo(cfg: FedConfig) -> None:
+    """Reject mode combinations where buffered merge is unsound.
+
+    The buffer consumes cohort uploads only through their weighted sum;
+    any per-client persistent state written at dispatch from commit-time
+    information cannot be reproduced out of order. Mirrors the fail-fast
+    contract of core/server.validate_mode_combo."""
+    if not cfg.async_agg:
+        return
     problems: List[str] = []
     if cfg.needs_client_velocities:
         problems.append(
@@ -154,37 +159,9 @@ def _split_round_problems(cfg: FedConfig) -> List[str]:
             "client block has no weight-row plumbing (and under "
             "buffering a client's record diverges from what it actually "
             "downloaded). Drop --topk_down")
-    return problems
-
-
-def validate_async_combo(cfg: FedConfig) -> None:
-    """Reject mode combinations where buffered merge is unsound.
-
-    The buffer consumes cohort uploads only through their weighted sum;
-    any per-client persistent state written at dispatch from commit-time
-    information cannot be reproduced out of order. Mirrors the fail-fast
-    contract of core/server.validate_mode_combo."""
-    if not cfg.async_agg:
-        return
-    problems = _split_round_problems(cfg)
     if problems:
         raise ValueError(
             "--async_agg: buffered merge is unsound for this "
-            "configuration:\n  " + "\n  ".join(problems))
-
-
-def validate_overlap_combo(cfg: FedConfig) -> None:
-    """--decode_overlap's fail-fast twin of :func:`validate_async_combo`:
-    the split round shares the cohort step, so the same per-client
-    persistent-state combinations are out (config.py already rejects
-    --decode_overlap together with --async_agg)."""
-    if not cfg.decode_overlap:
-        return
-    problems = _split_round_problems(cfg)
-    if problems:
-        raise ValueError(
-            "--decode_overlap: splitting the round into client and "
-            "server-decode executables is unsound for this "
             "configuration:\n  " + "\n  ".join(problems))
 
 

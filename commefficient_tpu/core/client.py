@@ -249,7 +249,7 @@ def fused_encode_blockers(cfg: FedConfig, signals: bool = False) -> list:
     on the deferred-dense uploads, vmap-path grad stats) and raises
     under ``--sketch_fused_encode on``. ``signals`` is whether the
     per-round signal diagnostics are actually live (telemetry on, no
-    async/decode-overlap split) — ``--signals_exact`` only blocks then.
+    async split) — ``--signals_exact`` only blocks then.
     """
     problems = []
     if cfg.mode != "sketch":

@@ -255,64 +255,3 @@ class RoundPipeline:
         self.close()
         return False
 
-
-class DecodeOverlapRound:
-    """--decode_overlap driver adapter: one federated round as TWO
-    dispatched executables instead of one (the server-side twin of this
-    module's input prefetch — ROADMAP item 1's second half).
-
-    The monolithic ``FedRuntime.round`` fuses client compute and the
-    server decode/top-k uncompress into one program, so a record-cadence
-    metrics sync (and the profiler's device window) waits out the decode
-    even though the metrics are client-block outputs. Here round t is
-    dispatched as ``cohort`` (the client half — identical code to the
-    sync round's client block) immediately followed by ``decode`` (the
-    server half — the sync round's server tail verbatim, see
-    FedRuntime._decode_step): jax's async dispatch returns both at once,
-    a ``block_until_ready`` on the returned metrics completes when the
-    CLIENT executable finishes, and the decode executes while this loop
-    (and the RoundPipeline prefetcher above) stages round t+1's input.
-    Losses are bit-identical to the monolithic round for every
-    configuration that consumes no per-round randomness (no DP — the
-    split advances ``state.rng`` by a W+1 split then a 2-split instead
-    of one W+2 split, the async_agg K=1/M=1 caveat verbatim); asserted
-    by ``__graft_entry__.dryrun_multichip`` the same way PR 5 gated the
-    input pipeline.
-
-    The returned metrics dict matches ``FedRuntime.round``'s contract
-    (``signals`` is None — the split decouples the quantities the
-    signal diagnostics compare; the runtime prints the NOTE once).
-    """
-
-    def __init__(self, runtime):
-        if not runtime.cfg.decode_overlap:
-            raise ValueError(
-                "DecodeOverlapRound needs a runtime built with "
-                "cfg.decode_overlap=True (the cohort/decode executables "
-                "are only jitted then)")
-        self.runtime = runtime
-
-    def init_state(self):
-        """Delegates to the runtime — the adapter is drop-in for the
-        driver/bench loops that build their state through the object
-        they call ``round`` on (bench_common.timed_rounds)."""
-        return self.runtime.init_state()
-
-    def round(self, state, client_ids, batch, mask, lr):
-        """Same contract as ``FedRuntime.round`` (state', metrics)."""
-        state, payload = self.runtime.cohort(state, client_ids, batch,
-                                             mask, lr)
-        state = self.runtime.decode(state, payload["sum"],
-                                    payload["n_total"], lr)
-        metrics = {
-            "results": payload["results"],
-            "n_valid": payload["n_valid"],
-            "download_bytes": payload["download_bytes"],
-            "upload_bytes": payload["upload_bytes"],
-            "signals": None,
-            "layer_signals": None,
-            "client_stats": payload["client_stats"],
-            "defense": payload["defense"],
-            "client_finite": payload["client_finite"],
-        }
-        return state, metrics

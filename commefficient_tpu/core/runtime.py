@@ -24,7 +24,7 @@ fed_aggregator.py:455).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +47,38 @@ from commefficient_tpu.telemetry.clients import (CLIENT_GRAD_KEYS,
                                                  summarize_per_client)
 from commefficient_tpu.telemetry.profiling import phase
 from commefficient_tpu.telemetry.signals import round_signals
+
+
+class _ClientHalf(NamedTuple):
+    """What ``FedRuntime._client_half`` leaves for the round or the
+    cohort; an entry is None where the configuration has no such thing."""
+    agg: jax.Array                  # UN-normalized transmitted-space sum
+    n_total: jax.Array              # its datum count
+    results: Tuple[jax.Array, ...]  # per client: loss first
+    n_valid: jax.Array
+    velocity: Optional[jax.Array]   # the clients' new rows, home layout
+    error: Optional[jax.Array]
+    sig_dense: Optional[jax.Array]  # dense summed gradient, for the signals
+    client_finite: Optional[jax.Array]
+    defense_stats: Optional[Dict[str, jax.Array]]
+    cur_med: Optional[jax.Array]    # this round's normclip ring entry
+    download_bytes: Optional[jax.Array]
+    upload_bytes: Optional[jax.Array]
+    client_last_round: Optional[jax.Array]
+    client_weights: Optional[jax.Array]
+    client_stats: Optional[Dict[str, Any]]
+
+
+class _ServerHalf(NamedTuple):
+    """What ``FedRuntime._server_half`` leaves for the round or the
+    commit."""
+    fields: Dict[str, jax.Array]    # into ``state.replace``: ps_weights,
+    #                                 Vvelocity, Verror, coord_last_update
+    update: jax.Array               # as the rule gave it, before padding:
+    #                                 what the signals measure
+    applied: jax.Array              # the (d_pad,) update the weights took
+    sup_mask: Optional[jax.Array]
+    bad: jax.Array                  # update or aggregate non-finite
 
 
 class FedRuntime:
@@ -382,14 +414,6 @@ class FedRuntime:
             else:
                 print("sketch kernel path, server tail: xla (gather "
                       f"form: {blocker})")
-        # --decode_overlap composition: the table reduce itself MOVES
-        # into the decode executable — the cohort ends at each device's
-        # LOCAL partial table, so the round's metrics sync completes
-        # without waiting any ICI collective and the reduce-scatter +
-        # sharded decode both run while the host stages round t+1 (see
-        # _decode_step / _reduce_partials; bit-identity dryrun-gated).
-        self._reduce_in_decode = (self._sharded_server
-                                  and cfg.decode_overlap)
         # ---- int8 quantized wire (--wire_dtype int8; ops/wire.py):
         # clients quantize their table contribution with per-column-
         # block abs-max scales + stochastic rounding (draws keyed off
@@ -453,7 +477,7 @@ class FedRuntime:
         # so signals are off there — loudly, not silently: the
         # async_round event's EF norms are the async health channel.
         self._signals = (cfg.signals and cfg.telemetry
-                         and not cfg.async_agg and not cfg.decode_overlap)
+                         and not cfg.async_agg)
         if cfg.signals and cfg.telemetry and cfg.async_agg:
             import sys
             print("NOTE: --async_agg disables the per-round `signals` "
@@ -461,13 +485,6 @@ class FedRuntime:
                   "the same round's server update, which buffered "
                   "aggregation decouples); commit-granularity EF norms "
                   "are emitted on the `async_round` events instead. Pass "
-                  "--no_signals to silence this note.", file=sys.stderr)
-        if cfg.signals and cfg.telemetry and cfg.decode_overlap:
-            import sys
-            print("NOTE: --decode_overlap disables the per-round `signals` "
-                  "diagnostics: the split round's client block finishes "
-                  "before the server decode it would be compared against "
-                  "(that early finish is the point of the split). Pass "
                   "--no_signals to silence this note.", file=sys.stderr)
         # the dense pre-encode aggregate exists only where the deferred
         # encode runs once on one device — capture it there so sketch
@@ -646,29 +663,31 @@ class FedRuntime:
                 fused_encode=self._fused_encode)
         self._val_fn_inner = client_lib.make_val_step(cfg, loss_fn_val, unravel)
 
-        if self.shardings is not None:
-            sh = self.shardings
+        sh = self.shardings
+        state_sh = cs_sh = batch_sh = clients_sh = None
+        if sh is not None:
             state_sh = sh.for_state(cfg, self._state_template())
-            batch_sh = self.batch_sharding()
             cs_sh = jax.tree.map(lambda _: sh.replicated, self.cs)
-            self._round = jax.jit(
-                self._round_step,
-                donate_argnums=(0,),
-                in_shardings=(state_sh, sh.round_axis, batch_sh,
-                              sh.round_axis, None, cs_sh,
-                              # gid: inferred from the argument's
-                              # committed layout (device_put dense_vec
-                              # in __init__) — a pinned entry here would
-                              # reject the legacy 6-argument lowerings
-                              # that omit it (see _round_step's
-                              # constant fallback)
-                              None),
-                out_shardings=(state_sh, None),
-            )
-            self._state_sharding = state_sh
-        else:
-            self._round = jax.jit(self._round_step, donate_argnums=(0,))
-            self._state_sharding = None
+            batch_sh = self.batch_sharding()
+            clients_sh = sh.round_axis
+        self._state_sharding = state_sh
+
+        def jit_step(fn, in_shardings, out_shardings):
+            """A step that donates its state; the shardings are pinned
+            on a mesh and left to JAX without one."""
+            if sh is None:
+                return jax.jit(fn, donate_argnums=(0,))
+            return jax.jit(fn, donate_argnums=(0,),
+                           in_shardings=in_shardings,
+                           out_shardings=out_shardings)
+
+        # gid (last): inferred from the argument's committed layout
+        # (device_put dense_vec above) — a pinned entry would reject the
+        # lowerings that omit it (see _round_step's constant fallback)
+        self._round = jit_step(
+            self._round_step,
+            (state_sh, clients_sh, batch_sh, clients_sh, None, cs_sh, None),
+            (state_sh, None))
         if self.mesh is not None:
             # mesh-parallel validation: val items are independent, so the
             # batch shards over EVERY mesh axis (flattened) and each device
@@ -681,60 +700,23 @@ class FedRuntime:
         else:
             self._val = jax.jit(self._val_step)
 
-        # async buffered aggregation (core/async_agg.py): the round splits
-        # into a client-compute cohort step (dispatch time) and a server
-        # commit step (buffer-goal time), plus a trivial merge. Built only
-        # under --async_agg — the synchronous path compiles nothing new.
-        # --decode_overlap reuses the SAME cohort step (the client half)
-        # plus a buffer-free decode step (core/pipeline.DecodeOverlapRound
-        # drives them): the server decode of round t runs as its own
-        # executable, so a metrics sync completes when the client half
-        # finishes and the host stages round t+1 under the decode.
+        # async buffered aggregation (core/async_agg.py): the round's two
+        # halves as programs of their own, a cohort step (dispatch time)
+        # and a commit step (buffer-goal time), plus a trivial merge.
+        # Built only under --async_agg — the synchronous path compiles
+        # nothing new.
         self._cohort = self._commit_jit = self._merge_jit = None
-        self._decode_jit = None
-        if cfg.async_agg or cfg.decode_overlap:
-            from commefficient_tpu.core.async_agg import (
-                validate_async_combo, validate_overlap_combo)
-            if cfg.async_agg:
-                validate_async_combo(cfg)
-            else:
-                validate_overlap_combo(cfg)
-            if self.shardings is not None:
-                sh = self.shardings
-                cs_sh = jax.tree.map(lambda _: sh.replicated, self.cs)
-                self._cohort = jax.jit(
-                    self._cohort_step, donate_argnums=(0,),
-                    in_shardings=(self._state_sharding, sh.round_axis,
-                                  self.batch_sharding(), sh.round_axis,
-                                  None, cs_sh),
-                    out_shardings=(self._state_sharding, None))
-                if cfg.async_agg:
-                    self._commit_jit = jax.jit(
-                        self._commit_step, donate_argnums=(0,),
-                        in_shardings=(self._state_sharding, None, cs_sh),
-                        out_shardings=(self._state_sharding, None))
-                    self._merge_jit = jax.jit(
-                        self._merge_step, donate_argnums=(0,),
-                        in_shardings=(self._state_sharding, None, None,
-                                      None),
-                        out_shardings=self._state_sharding)
-                else:
-                    self._decode_jit = jax.jit(
-                        self._decode_step, donate_argnums=(0,),
-                        in_shardings=(self._state_sharding, None, None,
-                                      None, cs_sh),
-                        out_shardings=self._state_sharding)
-            else:
-                self._cohort = jax.jit(self._cohort_step,
-                                       donate_argnums=(0,))
-                if cfg.async_agg:
-                    self._commit_jit = jax.jit(self._commit_step,
-                                               donate_argnums=(0,))
-                    self._merge_jit = jax.jit(self._merge_step,
-                                              donate_argnums=(0,))
-                else:
-                    self._decode_jit = jax.jit(self._decode_step,
-                                               donate_argnums=(0,))
+        if cfg.async_agg:
+            from commefficient_tpu.core.async_agg import validate_async_combo
+            validate_async_combo(cfg)
+            self._cohort = jit_step(
+                self._cohort_step,
+                (state_sh, clients_sh, batch_sh, clients_sh, None, cs_sh),
+                (state_sh, None))
+            self._commit_jit = jit_step(
+                self._commit_step, (state_sh, None, cs_sh), (state_sh, None))
+            self._merge_jit = jit_step(
+                self._merge_step, (state_sh, None, None, None), state_sh)
 
     def set_compile_watcher(self, watcher) -> None:
         """Compile observability hook (telemetry.JitWatcher): wraps the
@@ -753,8 +735,6 @@ class FedRuntime:
             self._cohort = watcher.wrap("cohort_step", self._cohort)
         if self._commit_jit is not None:
             self._commit_jit = watcher.wrap("commit_step", self._commit_jit)
-        if self._decode_jit is not None:
-            self._decode_jit = watcher.wrap("decode_step", self._decode_jit)
 
     def _probe_seq_grad_scale(self) -> float:
         """Measure how the round's cross-seq-shard gradient sum over-counts
@@ -885,17 +865,15 @@ class FedRuntime:
     # ------------------------------------------------- robustness tail
 
     def _transmit_tail(self, tx, out, adv, ref, client_rngs, step=None):
-        """Shared per-client transmitted-space tail of the sync round's
-        and async cohort's client blocks: adversarial injection ->
-        nonfinite quarantine -> wire rounding -> robust (or plain-sum)
-        aggregation. MUST stay one function: the async K=1/M=1
-        bit-identity claim rides on both paths tracing exactly these
-        ops. ``tx`` is None on the fused path (the aggregate is already
-        accumulated; the robustness flags that need per-client uploads
-        force the vmap path) — then agg comes back None and the caller
-        keeps its own. Everything is compiled out at the flag defaults.
-        Returns ``(agg_or_None, results, n_valid, stats, client_finite,
-        defense_stats, cur_med)``."""
+        """The per-client transmitted-space tail of the client block:
+        adversarial injection -> nonfinite quarantine -> wire rounding ->
+        robust (or plain-sum) aggregation. ``tx`` is None on the fused
+        path (the aggregate is already accumulated; the robustness flags
+        that need per-client uploads force the vmap path) — then agg
+        comes back None and the caller keeps its own. Everything is
+        compiled out at the flag defaults. Returns ``(agg_or_None,
+        results, n_valid, stats, client_finite, defense_stats,
+        cur_med)``."""
         cfg = self.cfg
         results, n_valid, stats = out.results, out.n_valid, out.stats
         client_finite = cur_med = defense_stats = agg = None
@@ -996,11 +974,10 @@ class FedRuntime:
         return counts
 
     def _download_ledger(self, state: FedState, client_ids: jax.Array):
-        """The dispatch-time half of the byte ledger (``track_bytes``),
-        shared by the sync round and the cohort: what each of the
-        round's clients downloads (coordinates changed since its last
-        round) and uploads, per slot and scattered over the client
-        universe, and the clients' new last-round marks. Returns
+        """The dispatch-time half of the byte ledger (``track_bytes``):
+        what each of the round's clients downloads (coordinates changed
+        since its last round) and uploads, per slot and scattered over
+        the client universe, and the clients' new last-round marks. Returns
         ``(download_bytes, upload_bytes, down_slot, up_slot,
         client_last_round)``."""
         with phase("fed_byte_ledger"):
@@ -1028,11 +1005,8 @@ class FedRuntime:
 
     def _apply_server_update(self, state: FedState, agg: jax.Array,
                              lr: jax.Array, server_rng: jax.Array, cs=None):
-        """Mode + topology dispatch of the server update rule — ONE
-        implementation consumed by the sync round step AND the split/
-        async server tails (the ``_transmit_tail`` discipline: the
-        sharded-vs-replicated parity gate rides on every path tracing
-        exactly the same ops). Returns ``(update, Vvel, Verr,
+        """Mode + topology dispatch of the server update rule
+        (``_server_half`` calls it). Returns ``(update, Vvel, Verr,
         sup_mask)``; the sharded tail's update is a mesh-padded
         (d_pad,) sharded vector, the replicated sketch decode's a
         true-d one (the caller's padding block handles both)."""
@@ -1098,59 +1072,10 @@ class FedRuntime:
             agg, axis=self._axis, n_shards=self.mesh.shape[self._axis],
             block=self._wire_block, seed=self.cfg.seed, round_idx=step)
 
-    def _reduce_partials(self, partials: jax.Array,
-                         step=None) -> jax.Array:
-        """--decode_overlap + sharded server: the cohort left each
-        device's LOCAL partial table stacked on the clients axis
-        ((n, r, c), device i owning slot i) — run the deferred
-        reduce-scatter here, in the DECODE executable, so the cohort's
-        metrics sync waits out no ICI collective and the reduce runs
-        under the next round's staging. Bitwise the sync round's
-        collective: same per-device partials, same op, same bf16 wire
-        rounding (the collective IS the wire)."""
-        ax = self._axis
-        td = self._table_dtype
-
-        if self._int8_wire:
-            # the int8 wire travels WITH the deferred collective exactly
-            # like the bf16 rounding: quantization draws key off the
-            # SAME state.step the monolithic round would use (the server
-            # tail has not advanced it yet), so the split round stays
-            # bitwise identical to the monolithic one
-            def blk8(part, step):
-                return self._int8_reduce_scatter(part[0], step)
-
-            with phase("fed_table_reduce"):
-                return shard_map(blk8, mesh=self.mesh,
-                                 in_specs=(P(ax, None, None), P()),
-                                 out_specs=P(None, ax),
-                                 check_vma=False)(partials, step)
-
-        def blk(part):
-            p = part[0]
-            if td != jnp.float32:
-                red = lax.optimization_barrier(lax.psum_scatter(
-                    p.astype(td), ax, scatter_dimension=1, tiled=True))
-                return red.astype(jnp.float32)
-            return lax.psum_scatter(p, ax, scatter_dimension=1,
-                                    tiled=True)
-
-        with phase("fed_table_reduce"):
-            return shard_map(blk, mesh=self.mesh,
-                             in_specs=P(ax, None, None),
-                             out_specs=P(None, ax),
-                             check_vma=False)(partials)
-
-    def _mesh_aggregate(self, agg: jax.Array, n_total: jax.Array, step,
-                        defer_reduce: bool = False):
-        """The cross-chip aggregation of a client block (called INSIDE
+    def _mesh_aggregate(self, agg: jax.Array, n_total: jax.Array, step):
+        """The cross-chip aggregation of the client block (called INSIDE
         its shard_map): the client sum over every mesh axis and the
-        datum count over the clients axis — ONE implementation for the
-        sync round and the async/split cohort (the ``_transmit_tail``
-        discipline: the bit-identity contracts ride on both paths
-        tracing exactly these ops). ``defer_reduce`` is the cohort's
-        --decode_overlap + sharded-server case. Returns ``(agg,
-        n_total)``."""
+        datum count over the clients axis. Returns ``(agg, n_total)``."""
         cfg = self.cfg
         td = self._table_dtype
         with phase("fed_table_reduce"):
@@ -1168,16 +1093,6 @@ class FedRuntime:
                 agg = lax.psum_scatter(
                     jnp.pad(agg, (0, self.d_pad - cfg.grad_size)),
                     all_axes, scatter_dimension=0, tiled=True)
-            elif defer_reduce:
-                # --decode_overlap + sharded server: the table reduce
-                # MOVES into the decode executable — the cohort ends at
-                # this device's LOCAL partial table (stacked on the
-                # clients axis, zero wire traffic), so the metrics sync
-                # completes without waiting any ICI collective and the
-                # reduce-scatter runs under round t+1's staging (see
-                # _reduce_partials; the bf16 wire rounding travels WITH
-                # the collective)
-                agg = agg[None]
             elif self._sharded_server:
                 # sharded server tail: reduce-SCATTER over table
                 # columns replaces the replicated table psum (the
@@ -1245,25 +1160,39 @@ class FedRuntime:
         return agg, n_total
 
     # ------------------------------------------------------------- round step
+    #
+    # A round has two halves. ``_client_half`` is everything up to the
+    # un-normalized client sum, ``_server_half`` everything from the
+    # normalized aggregate to the new weights. The synchronous round runs
+    # both in one program; async buffered aggregation (FedBuff-style,
+    # core/async_agg.py) runs them apart: cohort gradients are computed
+    # against the weights AT DISPATCH, land out of order, merge into the
+    # FedState buffer by (staleness-weighted) addition, and the server
+    # half runs when the buffer goal is reached. With max_inflight=1,
+    # buffer_goal=1 and no scenario latency, cohort + merge + commit is
+    # bit-identical to the round (tests/test_async_agg.py,
+    # tests/test_sharded_server.py).
 
-    def _round_step(self, state: FedState, client_ids: jax.Array,
-                    batch: Any, mask: jax.Array, lr: jax.Array, cs=None,
-                    gid=None):
+    def _client_half(self, state: FedState, client_ids: jax.Array,
+                     batch: Any, mask: jax.Array, lr: jax.Array, cs,
+                     client_rngs: jax.Array) -> _ClientHalf:
+        """The client half of a round: the dispatch-time byte ledger, the
+        weights and rows each client reads, the client block (vmapped over
+        the round's client axis; on a mesh shard_mapped, so each device
+        sums and, deferred, sketch-encodes its local clients before ONE
+        explicit collective over ICI: the analogue of the reference's
+        per-worker compute + NCCL reduce, fed_worker.py:131,138 +
+        fed_aggregator.py:329-332) and the per-client population stats.
+        The caller splits ``state.rng``. Under --async_agg there are no
+        per-client rows and no top-k-down weights (validate_async_combo);
+        they trace as None and compile out."""
         cfg = self.cfg
-        if gid is None and self._layer_signals:
-            # legacy 6-argument lowerings (tests/benches that lower the
-            # round directly) omit the group-id map: fall back to the
-            # runtime's copy as a trace-time constant. The REAL round
-            # (self.round) always passes it as an argument — a constant
-            # would serialize d_pad*4 bytes into the HLO shipped to the
-            # compiler at GPT-2 scale, the same reason cs is an argument
-            gid = self._gid
         num_workers = client_ids.shape[0]
-        keys = jax.random.split(state.rng, num_workers + 2)
-        rng, server_rng, client_rngs = keys[0], keys[1], keys[2:]
 
         # ---- download byte accounting, before this round's update
-        # (re-design of reference fed_aggregator.py:239-289; see state.py)
+        # (re-design of reference fed_aggregator.py:239-289; see state.py).
+        # Under --async_agg the step counter advances per COMMIT, so the
+        # client reads the weights of server version ``state.step``
         download_bytes = upload_bytes = None
         down_slot = up_slot = None
         client_last_round = state.client_last_round
@@ -1294,13 +1223,6 @@ class FedRuntime:
                         if state.client_velocities is not None else None)
             err_rows = (state.client_errors[client_ids]
                         if state.client_errors is not None else None)
-
-        # ---- client compute + aggregation
-        # (reference fed_worker.py:131,138 + fed_aggregator.py:329-332)
-        # vmapped over the round's client axis; on a mesh the block below is
-        # shard_mapped so each device sums (and, deferred, sketch-encodes)
-        # its local clients before ONE explicit psum over ICI — the direct
-        # analogue of the reference's per-worker compute + NCCL reduce.
         has_vel = vel_rows is not None
         has_err = err_rows is not None
 
@@ -1383,10 +1305,9 @@ class FedRuntime:
                         used, batch, mask, vel_rows, err_rows,
                         client_rngs, cs)
                 tx = out.transmit
-            # ---- shared per-client transmitted-space tail (injection
-            # -> quarantine -> wire -> robust aggregation); compiled out
-            # entirely at the flag defaults — the off-path ops and their
-            # order stay byte-identical to the pre-defense round
+            # ---- per-client transmitted-space tail (injection ->
+            # quarantine -> wire -> robust aggregation); compiled out
+            # entirely at the flag defaults
             t_agg, results, n_valid, stats, client_finite, \
                 defense_stats, cur_med = self._transmit_tail(
                     tx, out, adv, ref, client_rngs, step)
@@ -1519,32 +1440,153 @@ class FedRuntime:
         step_arg = state.step if self._int8_wire else None
         with phase("fed_client_step"):
             agg, n_total, vel_new, err_new, results, n_valid, sig_dense, \
-                client_grad_stats, client_finite, defense_stats, cur_med = \
+                grad_stats, client_finite, defense_stats, cur_med = \
                 client_block(used_weights, batch, mask, vel_rows, err_rows,
                              client_rngs, lr, adv_slot, ref_thresh, step_arg,
                              cs)
-        out = client_lib.ClientOut(None, vel_new, err_new, results, n_valid,
-                                   client_grad_stats)
-        with phase("fed_server_tail"):
-            total = jnp.maximum(n_total, 1.0)
-            agg = agg / total
-        if sig_dense is not None:
-            # same normalization as agg: the signals compare like with like
-            with phase("fed_signals"):
-                sig_dense = sig_dense / total
 
+        # ---- per-client population stats (telemetry/clients.py): quantile
+        # summaries along the client axis, riding the same async metrics
+        # fetch as the loss — per-client vectors never leave the device
+        client_stats = None
+        if self._client_stats:
+            with phase("fed_client_stats"):
+                per_client = {"loss": results[0]}
+                if grad_stats is not None:
+                    per_client.update(grad_stats)
+                else:
+                    # fused path: no per-client gradient exists (see __init__
+                    # _client_grad_stats) — NaN quantiles, never fake zeros
+                    nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
+                    per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
+                if cfg.track_bytes:
+                    per_client["upload_bytes"] = up_slot
+                    per_client["download_bytes"] = down_slot
+                rep = None
+                if self.mesh is not None:
+                    # one W-sized all-gather for the WHOLE summary: without
+                    # the replication constraint every per-key quantile
+                    # lowers to its own tiny collectives (launch-count
+                    # pathology, see summarize_per_client)
+                    rep_sh = NamedSharding(self.mesh, P())
+
+                    def rep(x, _sh=rep_sh):
+                        return lax.with_sharding_constraint(x, _sh)
+                client_stats = summarize_per_client(per_client, n_valid,
+                                                    replicate_fn=rep)
+
+        return _ClientHalf(
+            agg=agg, n_total=n_total, results=results, n_valid=n_valid,
+            velocity=vel_new, error=err_new, sig_dense=sig_dense,
+            client_finite=client_finite, defense_stats=defense_stats,
+            cur_med=cur_med,
+            download_bytes=download_bytes, upload_bytes=upload_bytes,
+            client_last_round=client_last_round,
+            client_weights=client_weights, client_stats=client_stats)
+
+    def _client_health(self, state: FedState, half: _ClientHalf):
+        """What the round and the cohort both do with the client half's
+        health outputs: the client-side non-finite test, the normclip
+        ring write and the ``metrics['defense']`` scalars. Returns
+        ``(bad, defense_ref, defense)``; the caller ors ``bad`` with its
+        own tests and marks ``nan_round``."""
         with phase("fed_server_tail"):
-            # ---- server update (mode + topology dispatch: the sharded
-            # sketch tail on an eligible mesh, core/server.py's replicated
-            # rules otherwise — ONE implementation shared with the split/
-            # async server tails, see _apply_server_update)
+            if self._quarantine:
+                # per-client nonfinites were zeroed OUT of the aggregate in
+                # the client block (their losses too) — only a round with no
+                # finite DATA-CARRYING client left, or nonfinite SERVER
+                # state, still aborts. A nonfinite flag can only come from a
+                # live slot (benched/masked placeholders upload finite
+                # zeros), so "fully-nonfinite round" == some client went
+                # nonfinite AND no finite client with data remains
+                # (n_valid is post-zeroing: > 0 iff live AND finite)
+                bad = ((~half.client_finite).any()
+                       & ~(half.n_valid > 0).any())
+            else:
+                # the reference's host check is on the loss
+                # (cv_train.py:222-224)
+                bad = ~jnp.isfinite(half.results[0]).all()
+
+            # normclip rolling reference: this round's median per-datum norm
+            # enters the ring AFTER the round used the PAST medians — the
+            # attack round cannot vouch for its own normality. A cohort
+            # keys the ring off the server version: commits between
+            # dispatches share a slot, which only shortens the window
+            defense_ref = state.defense_ref
+            if self._defense_ring:
+                defense_ref = state.defense_ref.at[
+                    jnp.mod(state.step, self.cfg.defense_window)].set(
+                        half.cur_med)
+
+            defense = self._defense_scalars(half.defense_stats,
+                                            half.client_finite)
+        return bad, defense_ref, defense
+
+    @staticmethod
+    def _mark_nan_round(state: FedState, bad: jax.Array,
+                        *more: jax.Array) -> jax.Array:
+        """Device-side divergence detection: the FIRST round (server
+        version) at which any of the caller's non-finite tests fired."""
+        with phase("fed_server_tail"):
+            for flag in more:
+                bad = bad | flag
+            return jnp.where((state.nan_round < 0) & bad, state.step,
+                             state.nan_round)
+
+    def _server_half(self, state: FedState, agg: jax.Array, lr: jax.Array,
+                     server_rng: jax.Array, cs=None) -> _ServerHalf:
+        """The server half of a round, from the NORMALIZED aggregate: the
+        mode's momentum + error-feedback update (the sharded sketch tail
+        on an eligible mesh, core/server.py's replicated rules
+        otherwise), the weight apply, the changed-coordinate marks of the
+        byte ledger, and the non-finite test on update and aggregate (a
+        NaN gradient does not always survive the top-k select into the
+        update). The caller owns ``rng``, ``step``, ``nan_round`` and any
+        buffer."""
+        cfg = self.cfg
+        with phase("fed_server_tail"):
             update, Vvel, Verr, sup_mask = self._apply_server_update(
                 state, agg, lr, server_rng, cs)
+            padded = update
+            if self.d_pad != cfg.grad_size:
+                if update.shape[0] == cfg.grad_size:
+                    # sketch decode produces a true-d update; pad to the
+                    # server's sharded length
+                    padded = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
+                else:
+                    # keep the padding coordinates exactly zero (server-side
+                    # DP noise would otherwise drift them and pollute the
+                    # changed-coordinate byte accounting)
+                    padded = jnp.where(
+                        jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
+            ps_weights = state.ps_weights - padded
 
-        # ---- compression-signal health (telemetry/signals.py): on-device
-        # scalars fetched asynchronously alongside the loss — computed
-        # BEFORE the update is padded so true-d slicing stays uniform
-        signals = None
+        # ---- byte accounting: record which coordinates changed this round
+        coord_last_update = state.coord_last_update
+        if cfg.track_bytes:
+            with phase("fed_byte_ledger"):
+                coord_last_update = jnp.where(
+                    padded != 0, state.step, state.coord_last_update)
+
+        with phase("fed_server_tail"):
+            bad = ~jnp.isfinite(padded).all() | ~jnp.isfinite(agg).all()
+        fields = dict(ps_weights=ps_weights, Vvelocity=Vvel, Verror=Verr,
+                      coord_last_update=coord_last_update)
+        return _ServerHalf(fields, update, padded, sup_mask, bad)
+
+    def _round_signals(self, state: FedState, agg, srv: _ServerHalf,
+                       sig_dense, cs, gid):
+        """The round's compression-signal health (telemetry/signals.py)
+        and layer-wise attribution (telemetry/layer_signals.py): on-device
+        scalars and (G,) vectors fetched asynchronously alongside the
+        loss, measured on the update BEFORE it is padded so true-d
+        slicing stays uniform. Returns ``(signals, layer_signals,
+        sig_Vvelocity, sig_Verror)``; all pass-through when signals are
+        off."""
+        cfg = self.cfg
+        update = srv.update
+        Vvel, Verr = srv.fields["Vvelocity"], srv.fields["Verror"]
+        signals = layer_signals = None
         sig_vel_new, sig_err_new = state.sig_Vvelocity, state.sig_Verror
         if self._signals:
             with phase("fed_signals"):
@@ -1554,14 +1596,11 @@ class FedRuntime:
                     Vvel_new=Vvel, Verr_new=Verr, cs=cs,
                     dense_agg=sig_dense,
                     sig_vel=state.sig_Vvelocity, sig_err=state.sig_Verror)
-
-        # ---- layer-wise attribution (telemetry/layer_signals.py):
-        # per-group reductions of the same pre-padding quantities the
-        # scalar signals just measured — the conservation laws (group
-        # masses sum to the whole-vector norms squared, support counts
-        # sum to nnz) are dryrun-gated against exactly that pairing
-        layer_signals = None
         if self._layer_signals:
+            # per-group reductions of the same pre-padding quantities the
+            # scalar signals just measured — the conservation laws (group
+            # masses sum to the whole-vector norms squared, support counts
+            # sum to nnz) are dryrun-gated against exactly that pairing
             from commefficient_tpu.telemetry.layer_signals import \
                 layer_group_signals
             # dense gradient / dense EF sources, where the round holds
@@ -1592,145 +1631,85 @@ class FedRuntime:
                     cfg, gid=gid, n_groups=self.group_spec.n_groups,
                     update=update, grad_dense=grad_dense,
                     err_dense=err_dense, err_pre=err_pre)
+        return signals, layer_signals, sig_vel_new, sig_err_new
 
-        # ---- per-client population stats (telemetry/clients.py): quantile
-        # summaries along the client axis, riding the same async metrics
-        # fetch as the loss — per-client vectors never leave the device
-        client_stats = None
-        if self._client_stats:
-            with phase("fed_client_stats"):
-                per_client = {"loss": out.results[0]}
-                if out.stats is not None:
-                    per_client.update(out.stats)
-                else:
-                    # fused path: no per-client gradient exists (see __init__
-                    # _client_grad_stats) — NaN quantiles, never fake zeros
-                    nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
-                    per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
-                if cfg.track_bytes:
-                    per_client["upload_bytes"] = up_slot
-                    per_client["download_bytes"] = down_slot
-                rep = None
-                if self.mesh is not None:
-                    # one W-sized all-gather for the WHOLE summary: without
-                    # the replication constraint every per-key quantile
-                    # lowers to its own tiny collectives (launch-count
-                    # pathology, see summarize_per_client)
-                    rep_sh = NamedSharding(self.mesh, P())
-
-                    def rep(x, _sh=rep_sh):
-                        return lax.with_sharding_constraint(x, _sh)
-                client_stats = summarize_per_client(per_client, out.n_valid,
-                                                    replicate_fn=rep)
+    def _round_step(self, state: FedState, client_ids: jax.Array,
+                    batch: Any, mask: jax.Array, lr: jax.Array, cs=None,
+                    gid=None):
+        """The synchronous round: both halves in one program."""
+        cfg = self.cfg
+        if gid is None and self._layer_signals:
+            # lowerings that omit the group-id map (tests lower the round
+            # directly) fall back to the runtime's copy as a trace-time
+            # constant. The REAL round (self.round) always passes it as an
+            # argument — a constant would serialize d_pad*4 bytes into the
+            # HLO shipped to the compiler at GPT-2 scale, the same reason
+            # cs is an argument
+            gid = self._gid
+        keys = jax.random.split(state.rng, client_ids.shape[0] + 2)
+        rng, server_rng, client_rngs = keys[0], keys[1], keys[2:]
+        half = self._client_half(state, client_ids, batch, mask, lr, cs,
+                                 client_rngs)
+        with phase("fed_server_tail"):
+            total = jnp.maximum(half.n_total, 1.0)
+            agg = half.agg / total
+        sig_dense = half.sig_dense
+        if sig_dense is not None:
+            # same normalization as agg: the signals compare like with like
+            with phase("fed_signals"):
+                sig_dense = sig_dense / total
+        srv = self._server_half(state, agg, lr, server_rng, cs)
+        signals, layer_signals, sig_vel_new, sig_err_new = \
+            self._round_signals(state, agg, srv, sig_dense, cs, gid)
 
         with phase("fed_server_tail"):
-            if self.d_pad != cfg.grad_size:
-                if update.shape[0] == cfg.grad_size:
-                    # sketch decode produces a true-d update; pad to the
-                    # server's sharded length
-                    update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
-                else:
-                    # keep the padding coordinates exactly zero (server-side
-                    # DP noise would otherwise drift them and pollute the
-                    # changed-coordinate byte accounting)
-                    update = jnp.where(
-                        jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
-            ps_weights = state.ps_weights - update
-
             # ---- write back per-client rows
             client_velocities = state.client_velocities
-            if out.velocity is not None and client_velocities is not None:
-                new_rows = out.velocity
-                if cfg.mode == "true_topk" and sup_mask is not None:
+            if half.velocity is not None and client_velocities is not None:
+                new_rows = half.velocity
+                if cfg.mode == "true_topk" and srv.sup_mask is not None:
                     # momentum factor masking on participating clients'
                     # local velocities (intended behavior of
                     # fed_aggregator.py:528-533) — the server mask is in
                     # padded space; rows are at true d single-device, at
                     # d_row_pad in the mesh home layout (padding coords are
                     # identically 0 and where() keeps them 0)
-                    sm = sup_mask[: cfg.grad_size]
+                    sm = srv.sup_mask[: cfg.grad_size]
                     if self._rows_cols:
                         sm = jnp.pad(sm, (0, self.d_row_pad - cfg.grad_size))
                     new_rows = jnp.where(sm[None, :], 0.0, new_rows)
                 client_velocities = client_velocities.at[client_ids].set(
                     new_rows)
             client_errors = state.client_errors
-            if out.error is not None and client_errors is not None:
-                client_errors = client_errors.at[client_ids].set(out.error)
+            if half.error is not None and client_errors is not None:
+                client_errors = client_errors.at[client_ids].set(half.error)
 
-        # ---- byte accounting: record which coordinates changed this round
-        coord_last_update = state.coord_last_update
-        if cfg.track_bytes:
-            with phase("fed_byte_ledger"):
-                coord_last_update = jnp.where(
-                    update != 0, state.step, state.coord_last_update)
-
-        with phase("fed_server_tail"):
-            # device-side divergence detection: record the FIRST round
-            # where a client loss, the aggregated gradient, or the weight
-            # update went non-finite (fused isfinite+reduce; a NaN gradient
-            # does not always survive the top-k select into the update, and
-            # the reference's host check is on the loss, cv_train.py:222-224)
-            bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
-            if self._quarantine:
-                # per-client nonfinites were zeroed OUT of the aggregate in
-                # the client block (their losses too) — only a round with no
-                # finite DATA-CARRYING client left, or nonfinite SERVER
-                # state, still aborts. A nonfinite flag can only come from a
-                # live slot (benched/masked placeholders upload finite
-                # zeros), so "fully-nonfinite round" == some client went
-                # nonfinite AND no finite client with data remains
-                # (n_valid is post-zeroing: > 0 iff live AND finite)
-                bad = bad | ((~client_finite).any()
-                             & ~(out.n_valid > 0).any())
-            else:
-                bad = bad | ~jnp.isfinite(out.results[0]).all()
-            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                                  state.nan_round)
-
-            # normclip rolling reference: this round's median per-datum norm
-            # enters the ring AFTER the round used the PAST medians — the
-            # attack round cannot vouch for its own normality
-            defense_ref = state.defense_ref
-            if self._defense_ring:
-                defense_ref = state.defense_ref.at[
-                    jnp.mod(state.step, cfg.defense_window)].set(cur_med)
-
-            defense = self._defense_scalars(defense_stats, client_finite)
-
-        new_state = FedState(
-            ps_weights=ps_weights,
-            Vvelocity=Vvel,
-            Verror=Verr,
-            step=state.step + 1,
+        client_bad, defense_ref, defense = self._client_health(state, half)
+        new_state = state.replace(
             rng=rng,
+            step=state.step + 1,
             client_velocities=client_velocities,
             client_errors=client_errors,
-            client_weights=client_weights,
-            coord_last_update=coord_last_update,
-            client_last_round=client_last_round,
-            nan_round=nan_round,
+            client_weights=half.client_weights,
+            client_last_round=half.client_last_round,
+            nan_round=self._mark_nan_round(state, srv.bad, client_bad),
             sig_Vvelocity=sig_vel_new,
             sig_Verror=sig_err_new,
-            # pass-through: the synchronous round never touches the async
-            # buffer (the two paths are mutually exclusive per config)
-            async_buffer=state.async_buffer,
-            async_buffer_n=state.async_buffer_n,
             defense_ref=defense_ref,
-        )
+            **srv.fields)
         metrics = {
-            "results": out.results,          # tuple of (num_workers,) arrays
-            "n_valid": out.n_valid,
-            "download_bytes": download_bytes,
-            "upload_bytes": upload_bytes,
+            "results": half.results,         # tuple of (num_workers,) arrays
+            "n_valid": half.n_valid,
+            "download_bytes": half.download_bytes,
+            "upload_bytes": half.upload_bytes,
             "signals": signals,              # dict of scalars, or None
             # dict of (G,) per-group vectors, or None (layer_signals.py)
             "layer_signals": layer_signals,
-            "client_stats": client_stats,    # quantile summaries, or None
+            "client_stats": half.client_stats,   # quantile summaries, or None
             "defense": defense,              # dict of scalars, or None
             # (W,) bool, quarantine mode only: the host-side ledger's
             # per-round feed (False = zeroed out of this aggregate)
-            "client_finite": client_finite,
+            "client_finite": half.client_finite,
         }
         return new_state, metrics
 
@@ -1771,211 +1750,38 @@ class FedRuntime:
             check_vma=False)(ps_weights, batch, mask)
 
     # -------------------------------------------- async buffered aggregation
-    #
-    # The synchronous _round_step fuses client compute and the server
-    # update into one program; async buffered aggregation (FedBuff-style,
-    # core/async_agg.py) needs them apart: cohort gradients are computed
-    # against the weights AT DISPATCH, land out of order, merge into the
-    # FedState buffer by (staleness-weighted) addition, and the server
-    # momentum+EF step runs only when the buffer goal is reached. The
-    # three pieces below mirror the sync step's code EXACTLY over the
-    # combinations validate_async_combo admits (no per-client persistent
-    # rows, no topk_down) — with max_inflight=1, buffer_goal=1 and no
-    # scenario latency the composition is bit-identical to _round_step
-    # (asserted per mode by __graft_entry__.dryrun_multichip).
 
     def _cohort_step(self, state: FedState, client_ids: jax.Array,
                      batch: Any, mask: jax.Array, lr: jax.Array, cs=None):
-        """Client half of the round: the same client block as
-        _round_step, stopping BEFORE the datum normalization and server
-        update. Advances only the dispatch-time state (rng, download
-        byte accounting, nan flag) and returns the cohort payload: the
-        UNNORMALIZED transmitted-space sum, its datum count, per-client
-        results/stats, and the round's exact byte costs."""
-        cfg = self.cfg
-        num_workers = client_ids.shape[0]
-        keys = jax.random.split(state.rng, num_workers + 1)
+        """The client half as its own program, stopping BEFORE the datum
+        normalization and server update. Advances only the dispatch-time
+        state (rng, download byte accounting, nan flag, defense ring) and
+        returns the cohort payload: the UNNORMALIZED transmitted-space
+        sum, its datum count, per-client results/stats, and the round's
+        exact byte costs."""
+        keys = jax.random.split(state.rng, client_ids.shape[0] + 1)
         rng, client_rngs = keys[0], keys[1:]
-
-        # download byte accounting at DISPATCH: the client reads the
-        # weights of server version ``state.step`` (in async mode the
-        # step counter advances per COMMIT — the server version)
-        download_bytes = upload_bytes = None
-        down_slot = up_slot = None
-        client_last_round = state.client_last_round
-        if cfg.track_bytes:
-            download_bytes, upload_bytes, down_slot, up_slot, \
-                client_last_round = self._download_ledger(state, client_ids)
-
-        adv_slot = (self._adv_universe[client_ids]
-                    if self._adversary else None)
-        ref_thresh = (jnp.nanmedian(state.defense_ref)
-                      if self._defense_ring else None)
-
-        def client_block(used_weights, batch, mask, client_rngs, lr, adv,
-                         ref, step, cs):
-            # validate_async_combo guarantees no vel/err rows and no
-            # topk_down here — otherwise byte-for-byte the sync block
-            used = used_weights[: cfg.grad_size]
-            if self._labelflip:
-                batch = client_lib.flip_labels(batch, adv,
-                                               self._flip_classes)
-            td = self._table_dtype
-            wire = (td != jnp.float32 and not self._dense_preimage
-                    and cfg.mode == "sketch")
-            tx = None
-            if cfg.mode == "fedavg":
-                lr_c = lr[: cfg.grad_size] if lr.ndim == 1 else lr
-                out = jax.vmap(
-                    self._client_fn, in_axes=(None, 0, 0, None, 0))(
-                        used, batch, mask, lr_c, client_rngs)
-                tx = out.transmit
-            elif self._fused:
-                agg, f_results, f_nvalid = self._fused_fn(used, batch,
-                                                          mask, cs)
-                out = client_lib.ClientOut(None, None, None, f_results,
-                                           f_nvalid)
-            else:
-                out = jax.vmap(
-                    self._client_fn,
-                    in_axes=(None, 0, 0, None, None, 0, None))(
-                        used, batch, mask, None, None, client_rngs, cs)
-                tx = out.transmit
-            # the SAME transmitted-space tail as the sync block
-            # (adversarial fates act at COHORT COMPUTE, which both paths
-            # share — the reason injection works with and without
-            # --async_agg)
-            t_agg, results, n_valid, stats, client_finite, \
-                defense_stats, cur_med = self._transmit_tail(
-                    tx, out, adv, ref, client_rngs, step)
-            if t_agg is not None:
-                agg = t_agg
-            if (self._defer_encode and not self._dense_preimage
-                    and not self._fused_encode):
-                with phase("fed_sketch_encode"):
-                    agg = cs.encode(agg)
-            if wire and self._axis is None and agg.ndim == 2:
-                agg = agg.astype(td).astype(jnp.float32)
-            elif (self._int8_wire and self._axis is None
-                  and agg.ndim == 2 and self._defer_encode):
-                # same single-device simulated wire as the sync round —
-                # the async K=1/M=1 bit-identity rides on it
-                from commefficient_tpu.ops.wire import wire_round_trip
-                agg = wire_round_trip(agg, self._wire_block,
-                                      seed=cfg.seed, round_idx=step,
-                                      salt=0)
-            n_total = n_valid.sum()
-            if self._axis is not None:
-                agg, n_total = self._mesh_aggregate(
-                    agg, n_total, step,
-                    defer_reduce=self._reduce_in_decode)
-            return agg, n_total, results, n_valid, stats, \
-                client_finite, defense_stats, cur_med
-
-        if self._axis is not None:
-            ax = self._axis
-            row = P(ax)
-            if self._seq_axis and self._seq_spec:
-                batch_specs = {k: self._batch_pspec(sd)
-                               for k, sd in self._seq_spec.items()}
-            else:
-                batch_specs = jax.tree.map(lambda _: row, batch)
-            in_specs = (P(), batch_specs, row, row, P(),
-                        row if self._adversary else None,
-                        P() if self._defense_ring else None,
-                        P() if self._int8_wire else None,
-                        jax.tree.map(lambda _: P(), cs))
-            dense_agg_spec = P(tuple(self.mesh.axis_names))
-            if cfg.mode != "sketch":
-                agg_spec = dense_agg_spec
-            elif self._reduce_in_decode:
-                # stacked per-device partial tables (see client_block)
-                agg_spec = P(ax, None, None)
-            elif self._sharded_server:
-                agg_spec = P(None, ax)
-            else:
-                agg_spec = P()
-            out_specs = (
-                agg_spec,
-                P(),
-                tuple(row for _ in range(cfg.num_results_train)),
-                row,
-                ({k: row for k in CLIENT_GRAD_KEYS}
-                 if self._client_grad_stats else None),
-                row if self._quarantine else None,
-                ({k: P() for k in ("clip_frac", "clip_thresh",
-                                   "clipped_mass", "trim_frac")}
-                 if cfg.defense != "none" else None),
-                P() if self._defense_ring else None,
-            )
-            client_block = shard_map(client_block, mesh=self.mesh,
-                                     in_specs=in_specs, out_specs=out_specs,
-                                     check_vma=False)
-
-        with phase("fed_client_step"):
-            agg, n_total, results, n_valid, grad_stats, client_finite, \
-                defense_stats, cur_med = client_block(
-                    state.ps_weights, batch, mask, client_rngs, lr, adv_slot,
-                    ref_thresh, state.step if self._int8_wire else None, cs)
-
-        client_stats = None
-        if self._client_stats:
-            with phase("fed_client_stats"):
-                per_client = {"loss": results[0]}
-                if grad_stats is not None:
-                    per_client.update(grad_stats)
-                else:
-                    nan_w = jnp.full((num_workers,), jnp.nan, jnp.float32)
-                    per_client.update({k: nan_w for k in CLIENT_GRAD_KEYS})
-                if cfg.track_bytes:
-                    per_client["upload_bytes"] = up_slot
-                    per_client["download_bytes"] = down_slot
-                rep = None
-                if self.mesh is not None:
-                    rep_sh = NamedSharding(self.mesh, P())
-
-                    def rep(x, _sh=rep_sh):
-                        return lax.with_sharding_constraint(x, _sh)
-                client_stats = summarize_per_client(per_client, n_valid,
-                                                    replicate_fn=rep)
-
+        half = self._client_half(state, client_ids, batch, mask, lr, cs,
+                                 client_rngs)
         with phase("fed_server_tail"):
             # dispatch-side divergence detection: a poisoned cohort sum must
             # be flagged before it can merge into the buffer
-            bad = ~jnp.isfinite(agg).all()
-            if self._quarantine:
-                # same "fully-nonfinite" semantics as the sync round: a
-                # benched/masked placeholder slot never vouches for a cohort
-                # whose every live upload diverged
-                bad = bad | ((~client_finite).any() & ~(n_valid > 0).any())
-            else:
-                bad = bad | ~jnp.isfinite(results[0]).all()
-            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                                  state.nan_round)
-
-            defense_ref = state.defense_ref
-            if self._defense_ring:
-                # at cohort (dispatch) granularity the ring keys off the
-                # server version — commits between dispatches share a slot,
-                # which only shortens the effective window, never corrupts it
-                defense_ref = state.defense_ref.at[
-                    jnp.mod(state.step, cfg.defense_window)].set(cur_med)
-
-            defense = self._defense_scalars(defense_stats, client_finite)
-
-        new_state = state.replace(rng=rng, client_last_round=client_last_round,
-                                  nan_round=nan_round,
-                                  defense_ref=defense_ref)
+            bad = ~jnp.isfinite(half.agg).all()
+        client_bad, defense_ref, defense = self._client_health(state, half)
+        new_state = state.replace(
+            rng=rng, client_last_round=half.client_last_round,
+            nan_round=self._mark_nan_round(state, bad, client_bad),
+            defense_ref=defense_ref)
         payload = {
-            "sum": agg,                  # UNNORMALIZED weighted client sum
-            "n_total": n_total,          # datum count of this cohort
-            "results": results,
-            "n_valid": n_valid,
-            "download_bytes": download_bytes,
-            "upload_bytes": upload_bytes,
-            "client_stats": client_stats,
+            "sum": half.agg,             # UNNORMALIZED weighted client sum
+            "n_total": half.n_total,     # datum count of this cohort
+            "results": half.results,
+            "n_valid": half.n_valid,
+            "download_bytes": half.download_bytes,
+            "upload_bytes": half.upload_bytes,
+            "client_stats": half.client_stats,
             "defense": defense,
-            "client_finite": client_finite,
+            "client_finite": half.client_finite,
         }
         return new_state, payload
 
@@ -1992,124 +1798,51 @@ class FedRuntime:
             async_buffer=state.async_buffer + weight * cohort_sum,
             async_buffer_n=state.async_buffer_n + n_total)
 
-    def _server_tail_fields(self, state: FedState, agg: jax.Array,
-                            lr: jax.Array, server_rng: jax.Array, cs=None):
-        """The split round's shared server tail (normalize happened at
-        the caller): the mode's momentum+EF ``server_update``, the
-        weight apply, and the byte/nan bookkeeping — ONE implementation
-        consumed by both the async commit and the decode-overlap decode
-        (the ``_transmit_tail`` lesson applied to the server half: the
-        bit-identity contracts ride on these paths never drifting
-        apart). Returns ``(replace_fields, update, Vvel, Verr)``; the
-        caller owns ``rng`` advancement and any buffer handling."""
-        cfg = self.cfg
-        with phase("fed_server_tail"):
-            update, Vvel, Verr, _sup_mask = self._apply_server_update(
-                state, agg, lr, server_rng, cs)
-
-            if self.d_pad != cfg.grad_size:
-                if update.shape[0] == cfg.grad_size:
-                    update = jnp.pad(update, (0, self.d_pad - cfg.grad_size))
-                else:
-                    update = jnp.where(
-                        jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
-            ps_weights = state.ps_weights - update
-
-        coord_last_update = state.coord_last_update
-        if cfg.track_bytes:
-            with phase("fed_byte_ledger"):
-                coord_last_update = jnp.where(
-                    update != 0, state.step, state.coord_last_update)
-
-        with phase("fed_server_tail"):
-            bad = ~jnp.isfinite(update).all() | ~jnp.isfinite(agg).all()
-            nan_round = jnp.where((state.nan_round < 0) & bad, state.step,
-                                  state.nan_round)
-        fields = dict(
-            ps_weights=ps_weights,
-            Vvelocity=Vvel,
-            Verror=Verr,
-            step=state.step + 1,
-            coord_last_update=coord_last_update,
-            nan_round=nan_round,
-        )
-        return fields, update, Vvel, Verr
-
     def _commit_step(self, state: FedState, lr: jax.Array, cs=None):
-        """Server half of the round: normalize the buffered aggregate,
-        run the mode's momentum+EF update (core/server.py — identical
-        code to the sync round), apply it to the weights, and reset the
-        buffer. ``step`` advances here: it is the server version."""
+        """The server half as its own program: normalize the buffered
+        aggregate, run ``_server_half`` on it, and reset the buffer.
+        ``step`` advances here: it is the server version."""
         rng, server_rng = jax.random.split(state.rng)
         with phase("fed_server_tail"):
             total = jnp.maximum(state.async_buffer_n, 1.0)
             agg = state.async_buffer / total
-        fields, update, Vvel, Verr = self._server_tail_fields(
-            state, agg, lr, server_rng, cs)
+        srv = self._server_half(state, agg, lr, server_rng, cs)
         new_state = state.replace(
             rng=rng,
+            nan_round=self._mark_nan_round(state, srv.bad),
+            step=state.step + 1,
             async_buffer=jnp.zeros_like(state.async_buffer),
             async_buffer_n=jnp.zeros_like(state.async_buffer_n),
-            **fields)
+            **srv.fields)
         # commit health scalars for the async_round telemetry event: the
         # post-commit EF-accumulator norms are the staleness-divergence
         # signal telemetry/health.py watches
         with phase("fed_signals"):
             metrics = {
-                "update_norm": jnp.linalg.norm(update),
-                "error_norm": jnp.linalg.norm(Verr),
-                "velocity_norm": jnp.linalg.norm(Vvel),
+                "update_norm": jnp.linalg.norm(srv.applied),
+                "error_norm": jnp.linalg.norm(srv.fields["Verror"]),
+                "velocity_norm": jnp.linalg.norm(srv.fields["Vvelocity"]),
                 "buffer_n": state.async_buffer_n,
             }
         return new_state, metrics
 
-    def _decode_step(self, state: FedState, cohort_sum: jax.Array,
-                     n_total: jax.Array, lr: jax.Array, cs=None
-                     ) -> FedState:
-        """Server half of the --decode_overlap split round: the commit
-        step WITHOUT the async buffer — the cohort's unnormalized sum
-        arrives as an argument (the buffer at K=1/M=1 is a pure pytree
-        swap, so skipping it changes nothing; FedState keeps its sync
-        template and checkpoints stay vintage-compatible). Dispatched as
-        its own executable so the decode/top-k uncompress of round t
-        runs while the host stages round t+1's client block, and a
-        metrics sync on the cohort outputs returns without waiting the
-        decode out. Numerically the sync round's server tail verbatim
-        (losses bit-identical — dryrun-asserted, the PR-5 gate
-        pattern). Returns ONLY the new state: with the per-round
-        signals off under the split, nothing reads post-decode norms —
-        emitting them as executable outputs would force a (d,)-sized
-        reduction per round that XLA cannot DCE."""
-        rng, server_rng = jax.random.split(state.rng)
-        if self._reduce_in_decode:
-            # the cohort deferred the table reduce to THIS executable
-            # (stacked per-device partials): run the reduce-scatter
-            # first, then normalize — the sync round's exact order.
-            # state.step has not advanced yet, so the int8 wire's
-            # quantization draws match the monolithic round's bitwise.
-            cohort_sum = self._reduce_partials(
-                cohort_sum, state.step if self._int8_wire else None)
-        with phase("fed_server_tail"):
-            agg = cohort_sum / jnp.maximum(n_total, 1.0)
-        fields, _update, _Vvel, _Verr = self._server_tail_fields(
-            state, agg, lr, server_rng, cs)
-        return state.replace(rng=rng, **fields)
-
     def _prep_lr(self, lr) -> jax.Array:
         lr = jnp.asarray(lr, jnp.float32)
         if lr.ndim == 1 and lr.shape[0] != self.d_pad:
+            # per-param LR vector (Fixup groups): pad to the server's
+            # mesh-padded length (padding coords get multiplier 1; their
+            # update is identically 0)
             lr = jnp.pad(lr, (0, self.d_pad - lr.shape[0]),
                          constant_values=1.0)
         return lr
 
     def cohort(self, state: FedState, client_ids, batch, mask, lr
                ) -> Tuple[FedState, Dict]:
-        """Dispatch one cohort's client compute (async or decode-overlap
-        mode). Same argument contract as :meth:`round`; returns (state',
-        payload) where payload carries the unnormalized transmitted-space
-        sum the AsyncAggregator merges (or :meth:`decode` consumes)."""
-        assert self._cohort is not None, \
-            "neither --async_agg nor --decode_overlap is on"
+        """Dispatch one cohort's client compute (--async_agg). Same
+        argument contract as :meth:`round`; returns (state', payload)
+        where payload carries the unnormalized transmitted-space sum the
+        AsyncAggregator merges."""
+        assert self._cohort is not None, "--async_agg is off"
         with tracing.span("cohort_dispatch"):
             return self._cohort(state, jnp.asarray(client_ids, jnp.int32),
                                 batch, jnp.asarray(mask),
@@ -2148,16 +1881,6 @@ class FedRuntime:
         with tracing.span("commit_dispatch"):
             return self._commit_jit(state, self._prep_lr(lr), self.cs)
 
-    def decode(self, state: FedState, cohort_sum, n_total, lr
-               ) -> FedState:
-        """Run the --decode_overlap server half on one cohort payload
-        (core/pipeline.DecodeOverlapRound). Returns the new state."""
-        assert self._decode_jit is not None, "--decode_overlap is off"
-        with tracing.span("decode_dispatch"):
-            return self._decode_jit(state, cohort_sum,
-                                    jnp.asarray(n_total, jnp.float32),
-                                    self._prep_lr(lr), self.cs)
-
     # -------------------------------------------------------------- user API
 
     def round(self, state: FedState, client_ids, batch, mask, lr
@@ -2165,21 +1888,14 @@ class FedRuntime:
         """Run one federated round. ``client_ids``: (num_workers,) int32;
         ``batch``: pytree with leaves (num_workers, batch_size, ...);
         ``mask``: (num_workers, batch_size); ``lr``: scalar or (d,) vector."""
-        lr = jnp.asarray(lr, jnp.float32)
-        if lr.ndim == 1 and lr.shape[0] != self.d_pad:
-            # per-param LR vector (Fixup groups): pad to the server's
-            # mesh-padded length (padding coords get multiplier 1; their
-            # update is identically 0)
-            lr = jnp.pad(lr, (0, self.d_pad - lr.shape[0]),
-                         constant_values=1.0)
         # span = the async dispatch (argument staging + jit call return);
         # device completion lands in the caller's "device_wait" span. A
         # compile shows up here as a multi-second dispatch — cross-check
         # with the `compile` event the JitWatcher emits for the same round
         with tracing.span("round_dispatch"):
             return self._round(state, jnp.asarray(client_ids, jnp.int32),
-                               batch, jnp.asarray(mask), lr, self.cs,
-                               self._gid)
+                               batch, jnp.asarray(mask), self._prep_lr(lr),
+                               self.cs, self._gid)
 
     def val(self, state: FedState, batch, mask):
         """Masked evaluation on the current PS weights; returns

@@ -423,19 +423,6 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
               f"{async_agg.discount} staleness discount"
               + ("" if async_agg.scenario is None
                  else f", scenario={cfg.scenario}"))
-    # decode overlap (core/pipeline.DecodeOverlapRound): the round runs
-    # as separate client and server-decode executables, so a record-
-    # cadence metrics sync completes when the CLIENT half finishes and
-    # the PS decode of round t executes while this loop (and the input
-    # pipeline) stage round t+1. Bit-identical losses (dryrun-asserted);
-    # mutually exclusive with --async_agg (config-validated).
-    overlap_rt = None
-    if cfg.decode_overlap:
-        from commefficient_tpu.core.pipeline import DecodeOverlapRound
-        overlap_rt = DecodeOverlapRound(runtime)
-        print("decode overlap: round split into cohort + decode "
-              "executables (server decode runs under round t+1's "
-              "staging)")
     # robustness subsystem (core/runtime.py does the in-round work; this
     # loop owns the host half): the quarantine ledger benches/ejects
     # clients whose uploads went nonfinite — the device already zeroed
@@ -749,9 +736,6 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
                     # compute happened — nothing to record or accumulate)
                     state, metrics, commits = async_agg.step(
                         state, rnd, global_round, batch, lr_arr)
-                elif overlap_rt is not None:
-                    state, metrics = overlap_rt.round(
-                        state, rnd.client_ids, batch, rnd.mask, lr_arr)
                 else:
                     state, metrics = runtime.round(
                         state, rnd.client_ids, batch, rnd.mask, lr_arr)

@@ -36,10 +36,9 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_defense.py \
 env JAX_PLATFORMS=cpu python -m pytest tests/test_memory.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 
-# fused sketch encode + decode overlap: a regression here (broken
-# sketch linearity in the table-carry scan, streaming_grad drift vs
-# jax.grad, lost decode-overlap bit-identity, soundness guards) fails
-# in seconds, before the full suite
+# fused sketch encode: a regression here (broken sketch linearity in
+# the table-carry scan, streaming_grad drift vs jax.grad, soundness
+# guards) fails in seconds, before the full suite
 env JAX_PLATFORMS=cpu python -m pytest tests/test_fused_encode.py -q \
     -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 

@@ -57,13 +57,6 @@ ARMS = {
     # between the two is the committed proof the floor moved
     # (runs/BREAKDOWN_gpt2.md §Round 7)
     "unfused_encode": {"fused_encode": "off"},
-    # split-round arms (--decode_overlap): the decode of round t runs
-    # while round t+1 stages, and the COHORT executable's ledger
-    # isolates the client block — the granularity where the fused
-    # encode's temp drop is measurable at all (the monolithic round's
-    # peak is shared with the server decode's own dense buffers)
-    "overlap": {"decode_overlap": True},
-    "overlap_unfused": {"decode_overlap": True, "fused_encode": "off"},
     "no_remat": {"remat": False},
     "policy_dots": {"remat_policy": "dots_saveable"},
     "mb4": {"microbatch": 4},

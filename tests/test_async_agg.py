@@ -150,9 +150,12 @@ def test_out_of_order_merge_matches_runtime():
 
 @pytest.mark.parametrize("mode,extra", [
     ("sketch", {}),
+    ("sketch", {"sketch_fused_encode": "off"}),
+    ("sketch", {"sketch_dtype": "bfloat16"}),
     ("uncompressed", {"error_type": "none"}),
     ("true_topk", {"error_type": "virtual"}),
-])
+], ids=["sketch", "sketch-unfused", "sketch-bf16", "uncompressed",
+        "true_topk"])
 def test_sync_equivalence_bit_identical(mode, extra):
     """K=1, M=1, no scenario: every cohort lands and commits in its own
     tick with staleness 0 — losses and final weights must be BITWISE

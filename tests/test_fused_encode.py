@@ -1,5 +1,4 @@
-"""Fused sketch encode (core/client.py + ops/sketch.py + ops/circulant.py)
-and decode overlap (core/pipeline.DecodeOverlapRound):
+"""Fused sketch encode (core/client.py + ops/sketch.py + ops/circulant.py):
 
 - the streaming/accumulating encode entry points against dense-encode
   references (sketch linearity: ``table + encode(v)``, range offsets,
@@ -12,9 +11,7 @@ and decode overlap (core/pipeline.DecodeOverlapRound):
   and update-space adversary injection (which acts on the table);
 - HLO byte-identity where the fused encode must be invisible (non-sketch
   modes; auto-with-blocker == explicit off);
-- the --sketch_fused_encode on fail-fast and --decode_overlap
-  validation guards, and the split round's bit-identity to the
-  monolithic round (the PR-5 pipeline-gate pattern, server-side);
+- the --sketch_fused_encode on fail-fast guard;
 - the blocked-scan download-byte accounting against the numpy reference
   (the (W, d) broadcast it replaced was the round's largest temp).
 """
@@ -26,8 +23,7 @@ import pytest
 from jax.flatten_util import ravel_pytree
 
 from commefficient_tpu.config import FedConfig
-from commefficient_tpu.core import (DecodeOverlapRound, FedRuntime,
-                                    validate_overlap_combo)
+from commefficient_tpu.core import FedRuntime
 from commefficient_tpu.core.client import (encode_grad_tree,
                                            fused_encode_blockers)
 from commefficient_tpu.models.stream_mlp import (init_stream_mlp,
@@ -337,95 +333,6 @@ def test_fused_encode_auto_with_blocker_hlo_identical_to_off():
             jnp.asarray(0.1, jnp.float32), rt_on.cs)
     assert (rt_on._round.lower(*args).as_text()
             != rt_off._round.lower(*args).as_text())
-
-
-# ------------------------------------------------------------ decode overlap
-
-
-def test_decode_overlap_bitwise_vs_inline():
-    """The PR-5 gate pattern, server side: split cohort+decode rounds
-    are BIT-identical to the monolithic round — losses and weights."""
-    cfg_s = make_cfg(decode_overlap=True)
-    rt_s = FedRuntime(cfg_s, make_params(), quad_loss, num_clients=16)
-    ov = DecodeOverlapRound(rt_s)
-    rt_m = FedRuntime(make_cfg(), make_params(), quad_loss, num_clients=16)
-    ss, sm = rt_s.init_state(), rt_m.init_state()
-    batch, mask, ids = make_batch(1, W=W, B=B)
-    for r in range(4):
-        ss, mo = ov.round(ss, ids, batch, mask, 0.1)
-        sm, mi = rt_m.round(sm, ids, batch, mask, 0.1)
-        assert (np.asarray(mo["results"][0])
-                == np.asarray(mi["results"][0])).all(), r
-        assert (np.asarray(mo["n_valid"])
-                == np.asarray(mi["n_valid"])).all(), r
-    assert (np.asarray(ss.ps_weights) == np.asarray(sm.ps_weights)).all()
-
-
-def test_decode_overlap_metrics_contract():
-    """The adapter's metrics dict matches FedRuntime.round's contract
-    keys; signals is None (the split decouples what they compare)."""
-    cfg = make_cfg(decode_overlap=True)
-    rt = FedRuntime(cfg, make_params(), quad_loss, num_clients=16)
-    ov = DecodeOverlapRound(rt)
-    batch, mask, ids = make_batch(2, W=W, B=B)
-    _, m = ov.round(rt.init_state(), ids, batch, mask, 0.1)
-    rt_m = FedRuntime(make_cfg(signals=False), make_params(), quad_loss,
-                      num_clients=16)
-    _, mm = rt_m.round(rt_m.init_state(), ids, batch, mask, 0.1)
-    assert set(m) == set(mm), (sorted(m), sorted(mm))
-    assert m["signals"] is None
-    assert m["download_bytes"] is not None
-
-
-def test_decode_overlap_validation():
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        make_cfg(decode_overlap=True, async_agg=True)
-    with pytest.raises(ValueError, match="--decode_overlap"):
-        validate_overlap_combo(make_cfg(
-            decode_overlap=True, mode="local_topk", error_type="local",
-            local_momentum=0.9, k=5))
-    # the adapter refuses a runtime built without the split executables
-    rt = FedRuntime(make_cfg(), make_params(), quad_loss, num_clients=16)
-    with pytest.raises(ValueError, match="decode_overlap"):
-        DecodeOverlapRound(rt)
-
-
-def test_decode_overlap_driver_end_to_end(tmp_path):
-    """The driver loop's --decode_overlap branch (cv_train.train):
-    one synthetic-CIFAR epoch split vs monolithic, identical data order
-    (same seed), train losses bit-identical — the PR-5 gate pattern at
-    driver granularity."""
-    from commefficient_tpu import cv_train, models
-    from commefficient_tpu.data import FedCIFAR10, transforms_for
-    from commefficient_tpu.losses import make_cv_loss
-
-    def run(decode_overlap):
-        ds = FedCIFAR10(str(tmp_path / f"d{int(decode_overlap)}"),
-                        synthetic=True, synthetic_per_class=8,
-                        transform=transforms_for("CIFAR10", True, seed=0))
-        cfg = FedConfig(mode="sketch", error_type="virtual", k=10,
-                        num_rows=2, num_cols=64, num_blocks=2,
-                        sketch_impl="hash", local_momentum=0.0,
-                        virtual_momentum=0.9, num_workers=4,
-                        local_batch_size=4, num_clients=ds.num_clients,
-                        num_epochs=1.0, track_bytes=True,
-                        compute_dtype="float32", telemetry=False,
-                        decode_overlap=decode_overlap)
-        model = models.ResNet9(num_classes=10,
-                               channels={"prep": 2, "layer1": 2,
-                                         "layer2": 2, "layer3": 2})
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.ones((1, 32, 32, 3)))
-        rt = FedRuntime(cfg, params, make_cv_loss(model, "float32"),
-                        num_clients=ds.num_clients)
-        state, summary = cv_train.train(cfg, rt, rt.init_state(), ds, ds)
-        return summary
-
-    s_split = run(True)
-    s_mono = run(False)
-    assert s_split is not None and np.isfinite(s_split["train_loss"])
-    assert s_split["train_loss"] == s_mono["train_loss"], (
-        s_split["train_loss"], s_mono["train_loss"])
 
 
 # ----------------------------------------------------- byte-count accounting

@@ -48,19 +48,24 @@ class Recorder:
         pass
 
 
+def make_runtime(mode, mesh=None, **kw):
+    """The runtime of a tiny model, with the observability as shipped
+    unless ``kw`` says otherwise."""
+    cfg = FedConfig(**{**dict(
+        local_momentum=0.0, virtual_momentum=0.9, weight_decay=0.0,
+        num_workers=W, local_batch_size=B, track_bytes=True,
+        num_clients=8, num_results_train=2, num_results_val=2),
+        **MODES[mode], **kw})
+    params = {"w": jnp.asarray(
+        np.random.RandomState(0).randn(D_IN, D_OUT), jnp.float32)}
+    return FedRuntime(cfg, params, loss_fn, num_clients=8, mesh=mesh)
+
+
 def run_round(mode, mesh=None, lr=0.05, runtime=None, **kw):
-    """One round of a tiny model through a watched runtime, with the
-    observability as shipped unless ``kw`` says otherwise. Returns
-    (runtime, {instruction: phase}) of the compiled round."""
+    """One round through a watched runtime. Returns (runtime,
+    {instruction: phase}) of the compiled round."""
     if runtime is None:
-        cfg = FedConfig(**{**dict(
-            local_momentum=0.0, virtual_momentum=0.9, weight_decay=0.0,
-            num_workers=W, local_batch_size=B, track_bytes=True,
-            num_clients=8, num_results_train=2, num_results_val=2),
-            **MODES[mode], **kw})
-        params = {"w": jnp.asarray(
-            np.random.RandomState(0).randn(D_IN, D_OUT), jnp.float32)}
-        runtime = FedRuntime(cfg, params, loss_fn, num_clients=8, mesh=mesh)
+        runtime = make_runtime(mode, mesh=mesh, **kw)
         runtime.set_compile_watcher(compilewatch.JitWatcher(Recorder()))
     rng = np.random.RandomState(1)
     batch = {"x": jnp.asarray(rng.randn(W, B, D_IN), jnp.float32),
@@ -93,6 +98,58 @@ def test_observability_off_leaves_nothing_under_its_phases(mode):
     assert not phases_of(table) & set(OBSERVABILITY)
     assert {"fed_client_step", "fed_server_tail",
             "fed_byte_ledger"} <= phases_of(table)
+
+
+def client_step_instructions(lowered):
+    """(shape, opcode) of every instruction a lowering traced under
+    ``fed_client_step``, sorted, names aside: those whose ``op_name`` path
+    holds the scope, and those of the computations they call (an inner
+    function's instructions are named from its own root). Broadcasts of
+    constants are left out: the lowering shares one between its users,
+    and the first user's scope names it."""
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    bodies, comp = {}, None          # computation -> its instruction lines
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?([\w.\-]+)\s*\{\s*$", line)
+        m = phase_reader._LINE.match(line)
+        if head:
+            comp = bodies.setdefault(head.group(1), [])
+        elif m and comp is not None:
+            comp.append(m.groups())
+    todo = [ins for body in bodies.values() for ins in body
+            if "/fed_client_step/" in ins[1]]
+    under = {}                       # instruction name -> (shape, opcode)
+    while todo:
+        name, rest = todo.pop()
+        if name not in under:
+            under[name] = re.match(r"(.*?)\s([\w\-]+)\(", rest).groups()
+            for called in phase_reader._CALLS.findall(rest):
+                todo += bodies[called]
+    return sorted(f for f in under.values()
+                  if f[1] not in ("broadcast", "constant"))
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_round_and_cohort_trace_one_client_step(mode):
+    """The round and the async cohort run one client half
+    (``FedRuntime._client_half``): with the signals off in both (they are
+    off under ``--async_agg``), what the cohort traces under the client
+    step is what the round traces under it, instruction for
+    instruction."""
+    rt = make_runtime(mode, **OFF)
+    rt_async = make_runtime(mode, async_agg=True, max_inflight=1,
+                            buffer_goal=1, **OFF)
+    batch = {"x": jnp.zeros((W, B, D_IN)), "y": jnp.zeros((W, B, D_OUT))}
+    args = (jnp.arange(W, dtype=jnp.int32), batch, jnp.ones((W, B), bool),
+            jnp.asarray(0.05, jnp.float32))
+    in_round = client_step_instructions(
+        rt._round.lower(rt.init_state(), *args, rt.cs, rt._gid))
+    in_cohort = client_step_instructions(
+        rt_async._cohort.lower(rt_async.init_state(), *args, rt_async.cs))
+    assert len(in_round) > 20
+    assert in_cohort == in_round
+    opcodes = {op for _shape, op in in_round}
+    assert "dot" in opcodes and "while" in opcodes
 
 
 def test_innermost_scope_names_the_instruction():

@@ -17,7 +17,8 @@ import numpy as np
 import pytest
 
 from commefficient_tpu.config import FedConfig
-from commefficient_tpu.core import DecodeOverlapRound, FedRuntime
+from commefficient_tpu.core import AsyncAggregator, FedRuntime
+from commefficient_tpu.data.fed_sampler import Round
 from commefficient_tpu.ops.circulant import make_circulant_sketch
 from commefficient_tpu.ops.sketch import make_sketch
 from commefficient_tpu.ops.topk import (local_topk_candidates,
@@ -434,15 +435,47 @@ def test_sharded_round_per_param_lr_vector():
                                rtol=SHARDED_W_RTOL, atol=SHARDED_W_ATOL)
 
 
-def test_decode_overlap_composes_with_sharded_server():
-    """--decode_overlap + sharded server: the cohort ends at the LOCAL
-    partial tables (no collective), the decode executable runs the
-    deferred reduce-scatter + sharded tail — bit-identical to the
-    monolithic sharded round (the PR-9 gate pattern, extended)."""
-    _, losses_mono, w_mono = _run_rounds(_sketch_cfg())
+class _CohortCommitRound:
+    """``AsyncAggregator`` at K = 1, M = 1 behind ``FedRuntime.round``'s
+    signature: every round is one cohort, one ``merge_first`` and one
+    commit, the round's two halves as two executables."""
+
+    def __init__(self, runtime):
+        self.runtime = runtime
+        self.agg = AsyncAggregator(runtime)
+        self.tick = 0
+
+    def init_state(self):
+        return self.runtime.init_state()
+
+    def round(self, state, client_ids, batch, mask, lr):
+        self.tick += 1
+        rnd = Round(np.asarray(client_ids, np.int64),
+                    np.zeros(mask.shape, np.int64), np.asarray(mask))
+        state, metrics, commits = self.agg.step(state, rnd, self.tick,
+                                                batch, lr)
+        assert len(commits) == 1 and commits[0]["staleness_max"] == 0
+        return state, metrics
+
+
+@pytest.mark.parametrize("wire", ["float32", "bfloat16", "int8"])
+def test_cohort_commit_matches_round_on_the_mesh(wire):
+    """The client half and the server half as separate executables
+    (``--async_agg`` at K = 1, M = 1) reproduce the one-program round on
+    the mesh, sharded server tail and wire format included: losses and
+    weights BIT-identical. The cohort ends after the table's
+    reduce-scatter (bf16: rounded on the wire; int8: the quantized
+    all_to_all, its draws keyed by the server version both share)."""
+    variant = {"float32": {},
+               "bfloat16": {"sketch_dtype": "bfloat16"},
+               "int8": {"wire_dtype": "int8", "wire_block": 8}}[wire]
+    rt_mono, losses_mono, w_mono = _run_rounds(_sketch_cfg(**variant))
     rt, losses_split, w_split = _run_rounds(
-        _sketch_cfg(decode_overlap=True), adapter=DecodeOverlapRound)
-    assert rt._reduce_in_decode
+        _sketch_cfg(async_agg=True, max_inflight=1, buffer_goal=1,
+                    **variant),
+        adapter=_CohortCommitRound)
+    assert rt_mono._sharded_server and rt._sharded_server
+    assert rt._int8_wire == (wire == "int8")
     assert (losses_split == losses_mono).all()
     assert (w_split == w_mono).all()
 
