@@ -197,7 +197,11 @@ def encode_grad_tree(cs, table, gtree, scale=None, token=None,
     shifts — CirculantSketch._use_pallas_encode), the whole-vector route
     is faster than per-chunk rolls, so the tree IS raveled once and
     encoded in one kernel call — one (d,) buffer inside the scan step
-    instead of the unfused path's persistent (d,) carry pair.
+    instead of the unfused path's persistent (d,) carry pair. That
+    ravel is the only d-long pass XLA makes there: it ends in the
+    m·c - d zeros of the last block (on the last leaf, or a leaf of
+    their own), ``scale`` goes to the kernel as a scalar and the kernel
+    makes its wrap padding in VMEM (ops/circulant_pallas.py v7).
 
     Returns ``table + encode(scale * ravel(gtree))`` up to fp addition
     order (sketch linearity; pinned by tests/test_fused_encode.py).
@@ -205,7 +209,22 @@ def encode_grad_tree(cs, table, gtree, scale=None, token=None,
     with phase("fed_sketch_encode"):
         leaves = jax.tree_util.tree_leaves(gtree)
         if getattr(cs, "_use_pallas_encode", lambda: False)():
-            flat = jnp.concatenate([l.reshape(-1) for l in leaves])
+            # the ravel lands at the kernel's padded length in the pass
+            # it makes anyway. The zeros that close the last block ride
+            # on the last leaf where a copy of it is cheap (under 1/64
+            # of the ravel): every operand is then still a leaf, and XLA
+            # goes on moving the leaves' bf16 -> f32 converts past the
+            # concatenate (ResNet-50: leaves born f32 hold 26 MB more at
+            # the round's peak). Behind a large last leaf (GPT-2's
+            # embedding) they are a leaf of their own
+            flats = [l.reshape(-1) for l in leaves]
+            size = sum(f.shape[0] for f in flats)
+            tail = cs.m * cs.c - size
+            if 64 * flats[-1].shape[0] <= size:
+                flats[-1] = jnp.pad(flats[-1], (0, tail))
+            else:
+                flats.append(jnp.zeros((tail,), flats[-1].dtype))
+            flat = jnp.concatenate(flats)
             return cs.encode_accum(table, flat, 0, scale=scale, token=token)
         if max_chunk <= 0:
             max_chunk = _encode_chunk_max(int(getattr(cs, "d", 0)))
@@ -589,26 +608,37 @@ def make_fused_grad(
                 jnp.stack((loss,) + tuple(metrics)) * w)
             return (g_acc, sums), None
 
+        # decoupled weight decay, summed over the round's clients (equal to
+        # the per-client term (wd/W)*w scaled by n_c and summed); fused-
+        # encode streams it into a table by the same linearity
+        wd_scale = (cfg.weight_decay / cfg.num_workers) * n_per_client.sum()
+        wd_table = None
         if fused_encode:
             assert cs is not None, "fused encode requires the runtime's sketch"
             g_init = cs.empty_table()
+            if cfg.weight_decay != 0:
+                # made before the scan and added after it: the barrier
+                # holds XLA to that order, so the whole f32 parameter
+                # vector (all-gathered on a mesh) is dead before the
+                # clients' activations come alive. Left to itself XLA
+                # kept a d-long copy of it across the loop once the
+                # encode was one kernel call and a pad (+90 MB on a peak
+                # of 4.09 GiB a chip, ResNet-50 on four chips, PR 31)
+                with phase("fed_sketch_encode"):
+                    wd_table = cs.encode_accum(g_init, params_vec, 0,
+                                               scale=wd_scale)
+                wd_table, g_init = lax.optimization_barrier(
+                    (wd_table, g_init))
         else:
             g_init = jnp.zeros_like(params_vec)
         init = (g_init, jnp.zeros((n_res, W)))
         (g, sums), _ = lax.scan(
             body, init, (flat, flat_mask, client_of_mb, nc_of_mb))
-        # decoupled weight decay, summed over the round's clients (equal to
-        # the per-client term (wd/W)*w scaled by n_c and summed); fused-
-        # encode streams it into the table by the same linearity
-        if cfg.weight_decay != 0:
-            wd_scale = ((cfg.weight_decay / cfg.num_workers)
-                        * n_per_client.sum())
-            if fused_encode:
-                with phase("fed_sketch_encode"):
-                    g = cs.encode_accum(g, params_vec, 0, scale=wd_scale,
-                                        token=sums[0].sum())
-            else:
-                g = g + wd_scale * params_vec
+        if wd_table is not None:
+            with phase("fed_sketch_encode"):
+                g = g + wd_table
+        elif cfg.weight_decay != 0:
+            g = g + wd_scale * params_vec
         denom = jnp.maximum(n_per_client, 1.0)
         results = tuple(sums[j] / denom for j in range(n_res))
         return g, results, n_per_client

@@ -205,10 +205,7 @@ class CirculantSketch:
         assert vec.ndim == 1 and vec.shape[0] == self.d, (vec.shape, self.d)
         m, c = self.m, self.c
         if self._use_pallas_encode():
-            from commefficient_tpu.ops.circulant_pallas import pallas_encode
-            vp = jnp.pad(vec.astype(jnp.float32), (0, m * c - self.d))
-            return pallas_encode(vp, jnp.asarray(self.shifts, jnp.int32),
-                                 self.sign_keys, c=c, r=self.r, m=m)
+            return self._pallas_encode(vec)
         vp = jnp.pad(vec.astype(jnp.float32), (0, m * c - self.d)).reshape(
             m, c)
         rows = []
@@ -223,6 +220,21 @@ class CirculantSketch:
                     sv, self._row_shift_idx(j, sign=1), axis=1)
             rows.append(rolled.sum(axis=0))
         return jnp.stack(rows)
+
+    def _pallas_encode(self, vec: jax.Array, scale=None) -> jax.Array:
+        """The table of ``scale * vec`` by the fused kernel, which scales
+        and wrap-pads in VMEM: a vector that comes m·c long (the ravel
+        of ``encode_grad_tree``, zeros from d on) is read where it lies;
+        a (d,) one pays the one pad to m·c."""
+        from commefficient_tpu.ops.circulant_pallas import pallas_encode
+        m, c = self.m, self.c
+        vec = vec.astype(jnp.float32)
+        if vec.shape[0] != m * c:
+            vec = jnp.pad(vec, (0, m * c - self.d))
+        return pallas_encode(vec, jnp.asarray(self.shifts, jnp.int32),
+                             self.sign_keys,
+                             1.0 if scale is None else scale,
+                             c=c, r=self.r, m=m)
 
     def encode_accum(self, table: jax.Array, vals: jax.Array,
                      start: int = 0, scale=None,
@@ -242,25 +254,24 @@ class CirculantSketch:
         ``scale`` multiplies the values before encoding (linearity);
         ``token`` is any loop-varying scalar defeating while-loop sign
         hoisting (ops/sketch.py loop_token_zero). The whole-vector call
-        (``start == 0``, full d) routes through the fused Pallas encode
-        kernel when eligible — the accumulate is then one table add."""
+        (``start == 0``, d long or already m·c long with zeros from d
+        on) routes through the fused Pallas encode kernel when eligible
+        — the accumulate is then one table add, and nothing d-long
+        happens in XLA but the pad of a (d,) vector: the kernel takes
+        the scale as a scalar and makes its wrap in VMEM."""
         assert vals.ndim == 1, vals.shape
         assert table.shape == self.table_shape, (table.shape,
                                                  self.table_shape)
         start = int(start)
         assert start >= 0 and start + vals.shape[0] <= self.m * self.c, (
             start, vals.shape, self.d)
+        m, c = self.m, self.c
+        if start == 0 and vals.shape[0] in (self.d, m * c) \
+                and self._use_pallas_encode():
+            return table + self._pallas_encode(vals, scale)
         vals = vals.astype(jnp.float32)
         if scale is not None:
             vals = vals * scale
-        m, c = self.m, self.c
-        if start == 0 and vals.shape[0] == self.d \
-                and self._use_pallas_encode():
-            from commefficient_tpu.ops.circulant_pallas import pallas_encode
-            vp = jnp.pad(vals, (0, m * c - self.d))
-            return table + pallas_encode(
-                vp, jnp.asarray(self.shifts, jnp.int32), self.sign_keys,
-                c=c, r=self.r, m=m)
         n = vals.shape[0]
         b0 = start // c
         o0 = start - b0 * c

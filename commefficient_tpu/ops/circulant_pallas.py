@@ -25,7 +25,7 @@ Design history (all numbers measured the same way):
   address arithmetic, no data movement beyond the copy itself.
   Measured: decode 21 ms (6× over the roll path), with the whole table
   loaded into VMEM once (constant index map).
-- v5 (this file, PR 27) is v4 with the encode's grid turned inside out.
+- v5 (PR 27) is v4 with the encode's grid turned inside out.
   v4's encode ran a grid (lane tiles, blocks) and DMA'd one whole
   wrap-padded block per step, so the d-long input crossed HBM once per
   lane tile: 163 times at c = 500,736 = 3·163·1024 (largest aligned
@@ -48,6 +48,31 @@ Design history (all numbers measured the same way):
   chunk — 295 ms of a 372 ms round on four v5e chips at d = 25.5M,
   against 0.19 ms for the 14 of 51 blocks a chip needs. The whole
   decode is the same kernel at ``first = 0`` over all m blocks.
+- v7 (PR 31) takes the encode's input as the ravel left it. v5's
+  caller handed the kernel a scaled, zero-padded and wrap-padded copy:
+  ``vals * scale``, ``jnp.pad`` to m·c and ``_wrap_pad`` to
+  (m, c/128 + sub, 128) were three XLA instructions, each one read and
+  one write of d floats in HBM before every call — 113 of a 1,518 ms
+  round at d = 3.9e8 and 36 of 399 ms at d = 1.24e8, eight calls a
+  round, against 51 and 16 ms for the kernel itself. Now the input
+  BlockSpec is the plain (1, c/128, 128) block of the (m, c/128, 128)
+  view (a bitcast of the m·c-long vector), the scale is a third
+  prefetched scalar, and each grid step fills a VMEM scratch of
+  (c/128 + sub, 128) with ``block * scale`` and the block's own first
+  ``sub`` rows; the tile loop is v5's, reading the scratch. The product
+  is taken once an element, as XLA took it, and the additions are
+  v5's in v5's order: the table is bit-identical to v5's
+  ``encode(pad(v * scale))`` (on the chip at m = 51, 238 and 744).
+  Measured a call inside a scan that produces its gradient, v5e,
+  v5 -> v7: kernel 0.573 -> 0.588 ms (c = 500,736, d = 25.5M), 2.050 ->
+  2.160 (c = 2^19, d = 124.4M), 6.398 -> 6.740 (d = 389.6M): the fill is
+  1.35 cycles a vreg beside the hash's 5 a vreg and row; everything
+  else in the call 1.31 -> 0.59, 9.06 -> 4.57, 29.3 -> 15.1 ms, what is
+  left being the ravel. Tried and dropped, all bit-identical: walking
+  the block in input order and wrapping the *output* span a vreg at a
+  time (no scratch at all) 3.79 ms at d = 124.4M, a read-modify-write
+  at a dynamic address orders itself after the one before; per-vreg
+  dynamic loads straight from the input block 5.59 ms.
 
 Exactness vs the roll path is asserted in interpret mode by
 tests/test_ops.py and against numpy on the TPU at flagship scale.
@@ -87,11 +112,12 @@ SHIFT_ALIGN = 1024
 # accumulator. Checked on a v5e under jax 0.9.0 / libtpu 0.0.34: the
 # decode compiles under Mosaic's default scoped-VMEM limit at 10.1 MB
 # (r=5, c=500,736) and 11.8 MB (r=5, c=524,288), PR 21. The encode,
-# which Pallas gives two buffers of its table and of its input block,
-# asks for them with vmem_limit_bytes (_encode_vmem_limit): 28.3 MB and
-# 29.4 MB at those two geometries ran on the chip (PR 27), and the most
-# any sketch under this budget asks for, 54.5 MB at r=1, c=3,140,608,
-# compiles (tests/test_tpu_compile.py) against the core's 128 MiB.
+# which Pallas gives two buffers of its table and of its input block
+# and which keeps the scaled, wrap-padded block in a scratch of its
+# own, asks for them with vmem_limit_bytes (_encode_vmem_limit): 30.2 MB
+# and 31.5 MB at those two geometries, and the most any sketch under
+# this budget asks for, 67.0 MB at r=1, c=3,140,608, compiles
+# (tests/test_tpu_compile.py) against the core's 128 MiB.
 TABLE_VMEM_BUDGET = 12 << 20
 
 # lane-tile width of the decode's streamed output spans (a DMA unit)
@@ -102,6 +128,12 @@ _CT_MAX = 65536
 # resident table, so the tile only has to keep that working set near
 # the vregs (8192 lanes = 8 vregs a span)
 _ENCODE_CT_MAX = 8192
+
+# sublane-rows one step of the encode's fill loop scales and copies: 32
+# vregs of loads, products and stores with nothing between them to wait
+# for. A lane tile a step (24 rows at c = 500,736) measured +9.7% on the
+# kernel where this measures +2.7% (design history above, v7)
+_FILL_ROWS = 256
 
 
 def _lane_tile(c: int, cap: int | None = None) -> int:
@@ -126,17 +158,18 @@ def table_vmem_bytes(c: int, r: int) -> int:
 
 def encode_hbm_bytes(c: int, r: int, m: int) -> int:
     """HBM bytes one ``pallas_encode`` call moves by its own BlockSpecs:
-    each of the m wrap-padded input blocks fetched once, the (r, c)
+    each of the m input blocks fetched once as it lies, the (r, c)
     table written back once. Against the d + r·c floats the algorithm
     needs, it says how many passes over the input the grid makes."""
-    return 4 * (m * (c + _encode_tile(c)) + r * c)
+    return 4 * (m * c + r * c)
 
 
 def _encode_vmem_limit(c: int, r: int) -> int:
     """Scoped-VMEM request of the encode: Pallas holds two buffers of
-    the resident table and of the streamed input block; 4 MiB over that
-    for Mosaic's own temporaries."""
-    return 2 * 4 * (r * c + c + _encode_tile(c)) + (4 << 20)
+    the resident table and of the streamed input block, the kernel one
+    scratch of the scaled block with its wrap; 4 MiB over that for
+    Mosaic's own temporaries."""
+    return 4 * (2 * (r * c + c) + c + _encode_tile(c)) + (4 << 20)
 
 
 def _signs2d(start, sub, key):
@@ -174,13 +207,32 @@ def _decode_kernel(first_ref, shifts_ref, keys_ref, t_ref, out_ref, *,
     out_ref[0, 0] = median_axis0(jnp.stack(ests, axis=0))
 
 
-def _encode_kernel(shifts_ref, keys_ref, v_ref, out_ref, *, c, r, ct):
+def _encode_kernel(shifts_ref, keys_ref, scale_ref, v_ref, out_ref, buf_ref,
+                   *, c, r, ct):
     b = pl.program_id(0)
-    sub = ct // 128
+    sub, csub = ct // 128, c // 128
 
     @pl.when(b == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
+
+    # the block as the spans want it, made here in VMEM and not by XLA
+    # in HBM: scaled once an element, its first ``sub`` rows appended so
+    # a mod-c span never wraps. Chunks of _FILL_ROWS and what is left
+    # of a c/128 they do not divide
+    scale = scale_ref[0]
+    rows = min(_FILL_ROWS, csub)
+
+    def fill(i, carry):
+        at = pl.ds(pl.multiple_of(i * rows, 8), rows)
+        buf_ref[at] = v_ref[0, at] * scale
+        return carry
+
+    lax.fori_loop(0, csub // rows, fill, 0)
+    if csub % rows:
+        at = pl.ds(csub // rows * rows, csub % rows)
+        buf_ref[at] = v_ref[0, at] * scale
+    buf_ref[pl.ds(csub, sub)] = buf_ref[pl.ds(0, sub)]
 
     # table[j, i] += sign(b·c + (i − s) mod c) · v_b[(i − s) mod c]: lane
     # tile t of row j reads the span that starts back[j] + t·ct (mod c)
@@ -194,7 +246,7 @@ def _encode_kernel(shifts_ref, keys_ref, v_ref, out_ref, *, c, r, ct):
         for j in range(r):
             q = t * ct + back[j]
             q = q - jnp.where(q >= c, c, 0)
-            span = v_ref[0, pl.ds(pl.multiple_of(q // 128, 8), sub)]
+            span = buf_ref[pl.ds(pl.multiple_of(q // 128, 8), sub)]
             # the span crosses the block's mod-c seam at most once, so
             # one conditional subtract realizes the mod
             pos = q + lanes
@@ -224,24 +276,26 @@ def _wrap_pad(x3, sub):
 
 
 @functools.partial(jax.jit, static_argnames=("c", "r", "m", "interpret"))
-def pallas_encode(vec_padded, shifts, sign_keys, *, c, r, m,
+def pallas_encode(vec_padded, shifts, sign_keys, scale=1.0, *, c, r, m,
                   interpret=False):
-    """(m*c,) padded fp32 vector -> (r, c) table. ``shifts``: (r, m) int32
-    multiples of SHIFT_ALIGN; ``sign_keys``: (r,) uint32."""
+    """(m*c,) zero-padded fp32 vector -> the (r, c) table of ``scale``
+    times it. ``shifts``: (r, m) int32 multiples of SHIFT_ALIGN;
+    ``sign_keys``: (r,) uint32; ``scale``: a scalar, may be traced. The
+    vector is read where it lies: the view below is a bitcast, and
+    scale and wrap are made in the kernel."""
     ct = _encode_tile(c)
     sub, csub = ct // 128, c // 128
-    blocks = _wrap_pad(
-        vec_padded.astype(jnp.float32).reshape(m, csub, 128), sub)
+    blocks = vec_padded.astype(jnp.float32).reshape(m, csub, 128)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         # one step a vector block: block b crosses HBM -> VMEM once (ONE
         # DMA) and is added into all r rows of the table, which a
         # constant index map keeps resident for all m steps and writes
         # back once. The steps accumulate, so the axis is sequential
         grid=(m,),
-        in_specs=[pl.BlockSpec((1, csub + sub, 128),
-                               lambda b, *_: (b, 0, 0))],
+        in_specs=[pl.BlockSpec((1, csub, 128), lambda b, *_: (b, 0, 0))],
         out_specs=pl.BlockSpec((r, csub, 128), lambda b, *_: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((csub + sub, 128), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_encode_kernel, c=c, r=r, ct=ct),
@@ -252,7 +306,7 @@ def pallas_encode(vec_padded, shifts, sign_keys, *, c, r, m,
             vmem_limit_bytes=_encode_vmem_limit(c, r)),
         interpret=interpret,
         name=ENCODE_KERNEL_NAME,
-    )(shifts, sign_keys, blocks)
+    )(shifts, sign_keys, jnp.asarray(scale, jnp.float32).reshape(1), blocks)
     return out.reshape(r, c)
 
 

@@ -150,6 +150,69 @@ def test_encode_grad_tree_matches_ravel_encode(impl):
                                rtol=1e-5, atol=1e-4)
 
 
+# (d, scale, last leaf): a ragged last block, with no scale, a weak-typed
+# one and a traced one; d = m*c, where the zeros tail is empty; the zeros
+# as a leaf of their own behind a large last leaf, and on a small one
+@pytest.mark.parametrize("d_extra,scale,small_last", [
+    (1877, None, False), (1877, 0.37, False), (1877, "traced", False),
+    (0, "traced", False), (1877, "traced", True), (0, None, True),
+], ids=["ragged_no_scale", "ragged_python_scale", "ragged_traced_scale",
+        "whole_blocks_traced_scale", "ragged_zeros_on_last_leaf",
+        "whole_blocks_small_last_leaf"])
+def test_encode_grad_tree_pallas_route_equals_parents_table(
+        monkeypatch, d_extra, scale, small_last):
+    """Where the Pallas kernels serve the sketch, ``encode_grad_tree``
+    ravels the tree once, to m*c with a zeros tail, and hands the scale
+    to the kernel as a scalar (interpret mode here). The table is the
+    parent's bit for bit: there XLA took ``ravel * scale``, padded it to
+    m*c and appended each block's wrap in three d-long passes, and the
+    kernel added the blocks in order."""
+    import functools
+    from commefficient_tpu.ops import circulant as circ
+    from commefficient_tpu.ops import circulant_pallas as cp
+    from tests.test_ops import TestCirculantSketch
+    c, r = 2048, 3
+    rng = np.random.RandomState(31)
+    gtree = {
+        "a_bias": jnp.asarray(rng.randn(7), jnp.float32),
+        "b_kernel": jnp.asarray(rng.randn(90, 30), jnp.float32),
+        "c_bias": jnp.asarray(rng.randn(11), jnp.float32),
+        "d_kernel": jnp.asarray(rng.randn(3 * c - 2718 + d_extra
+                                          - 13 * small_last), jnp.float32),
+    }
+    if small_last:
+        gtree["e_bias"] = jnp.asarray(rng.randn(13), jnp.float32)
+    flat, _ = ravel_pytree(gtree)
+    d = flat.shape[0]
+    last = jax.tree_util.tree_leaves(gtree)[-1]
+    assert (64 * last.size <= d) == small_last
+    cs = circ.make_circulant_sketch(d=d, c=c, r=r, seed=31)
+    assert (d % c == 0) == (d_extra == 0) and cs.m in (3, 4)
+    monkeypatch.setattr(circ.CirculantSketch, "_use_pallas_encode",
+                        lambda self: True)
+    monkeypatch.setattr(cp, "pallas_encode", functools.partial(
+        cp.pallas_encode, interpret=True))
+    table0 = jnp.asarray(rng.randn(r, c), jnp.float32)
+    if scale == "traced":
+        got = jax.jit(lambda t, g, s: encode_grad_tree(cs, t, g, scale=s))(
+            table0, gtree, jnp.float32(2.5))
+        factor = np.float32(2.5)
+    else:
+        got = encode_grad_tree(cs, table0, gtree, scale=scale)
+        factor = np.float32(1.0 if scale is None else scale)
+    want = np.asarray(table0) + TestCirculantSketch._encode_in_block_order(
+        cs, factor * np.asarray(flat))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # the (d,) entry, the weight-decay encode of params_vec: one pad, the
+    # same kernel, the same table
+    got_d = cs.encode_accum(table0, flat, 0, scale=None if scale is None
+                            else factor)
+    np.testing.assert_array_equal(np.asarray(got_d), want)
+    np.testing.assert_array_equal(
+        np.asarray(cs.encode(flat)),
+        TestCirculantSketch._encode_in_block_order(cs, np.asarray(flat)))
+
+
 def test_streaming_grad_matches_jax_grad():
     """models/stream_mlp.py's manual VJP: the streamed table equals
     encode(jax.grad) of the same loss in ravel layout, the loss matches

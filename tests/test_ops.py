@@ -473,29 +473,49 @@ class TestCirculantSketch:
                                     cs.shifts[j][b])
         return table
 
-    # (c, d, cap on the encode's tile, tile it must pick, seam shifts);
-    # every case has its own m: pallas_encode is jit-cached on (c, r, m,
-    # interpret) and a collision would reuse another case's tile
-    @pytest.mark.parametrize("c,d,cap,tile,seam", [
-        (2048, 7000, None, 2048, False),     # one tile
-        (2048, 13000, 1024, 1024, False),    # two tiles
+    # (c, d, cap on the encode's tile, tile it must pick, seam shifts,
+    # scale, cap on the fill's chunk); every case has its own m:
+    # pallas_encode is jit-cached on (c, r, m, interpret) and a collision
+    # would reuse another case's tile. No d is a multiple of c or of 128:
+    # the last block is ragged in every case
+    @pytest.mark.parametrize("c,d,cap,tile,seam,scale,fill", [
+        (2048, 7000, None, 2048, False, 1.0, None),     # one tile
+        (2048, 13000, 1024, 1024, False, 1.0, None),    # two tiles
         # c = 500,736 = 3 * 163 * 1024 in miniature: a non-power-of-two
         # c whose tile is 3,072; spans cross tile edges and the mod-c seam
-        (9216, 40000, None, 3072, False),
-        (9216, 30000, None, 3072, True),     # shifts 0 and c - 1024 in a row
-    ], ids=["one_tile", "two_tiles", "c9216_tile3072", "shift_0_and_c-1024"])
+        (9216, 40000, None, 3072, False, 1.0, None),
+        # shifts 0 and c - 1024 in every row: the span that crosses the
+        # seam lies in the first tile and in the last
+        (9216, 30000, None, 3072, True, 1.0, None),
+        (2048, 15001, None, 2048, False, 0.37, None),
+        (2048, 17003, 1024, 1024, False, 1.0 / 3.0, None),
+        (9216, 50003, None, 3072, True, 3.3, None),
+        # c/128 = 72 rows filled as 2 chunks of 32 and 8 left over, as
+        # c = 500,736 fills 3,912 rows in 15 chunks of 256 and 72
+        (9216, 60001, None, 3072, False, 0.37, 32),
+        (9216, 70001, None, 3072, True, 1.0, 32),
+    ], ids=["one_tile", "two_tiles", "c9216_tile3072", "shift_0_and_c-1024",
+            "scale_0.37", "two_tiles_scale_third", "seam_scale_3.3",
+            "fill_chunks_and_rest_scale_0.37", "fill_chunks_and_rest_seam"])
     def test_pallas_encode_one_pass_bit_exact(self, monkeypatch, c, d, cap,
-                                              tile, seam):
+                                              tile, seam, scale, fill):
         """The one-pass encode (table resident, lane tiles looped inside
-        the kernel) against the roll path, and bit for bit against the
-        block-order reference: the same float32 additions in the same
-        order as the (tile, block) grid it replaced."""
+        the kernel; the block scaled and wrap-padded in VMEM by the
+        kernel, the scale a prefetched scalar) against the roll path's
+        ``encode(scale * v)``, and bit for bit against the block-order
+        reference of ``scale * v``: the table the parent's kernel made
+        of the scaled, padded, wrap-padded copy XLA handed it, the same
+        float32 additions in the same order."""
         import dataclasses
         from commefficient_tpu.ops import circulant as circ
         from commefficient_tpu.ops import circulant_pallas as cp
         if cap is not None:
             monkeypatch.setattr(cp, "_ENCODE_CT_MAX", cap)
+        if fill is not None:
+            monkeypatch.setattr(cp, "_FILL_ROWS", fill)
+            assert (c // 128) // fill >= 2 and (c // 128) % fill
         assert cp._encode_tile(c) == tile
+        assert d % c and d % 128
         cs = circ.make_circulant_sketch(d=d, c=c, r=5, seed=c + d)
         if seam:
             cs = dataclasses.replace(cs, shifts=tuple(
@@ -509,30 +529,56 @@ class TestCirculantSketch:
         v = rng.randn(d).astype(np.float32)
         vp = jnp.pad(jnp.asarray(v), (0, cs.m * c - d))
         t_pl = np.asarray(cp.pallas_encode(
-            vp, jnp.asarray(cs.shifts, jnp.int32), cs.sign_keys, c=c,
-            r=cs.r, m=cs.m, interpret=True))
-        np.testing.assert_allclose(t_pl, np.asarray(cs.encode(v)),
+            vp, jnp.asarray(cs.shifts, jnp.int32), cs.sign_keys,
+            jnp.float32(scale), c=c, r=cs.r, m=cs.m, interpret=True))
+        scaled = np.float32(scale) * v
+        np.testing.assert_allclose(t_pl, np.asarray(cs.encode(scaled)),
                                    atol=1e-4)
-        np.testing.assert_array_equal(t_pl,
-                                      self._encode_in_block_order(cs, v))
+        np.testing.assert_array_equal(
+            t_pl, self._encode_in_block_order(cs, scaled))
+
+    def test_pallas_encode_batches_vector_and_scale(self):
+        """The per-client path runs ``cs.encode`` under ``jax.vmap``: the
+        kernel's scratch and its scalar batch, with one scale for all
+        rows of the batch or one a row."""
+        from commefficient_tpu.ops import circulant as circ
+        from commefficient_tpu.ops import circulant_pallas as cp
+        cs = circ.make_circulant_sketch(d=21001, c=2048, r=3, seed=31)
+        shifts = jnp.asarray(cs.shifts, jnp.int32)
+        rng = np.random.RandomState(31)
+        vs = np.zeros((3, cs.m * cs.c), np.float32)
+        vs[:, :cs.d] = rng.randn(3, cs.d)
+        scales = np.asarray([1.0, 2.5, 0.3], np.float32)
+
+        def enc(v, s):
+            return cp.pallas_encode(v, shifts, cs.sign_keys, s, c=cs.c,
+                                    r=cs.r, m=cs.m, interpret=True)
+
+        one = np.asarray(jax.vmap(lambda v: enc(v, 2.5))(jnp.asarray(vs)))
+        each = np.asarray(jax.vmap(enc)(jnp.asarray(vs), jnp.asarray(scales)))
+        for i in range(3):
+            np.testing.assert_array_equal(one[i], self._encode_in_block_order(
+                cs, np.float32(2.5) * vs[i, :cs.d]))
+            np.testing.assert_array_equal(each[i], self._encode_in_block_order(
+                cs, scales[i] * vs[i, :cs.d]))
 
     @pytest.mark.parametrize("c,r,d", [
         (500736, 5, 25504026),     # rn50_sketch_8x64
         (524288, 5, 124444416),    # gpt2_sketch_8x8x2x256
     ])
     def test_encode_hbm_bytes_is_one_pass(self, c, r, d):
-        """The encode's own BlockSpecs move the input once and the table
-        once; wrap padding is all that separates that from what the
-        algorithm needs. The (lane tile, block) grid this replaced
-        fetched every block once per lane tile: 150x and 8.8x."""
+        """The encode's own BlockSpecs move the input once, as it lies,
+        and the table once; the zeros that close the last block are all
+        that separates that from what the algorithm needs. The (lane
+        tile, block) grid of v4 fetched every block once per lane tile:
+        150x and 8.8x."""
         from commefficient_tpu.ops import circulant_pallas as cp
         from perfbench.harness.arith import sketch_encode_bytes
         m = -(-d // c)
-        ct = cp._encode_tile(c)
         moved = cp.encode_hbm_bytes(c, r, m)
-        assert moved == 4 * (m * (c + ct) + r * c)
+        assert moved == 4 * (m * c + r * c)
         need = sketch_encode_bytes(d, r, c)
-        assert 1.0 <= moved / need < 1.2
+        assert 1.0 <= moved / need < 1.005
         pt = cp._lane_tile(c)
         per_tile_grid = 4 * ((c // pt) * m * (c + pt) + r * c)
         assert per_tile_grid / need > 8

@@ -10,6 +10,9 @@ process at a time may load the TPU library, and under pytest-xdist only
 the worker given this file does.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,7 +41,8 @@ def one_chip(topo):
 # (c, r, m, widest): the benchmark's two sketch geometries at their real m, and
 # the widest c CirculantSketch.pallas_blocker lets through at r = 1 and
 # r = 5 (TABLE_VMEM_BUDGET on the decode's wrap-padded table): at r = 1
-# the encode asks for the most VMEM any eligible sketch does, 54.5 MB
+# the encode asks for the most VMEM any eligible sketch does, 67.0 MB
+# with the scratch that holds the scaled, wrap-padded block
 @pytest.mark.parametrize("c,r,m,widest", [
     (500736, 5, 51, False),
     (524288, 5, 238, False),
@@ -51,15 +55,94 @@ def test_encode_compiles_with_table_resident(one_chip, c, r, m, widest):
     assert cp.table_vmem_bytes(c, r) <= cp.TABLE_VMEM_BUDGET
     if widest:
         assert cp.table_vmem_bytes(c + 1024, r) > cp.TABLE_VMEM_BUDGET
+    assert cp._encode_vmem_limit(c, r) <= (67 << 20)
     args = (jax.ShapeDtypeStruct((m * c,), jnp.float32, sharding=one_chip),
             jax.ShapeDtypeStruct((r, m), jnp.int32, sharding=one_chip),
-            jax.ShapeDtypeStruct((r,), jnp.uint32, sharding=one_chip))
+            jax.ShapeDtypeStruct((r,), jnp.uint32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip))
     hlo = cp.pallas_encode.lower(*args, c=c, r=r, m=m).compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     assert cp.ENCODE_KERNEL_NAME in hlo
     # the table is the kernel's one output, whole: no lane-tile axis is
     # left in the grid for the input to be streamed along
     assert f"f32[{r},{c // 128},128]" in hlo
+    # the vector goes in as it lies (a bitcast view), the scale as a
+    # scalar: no wrap-padded copy, no product in XLA
+    sub = cp._encode_tile(c) // 128
+    assert f"f32[{m},{c // 128},128]" in hlo
+    assert f"f32[{m},{c // 128 + sub},128]" not in hlo
+    assert " multiply(" not in hlo and " pad(" not in hlo
+
+
+# (leaves flat?, bound on the temporaries in units of 4·m·c): the ravel's
+# buffer is the one d-long temporary (0.5 GB; the parent held two,
+# 1,007 MB either way). Leaves in GPT-2's shapes add their relayouts to
+# one dimension (309 MB at once, the model's with or without this route)
+# The zeros that close the last block are a leaf of their own behind
+# GPT-2's embedding and ride on a small last leaf (one short ``pad``)
+@pytest.mark.parametrize("flat,small_last,temp_bound", [
+    (True, False, 1.5), (False, False, 1.75), (True, True, 1.5),
+], ids=["flat_leaves", "gpt2_shapes", "flat_leaves_small_last"])
+def test_fused_encode_route_reads_the_ravel_as_it_lies(one_chip, monkeypatch,
+                                                       flat, small_last,
+                                                       temp_bound):
+    """``encode_grad_tree``'s Pallas route at GPT-2's geometry
+    (d = 124,444,416, c = 524,288, m = 238) with a traced scale: the
+    ravel (in-place ``dynamic-update-slice`` writes into one m*c-long
+    buffer, zeros tail included) is the only instruction with a d-long
+    output. The scale, the pad to m*c and the wrap copy that were three
+    more passes over d in HBM before every kernel call are gone, and
+    with them one of the two d-long temporaries."""
+    from commefficient_tpu.core.client import encode_grad_tree
+    from commefficient_tpu.ops.circulant import make_circulant_sketch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c, r, m, L, E = 524288, 5, 238, 12, 768
+    shapes = {"wte": (50262, E), "wpe": (1024, E), "ln_f": (2, E),
+              "mc_head": (E,), "h_ln": (L, 4, E),
+              "h_attn_w": (L, E, 3 * E), "h_attn_b": (L, 3 * E),
+              "h_proj_w": (L, E, E), "h_proj_b": (L, E),
+              "h_fc_w": (L, E, 4 * E), "h_fc_b": (L, 4 * E),
+              "h_out_w": (L, 4 * E, E), "h_out_b": (L, E)}
+    if small_last:
+        shapes["z_bias"] = (E,)
+    d = sum(int(np.prod(s)) for s in shapes.values())
+    assert d == 124444416 + E * small_last
+    cs = make_circulant_sketch(d, c, r, seed=42)
+    assert cs.pallas_blocker() is None and cs.m == m and m * c > d
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda table, gtree, scale, keys: encode_grad_tree(
+        dataclasses.replace(cs, sign_keys=keys), table, gtree, scale=scale))
+    gtree = {k: sds((int(np.prod(s)),) if flat else s)
+             for k, s in shapes.items()}
+    compiled = fn.lower(sds((r, c)), gtree, sds(()),
+                        sds((r,), jnp.uint32)).compile()
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and cp.ENCODE_KERNEL_NAME in calls[0]
+    long = []
+    for line in hlo.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%?[\w.-]+ = f32\[([\d,]+)\]\S* "
+                         r"([\w-]+)\(", line)
+        if found and np.prod([int(n) for n in found[1].split(",")]) >= d:
+            long.append((found[2], line))
+    # the m*c-long buffer (an AllocateBuffer custom-call), the in-place
+    # writes of the leaves and the zeros into it, alone or as fusions
+    # named for them, and the kernel's view of it
+    assert {op for op, _ in long} <= {
+        "custom-call", "parameter", "dynamic-update-slice", "fusion",
+        "bitcast"}, long
+    assert all("dynamic-update-slice" in line for op, line in long
+               if op == "fusion")
+    sub = cp._encode_tile(c) // 128
+    assert f"f32[{m},{c // 128 + sub},128]" not in hlo
+    assert " multiply(" not in hlo
+    assert (" pad(" in hlo) == small_last
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < temp_bound * 4 * m * c)
 
 
 def test_decode_compiles_past_the_xla_paths_block_limit(one_chip):
