@@ -182,8 +182,7 @@ def run(remat: bool = True, telemetry=None, profiler=None, *,
             ledger_from_compiled
         compiled = runtime._round.lower(
             runtime.init_state(), ids, batch, mask,
-            jnp.asarray(0.1, jnp.float32), runtime.cs,
-            runtime._gid).compile()
+            jnp.asarray(0.1, jnp.float32), runtime.cs).compile()
         nbytes = compiled.cost_analysis().get("bytes accessed")
         mledger = ledger_from_compiled(compiled)
     from commefficient_tpu.telemetry.utilization import roofline_fields

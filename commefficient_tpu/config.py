@@ -388,9 +388,9 @@ class FedConfig:
     # --signals_exact. Emitted as schema-v10 `layer_signals` events at
     # the signals cadence; "off" compiles the group machinery out
     # entirely (round HLO byte-identical, tested). Gated exactly like
-    # signals: --no_signals / --no_telemetry / async drop it too. Cost: one (d_pad,) int32 group-id map resident on
-    # device (sharded on a mesh — the same O(d) class as the byte
-    # accounting's coord_last_update) plus a few segment reductions.
+    # signals: --no_signals / --no_telemetry / async drop it too. Cost:
+    # a few reductions over the spec's static ranges (ops/segments.py);
+    # nothing d-long is resident for it.
     signal_groups: str = "coarse"
     # fail (instead of warn) on configurations round 5 MEASURED divergent
     # — see core/server.py check_regime_health: local_topk with local

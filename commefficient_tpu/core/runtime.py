@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from commefficient_tpu.config import FedConfig
 from commefficient_tpu.core import client as client_lib
-from commefficient_tpu.core.server import (robust_aggregate,
+from commefficient_tpu.core.server import (Support, robust_aggregate,
                                            server_update,
                                            sharded_sketch_server_update,
                                            validate_defense_combo,
@@ -76,6 +76,8 @@ class _ServerHalf(NamedTuple):
     #                                 Vvelocity, Verror, coord_last_update
     update: jax.Array               # as the rule gave it, before padding:
     #                                 what the signals measure
+    support: Optional[Support]      # the same update as its k (indices,
+    #                                 values), where the rule selected them
     applied: jax.Array              # the (d_pad,) update the weights took
     sup_mask: Optional[jax.Array]
     bad: jax.Array                  # update or aggregate non-finite
@@ -608,16 +610,17 @@ class FedRuntime:
             self._signals_dense_cap = False
         # ---- layer-wise compression attribution (telemetry/
         # layer_signals.py): named parameter groups over the ravel-order
-        # coordinate line, reduced per group inside the jitted round
-        # (ops/segments.py scatter-adds keyed by a precomputed int32
-        # group-id map). Gated exactly like the scalar signals — off,
-        # the group machinery is compiled out entirely (HLO identity-
-        # tested); on, the gid map rides as a CALL-TIME jit argument
-        # (like cs: a (d_pad,) int32 constant baked into the HLO would
-        # ship ~d*4 bytes to the compiler at GPT-2 scale), sharded like
-        # the dense federated vectors so each device reduces its own
-        # coordinate shard and ONE small (G,) psum recombines — never a
-        # per-group collective unroll (dryrun-ledger-gated).
+        # coordinate line, reduced per group inside the jitted round from
+        # the group spec's static ranges (ops/segments.py: the update's k
+        # winners by index where the server rule names them, a dense
+        # operand in one pass of 1,024-wide blocks). Gated exactly like
+        # the scalar signals — off, the group machinery is compiled out
+        # entirely (HLO identity-tested); on, it adds NO argument and no
+        # d-long integer to the round (round_step's d-long arguments are
+        # ps_weights and coord_last_update); on a mesh each device
+        # reduces its own coordinate shard of a dense operand and ONE
+        # small psum recombines — never a per-group collective unroll
+        # (dryrun-ledger-gated).
         self._layer_signals = (self._signals
                                and cfg.signal_groups != "off")
         # the per-group DENSE gradient mass needs a dense aggregated
@@ -633,19 +636,12 @@ class FedRuntime:
                                       or self._dense_preimage
                                       or self._signals_dense_cap))
         self.group_spec = None
-        self._gid = None
         if self._layer_signals:
             from commefficient_tpu.telemetry.layer_signals import \
                 make_group_spec
             self.group_spec = make_group_spec(params, cfg.signal_groups)
             assert self.group_spec.d == cfg.grad_size, (
                 self.group_spec.d, cfg.grad_size)
-            gid_np = self.group_spec.gid(self.d_pad)
-            if mesh is not None:
-                self._gid = jax.device_put(jnp.asarray(gid_np),
-                                           self.shardings.dense_vec)
-            else:
-                self._gid = jnp.asarray(gid_np)
         if cfg.mode == "fedavg":
             self._client_fn = client_lib.make_fedavg_client(
                 cfg, loss_fn_train, unravel, self.batch_size,
@@ -681,12 +677,9 @@ class FedRuntime:
                            in_shardings=in_shardings,
                            out_shardings=out_shardings)
 
-        # gid (last): inferred from the argument's committed layout
-        # (device_put dense_vec above) — a pinned entry would reject the
-        # lowerings that omit it (see _round_step's constant fallback)
         self._round = jit_step(
             self._round_step,
-            (state_sh, clients_sh, batch_sh, clients_sh, None, cs_sh, None),
+            (state_sh, clients_sh, batch_sh, clients_sh, None, cs_sh),
             (state_sh, None))
         if self.mesh is not None:
             # mesh-parallel validation: val items are independent, so the
@@ -1007,7 +1000,7 @@ class FedRuntime:
                              lr: jax.Array, server_rng: jax.Array, cs=None):
         """Mode + topology dispatch of the server update rule
         (``_server_half`` calls it). Returns ``(update, Vvel, Verr,
-        sup_mask)``; the sharded tail's update is a mesh-padded
+        sup_mask, support)``; the sharded tail's update is a mesh-padded
         (d_pad,) sharded vector, the replicated sketch decode's a
         true-d one (the caller's padding block handles both)."""
         cfg = self.cfg
@@ -1022,9 +1015,9 @@ class FedRuntime:
             # multiplier 1 against an identically-zero update)
             server_lr = server_lr[: cfg.grad_size]
         if self._sharded_server:
-            update, Vvel, Verr = self._sharded_server_apply(
+            update, Vvel, Verr, support = self._sharded_server_apply(
                 agg, state.Vvelocity, state.Verror, server_lr, cs)
-            return update, Vvel, Verr, None
+            return update, Vvel, Verr, None, support
         if self._server_tail_xla:
             cs = dataclasses.replace(cs, pallas="off")
         return server_update(cfg, agg, state.Vvelocity, state.Verror,
@@ -1040,7 +1033,9 @@ class FedRuntime:
         (P(None, clients) — no reshard), the update leaves as the
         dense-vector layout's (d_pad,) coordinate shards (P(clients) —
         matching ps_weights, so the weight apply runs sharded with no
-        further collective)."""
+        further collective); the k winners every device holds after
+        the merge leave replicated (``support``, None under a
+        per-parameter lr)."""
         ax = self._axis
         tab = P(None, ax)
         n_dev = self.mesh.shape[ax]
@@ -1055,7 +1050,9 @@ class FedRuntime:
                        in_specs=(tab, tab, tab,
                                  P(ax) if lr_vec else P(),
                                  jax.tree.map(lambda _: P(), cs)),
-                       out_specs=(P(ax), tab, tab), check_vma=False)
+                       out_specs=(P(ax), tab, tab,
+                                  None if lr_vec else (P(), P())),
+                       check_vma=False)
         return fn(agg, Vvel_prev, Verr_prev, server_lr, cs)
 
     def _int8_reduce_scatter(self, agg: jax.Array,
@@ -1545,8 +1542,8 @@ class FedRuntime:
         buffer."""
         cfg = self.cfg
         with phase("fed_server_tail"):
-            update, Vvel, Verr, sup_mask = self._apply_server_update(
-                state, agg, lr, server_rng, cs)
+            update, Vvel, Verr, sup_mask, support = \
+                self._apply_server_update(state, agg, lr, server_rng, cs)
             padded = update
             if self.d_pad != cfg.grad_size:
                 if update.shape[0] == cfg.grad_size:
@@ -1572,10 +1569,10 @@ class FedRuntime:
             bad = ~jnp.isfinite(padded).all() | ~jnp.isfinite(agg).all()
         fields = dict(ps_weights=ps_weights, Vvelocity=Vvel, Verror=Verr,
                       coord_last_update=coord_last_update)
-        return _ServerHalf(fields, update, padded, sup_mask, bad)
+        return _ServerHalf(fields, update, support, padded, sup_mask, bad)
 
     def _round_signals(self, state: FedState, agg, srv: _ServerHalf,
-                       sig_dense, cs, gid):
+                       sig_dense, cs):
         """The round's compression-signal health (telemetry/signals.py)
         and layer-wise attribution (telemetry/layer_signals.py): on-device
         scalars and (G,) vectors fetched asynchronously alongside the
@@ -1628,24 +1625,15 @@ class FedRuntime:
                         err_pre = (state.Verror + agg
                                    + rho * state.Vvelocity)[: cfg.grad_size]
                 layer_signals = layer_group_signals(
-                    cfg, gid=gid, n_groups=self.group_spec.n_groups,
-                    update=update, grad_dense=grad_dense,
-                    err_dense=err_dense, err_pre=err_pre)
+                    cfg, spec=self.group_spec, update=update,
+                    support=srv.support, grad_dense=grad_dense,
+                    err_dense=err_dense, err_pre=err_pre, mesh=self.mesh)
         return signals, layer_signals, sig_vel_new, sig_err_new
 
     def _round_step(self, state: FedState, client_ids: jax.Array,
-                    batch: Any, mask: jax.Array, lr: jax.Array, cs=None,
-                    gid=None):
+                    batch: Any, mask: jax.Array, lr: jax.Array, cs=None):
         """The synchronous round: both halves in one program."""
         cfg = self.cfg
-        if gid is None and self._layer_signals:
-            # lowerings that omit the group-id map (tests lower the round
-            # directly) fall back to the runtime's copy as a trace-time
-            # constant. The REAL round (self.round) always passes it as an
-            # argument — a constant would serialize d_pad*4 bytes into the
-            # HLO shipped to the compiler at GPT-2 scale, the same reason
-            # cs is an argument
-            gid = self._gid
         keys = jax.random.split(state.rng, client_ids.shape[0] + 2)
         rng, server_rng, client_rngs = keys[0], keys[1], keys[2:]
         half = self._client_half(state, client_ids, batch, mask, lr, cs,
@@ -1660,7 +1648,7 @@ class FedRuntime:
                 sig_dense = sig_dense / total
         srv = self._server_half(state, agg, lr, server_rng, cs)
         signals, layer_signals, sig_vel_new, sig_err_new = \
-            self._round_signals(state, agg, srv, sig_dense, cs, gid)
+            self._round_signals(state, agg, srv, sig_dense, cs)
 
         with phase("fed_server_tail"):
             # ---- write back per-client rows
@@ -1895,7 +1883,7 @@ class FedRuntime:
         with tracing.span("round_dispatch"):
             return self._round(state, jnp.asarray(client_ids, jnp.int32),
                                batch, jnp.asarray(mask), self._prep_lr(lr),
-                               self.cs, self._gid)
+                               self.cs)
 
     def val(self, state: FedState, batch, mask):
         """Masked evaluation on the current PS weights; returns

@@ -32,10 +32,12 @@ import jax
 import jax.numpy as jnp
 
 from commefficient_tpu.config import FedConfig
-from commefficient_tpu.ops import topk
 from commefficient_tpu.ops.topk import (local_topk_candidates,
                                         merge_topk_candidates,
                                         topk_with_idx)
+
+# a k-sparse weight update as (indices, values), see _support
+Support = Tuple[jax.Array, jax.Array]
 
 # Measured divergence envelopes (round 5). local_topk with LOCAL error
 # feedback learns only with the LR cut far below the dense-stable value:
@@ -343,20 +345,24 @@ def server_update(
     cs=None,
     dp_rng: Optional[jax.Array] = None,
     dense_preimage: bool = False,
-) -> Tuple[jax.Array, jax.Array, jax.Array, Optional[jax.Array]]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, Optional[jax.Array],
+           Optional[Support]]:
     """Dispatch to the mode's update rule (reference fed_aggregator.py:469-481).
 
     ``gradient`` is the aggregated transmitted quantity, already averaged by
     datum count (reference fed_aggregator.py:332). ``lr`` may be a scalar or a
     per-parameter vector (Fixup param groups, fed_aggregator.py:411-427).
-    Returns (weight_update, Vvelocity', Verror', support_mask_or_None).
+    Returns (weight_update, Vvelocity', Verror', support_mask_or_None,
+    support): ``support`` is the weight update once more as its k
+    ``(indices, values)``, where the rule selects k winners and one
+    scalar lr scales them (``_support``); None for a dense update.
     """
     rho = cfg.virtual_momentum
     if cfg.mode == "fedavg":
         # reference fed_aggregator.py:483-495: running average of weight
         # deltas; LR was already applied on the client, so update==Vvelocity.
         Vvel = gradient + rho * Vvelocity
-        return Vvel, Vvel, Verror, None
+        return Vvel, Vvel, Verror, None, None
 
     if cfg.mode == "uncompressed":
         # reference fed_aggregator.py:497-509
@@ -366,26 +372,28 @@ def server_update(
             noise = cfg.noise_multiplier * jax.random.normal(
                 dp_rng, grad.shape, grad.dtype)
             grad = grad + noise
-        return grad * lr, Vvel, Verror, None
+        return grad * lr, Vvel, Verror, None, None
 
     if cfg.mode == "true_topk":
         # reference fed_aggregator.py:511-542
         Vvel = gradient + rho * Vvelocity
         Verr = Verror + Vvel
-        update = topk(Verr, k=cfg.k, approx=cfg.approx_topk)
+        update, upd_idx = topk_with_idx(Verr, k=cfg.k,
+                                        approx=cfg.approx_topk)
+        support = _support(upd_idx, Verr[upd_idx], lr)
         mask = update != 0
         # error feedback + momentum factor masking at the update support
         Verr = jnp.where(mask, 0.0, Verr)
         Vvel = jnp.where(mask, 0.0, Vvel)
         if cfg.error_decay < 1.0:
             Verr = cfg.error_decay * Verr
-        return update * lr, Vvel, Verr, mask
+        return update * lr, Vvel, Verr, mask, support
 
     if cfg.mode == "local_topk":
         # reference fed_aggregator.py:544-566: momentum accumulates onto the
         # already-sparse summed worker top-k; no virtual error, no masking.
         Vvel = gradient + rho * Vvelocity
-        return Vvel * lr, Vvel, Verror, None
+        return Vvel * lr, Vvel, Verror, None, None
 
     if cfg.mode == "sketch":
         # FetchSGD core, reference fed_aggregator.py:568-613. All state lives
@@ -414,7 +422,8 @@ def server_update(
             Vvel = Vvel.at[upd_idx].set(0.0)           # momentum mask
             if cfg.error_decay < 1.0:
                 Verr = cfg.error_decay * Verr
-            return update * lr, Vvel, Verr, None
+            return (update * lr, Vvel, Verr, None,
+                    _support(upd_idx, ests[upd_idx], lr))
         Vvel = gradient + rho * Vvelocity
         Verr = Verror + Vvel  # virtual error (the only legal type, see above)
         if getattr(cs, "dense_transform", False):
@@ -436,9 +445,11 @@ def server_update(
             Vvel = Vvel - enc_vel
             if cfg.error_decay < 1.0:
                 Verr = cfg.error_decay * Verr
-            return update * lr, Vvel, Verr, None
+            return (update * lr, Vvel, Verr, None,
+                    _support(upd_idx, ests_err[upd_idx], lr))
         update, upd_idx = cs.unsketch_with_idx(
             Verr, k=cfg.k, approx=cfg.approx_topk)
+        support = _support(upd_idx, update[upd_idx], lr)
         # re-sketch the update to find which table cells it occupies
         # (reference fed_aggregator.py:593-595) — the update is k-sparse, so
         # the sparse encode is exact at O(k·r) instead of O(d·r)
@@ -462,9 +473,20 @@ def server_update(
             Verr = jnp.where(mask, 0.0, Verr)
         if cfg.error_decay < 1.0:
             Verr = cfg.error_decay * Verr
-        return update * lr, Vvel, Verr, mask
+        return update * lr, Vvel, Verr, mask, support
 
     raise ValueError(f"unknown mode {cfg.mode}")
+
+
+def _support(idx: jax.Array, vals: jax.Array,
+             lr: jax.Array) -> Optional[Support]:
+    """The k-sparse form ``(indices, values)`` of the update ``scatter(idx,
+    vals) * lr`` — what lets the layer signals reduce k winners and never
+    pass over d (telemetry/layer_signals.py). ``vals`` are the winners as
+    the top-k scattered them (the same gather of the same operand, so XLA
+    keeps one). A per-parameter lr vector (Fixup groups) leaves the dense
+    update as the only form: None."""
+    return None if jnp.ndim(lr) else (idx.astype(jnp.int32), vals * lr)
 
 
 def sharded_sketch_server_update(
@@ -478,7 +500,7 @@ def sharded_sketch_server_update(
     axis: str,
     n_shards: int,
     d_pad: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, Optional[Support]]:
     """The sketch-mode server tail, SHARDED — traced inside a
     ``shard_map`` over ``axis`` (core/runtime.py wraps it; the
     replicated twin is ``server_update``'s table branch, and the
@@ -522,7 +544,8 @@ def sharded_sketch_server_update(
 
     ``lr`` is a replicated scalar or the device's (d_pad/n,) shard of
     the per-parameter LR vector. Returns ``(update_shard, Vvel_shard',
-    Verr_shard')``.
+    Verr_shard', support)``: ``support`` is ``server_update``'s, the k
+    global winners as every shard holds them after the merge.
     """
     from jax import lax
 
@@ -575,4 +598,4 @@ def sharded_sketch_server_update(
         Verr = jnp.where(mask, 0.0, Verr)
     if cfg.error_decay < 1.0:
         Verr = cfg.error_decay * Verr
-    return update * lr, Vvel, Verr
+    return update * lr, Vvel, Verr, _support(win_idx, win_vals, lr)

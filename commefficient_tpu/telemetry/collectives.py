@@ -189,6 +189,5 @@ def round_ledger(runtime, state, client_ids, batch, mask, lr=0.1):
     import jax.numpy as jnp
     lowered = runtime._round.lower(
         state, client_ids, batch, mask,
-        jnp.asarray(lr, jnp.float32), runtime.cs,
-        getattr(runtime, "_gid", None))
+        jnp.asarray(lr, jnp.float32), runtime.cs)
     return ledger_from_compiled(lowered.compile())

@@ -11,11 +11,12 @@ embeddings) never win coordinates, and their signal rots in the error
 accumulator. This module measures exactly that: the model pytree is
 partitioned into named groups mapped to ravel-order index ranges (the
 same leaf order ``jax.flatten_util`` and the PR-9 ``encode_grad_tree``
-leaf-range stream walk), and the round reduces its dense quantities
-per group (ops/segments.py masked reductions keyed by a precomputed int32
-group-id map — on a mesh each device reduces its coordinate shard and
-ONE small (G,) psum recombines; the collective ledger gates against a
-per-group unroll):
+leaf-range stream walk), and the round reduces its quantities per
+group from those static ranges (ops/segments.py: the k winners of a
+k-sparse update by their indices, a dense operand in one pass of
+1,024-wide blocks — no d-long group map exists anywhere; on a mesh each
+device reduces its coordinate shard and ONE small psum recombines; the
+collective ledger gates against a per-group unroll):
 
 - ``grad_mass``   : per-group squared-L2 of the dense aggregated
                     gradient, where one exists in the round (dense
@@ -110,18 +111,6 @@ class GroupSpec:
     @property
     def n_groups(self) -> int:
         return len(self.names)
-
-    def gid(self, d_pad: Optional[int] = None):
-        """The (d_pad,) int32 group-id map the in-jit reductions key
-        off. Coordinates >= d (mesh padding) map to ``n_groups``,
-        which matches no group (ops/segments.py): padding lands in no
-        group."""
-        import numpy as np
-        d_pad = self.d if d_pad is None else int(d_pad)
-        gid = np.full((d_pad,), self.n_groups, np.int32)
-        for start, end, g in self.ranges:
-            gid[start:end] = g
-        return gid
 
 
 def _coarse_name(comps: List[str], ndim: int) -> str:
@@ -222,54 +211,82 @@ def make_group_spec(params: Any, mode: str = "coarse") -> GroupSpec:
                      ranges=tuple(ranges), d=off)
 
 
-def layer_group_signals(cfg, *, gid, n_groups: int, update,
-                        grad_dense=None, err_dense=None, err_pre=None
-                        ) -> Dict[str, Any]:
+def layer_group_signals(cfg, *, spec: GroupSpec, update, support=None,
+                        grad_dense=None, err_dense=None, err_pre=None,
+                        mesh=None) -> Dict[str, Any]:
     """Compute the round's per-group signal dict (traced inside the
     round step). ``update`` is the applied weight update exactly as the
     runtime holds it pre-padding (true-d, or the mesh-padded sharded
-    vector — gid maps padding out of every group, so either length is
-    sound); ``grad_dense``/``err_dense`` are the dense aggregated
+    vector — padding lies in no range of ``spec``, so either length is
+    sound); ``support`` is the same update as ``(indices, values)``
+    where the server rule selected k winners (None for a dense
+    update); ``grad_dense``/``err_dense`` are the dense aggregated
     gradient / NEW dense EF accumulator where the round holds one (None
     -> the field is emitted null, never fake zero); ``err_pre`` is the
     dense pre-feedback error for the ``--signals_exact`` heavy-hitter
     attribution (same reference round_signals' topk_overlap uses).
-    Returns {key: (G,) f32 array or None}."""
+    Dense operands as long as the mesh-padded vector reduce shard by
+    shard over ``mesh``. Returns {key: (G,) f32 array or None}."""
     import jax
     import jax.numpy as jnp
 
-    from commefficient_tpu.ops.segments import group_sum_at, group_sum_cols
+    from commefficient_tpu.ops.segments import (group_sums_at,
+                                                group_sums_dense, nonzero,
+                                                square)
 
-    # one segment reduction per live dense source into (G, C) buckets
-    # (ops/segments.py: masked reductions, no scatter); on a mesh the C
-    # small (G,) psums combine into one launch — adding a source must
-    # never add a collective launch (the per-group-unroll regression
-    # class the dryrun ledger gates). All live sources share the
-    # update's length by construction (the runtime passes round
-    # quantities of one topology — asserted, not assumed).
-    cols = [("update_mass", update.astype(jnp.float32) ** 2),
-            ("topk_count", (update != 0).astype(jnp.float32))]
+    n_groups = spec.n_groups
+    out: Dict[str, Any] = {"grad_mass": None, "error_mass": None}
+    # the update's two columns come from its k winners where the rule
+    # names them (sketch, true top-k under a scalar lr: no pass over d)
+    # and from the dense vector otherwise
+    dense = []
+    if support is not None:
+        idx, vals = support
+        sums = group_sums_at(idx, [square(vals), nonzero(vals)],
+                             spec.ranges, n_groups)
+        out["update_mass"], out["topk_count"] = sums[:, 0], sums[:, 1]
+    else:
+        dense.append((("update_mass", "topk_count"), update,
+                      (square, nonzero)))
     if grad_dense is not None:
-        assert grad_dense.shape == update.shape, (grad_dense.shape,
-                                                  update.shape)
-        cols.append(("grad_mass", grad_dense.astype(jnp.float32) ** 2))
+        dense.append((("grad_mass",), grad_dense, (square,)))
     if err_dense is not None:
-        assert err_dense.shape == update.shape, (err_dense.shape,
-                                                 update.shape)
-        cols.append(("error_mass", err_dense.astype(jnp.float32) ** 2))
-    buckets = group_sum_cols([c for _, c in cols], gid, n_groups)
-    out: Dict[str, Any] = {name: buckets[:, j]
-                           for j, (name, _) in enumerate(cols)}
-    out.setdefault("grad_mass", None)
-    out.setdefault("error_mass", None)
+        dense.append((("error_mass",), err_dense, (square,)))
+    if dense:
+        # every live dense source in ONE reduction (ops/segments.py: one
+        # read of each, no scatter), so that on a mesh adding a source
+        # never adds a collective launch (the per-group-unroll
+        # regression class the dryrun ledger gates): each chip reduces
+        # its own coordinate shard and one small psum recombines
+        fns = [f for _, _, f in dense]
+        xs = [x for _, x, _ in dense]
+        if mesh is not None and xs[0].shape[0] % mesh.size == 0:
+            from jax.sharding import PartitionSpec as P
+            axes = tuple(mesh.axis_names)
+
+            def shard_sums(*shards):
+                start = jax.lax.axis_index(axes) * shards[0].shape[0]
+                return jax.lax.psum(group_sums_dense(
+                    list(zip(shards, fns)), spec.ranges, n_groups,
+                    offset=start), axes)
+
+            sums = jax.shard_map(shard_sums, mesh=mesh,
+                                 in_specs=(P(axes),) * len(xs),
+                                 out_specs=P(), check_vma=False)(*xs)
+        else:
+            sums = group_sums_dense(list(zip(xs, fns)), spec.ranges,
+                                    n_groups)
+        names = [n for keys, _, _ in dense for n in keys]
+        out.update({n: sums[:, j] for j, n in enumerate(names)})
     if err_pre is not None:
         # exact top-k winners of the dense pre-feedback error,
         # attributed to their owning groups: win = winners per group,
         # rec = winners the update's support actually recovered
         _, idx = jax.lax.top_k(err_pre * err_pre, cfg.k)
-        win = group_sum_at(jnp.ones(idx.shape, jnp.float32), idx,
-                           gid, n_groups)
-        rec = group_sum_at(update[idx] != 0, idx, gid, n_groups)
+        sums = group_sums_at(idx, [jnp.ones(idx.shape, jnp.float32),
+                                   nonzero(update[idx])],
+                             spec.ranges, n_groups)
+        win, rec = sums[:, 0], sums[:, 1]
         out["hh_overlap"] = jnp.where(win > 0, rec / jnp.maximum(win, 1.0),
                                       jnp.nan)
     else:

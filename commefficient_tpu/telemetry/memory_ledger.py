@@ -107,8 +107,7 @@ def round_memory_ledger(runtime, state, client_ids, batch, mask,
     import jax.numpy as jnp
     lowered = runtime._round.lower(
         state, client_ids, batch, mask,
-        jnp.asarray(lr, jnp.float32), runtime.cs,
-        getattr(runtime, "_gid", None))
+        jnp.asarray(lr, jnp.float32), runtime.cs)
     return ledger_from_compiled(lowered.compile())
 
 

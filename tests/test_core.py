@@ -246,9 +246,9 @@ class TestSketchEFVariants:
         cfg_s = cfg_z.replace(sketch_ef="subtract")
         table = cs.encode(g)
         zeros = cs.empty_table()
-        _, _, verr_z, _ = server_update(cfg_z, table, zeros, zeros,
+        _, _, verr_z, _, _ = server_update(cfg_z, table, zeros, zeros,
                                         jnp.asarray(1.0), cs=cs)
-        _, _, verr_s, _ = server_update(cfg_s, table, zeros, zeros,
+        _, _, verr_s, _, _ = server_update(cfg_s, table, zeros, zeros,
                                         jnp.asarray(1.0), cs=cs)
         # zero rule wipes r cells entirely; subtract keeps the colliding
         # coordinates' mass: the surviving table mass must be strictly
@@ -266,8 +266,9 @@ class TestSketchEFVariants:
         cfg2 = cfg1.replace(error_decay=0.5)
         g = jnp.asarray(np.arange(1.0, d + 1, dtype=np.float32))
         zeros = jnp.zeros((d,), jnp.float32)
-        u1, v1, e1, _ = server_update(cfg1, g, zeros, zeros, jnp.asarray(1.0))
-        u2, v2, e2, _ = server_update(cfg2, g, zeros, zeros, jnp.asarray(1.0))
+        lr = jnp.asarray(1.0)
+        u1, v1, e1, _, _ = server_update(cfg1, g, zeros, zeros, lr)
+        u2, v2, e2, _, _ = server_update(cfg2, g, zeros, zeros, lr)
         np.testing.assert_allclose(np.asarray(u1), np.asarray(u2))
         np.testing.assert_allclose(np.asarray(e2), 0.5 * np.asarray(e1))
 

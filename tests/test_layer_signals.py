@@ -71,10 +71,30 @@ def fetch(metrics):
 # --------------------------------------------------- partition vs numpy
 
 
+def gid_reference(ranges, n_groups, d_pad):
+    """The numpy reference the range reductions are held to: the d-long
+    group-id map they replaced (PR 32). A coordinate in no range (mesh
+    padding, a gap) carries ``n_groups``, which matches no group."""
+    gid = np.full((d_pad,), n_groups, np.int32)
+    for start, end, g in ranges:
+        gid[start:end] = g
+    return gid
+
+
+def group_sums_reference(cols, gid, n_groups):
+    """Per-group float64 sums of numpy columns, one coordinate at a
+    time."""
+    out = np.zeros((n_groups, len(cols)))
+    for i, g in enumerate(gid):
+        if g < n_groups:
+            out[g] += [c[i] for c in cols]
+    return out
+
+
 def test_group_spec_tiles_ravel_order_exactly():
     """Ranges tile [0, d) with no gap/overlap, sizes sum to d, every
     boundary coordinate between adjacent leaf ranges lands in exactly
-    one group, and the gid map agrees with a numpy re-derivation from
+    one group, and the ranges agree with a numpy re-derivation from
     the ravel layout."""
     params = make_params()
     spec = make_group_spec(params, "coarse")
@@ -85,7 +105,7 @@ def test_group_spec_tiles_ravel_order_exactly():
         covered[start:end] += 1
     assert (covered == 1).all()          # exactly-one-group tiling
     # ravel order is tree_leaves order: 'b' (3 coords) then 'w' (18)
-    gid = spec.gid()
+    gid = gid_reference(spec.ranges, spec.n_groups, D)
     names = [spec.names[g] for g in gid]
     assert names[:D_OUT] == ["b/norm-bias"] * D_OUT
     assert names[D_OUT:] == ["w"] * (D_IN * D_OUT)
@@ -94,50 +114,105 @@ def test_group_spec_tiles_ravel_order_exactly():
     assert gid[D_OUT - 1] != gid[D_OUT]
 
 
-def test_gid_padding_lands_in_no_group():
-    """Mesh d_pad coordinates map to n_groups, which matches no group:
-    padded mass never leaks into a real group."""
-    from commefficient_tpu.ops.segments import group_sq_mass
+def test_padding_lands_in_no_group():
+    """Mesh d_pad coordinates lie in no range, so they match no group:
+    padded mass never leaks into a real group, through the dense
+    reduction or through a winner index past d."""
+    from commefficient_tpu.ops.segments import (group_sums_at,
+                                                group_sums_dense, square)
     spec = make_group_spec(make_params(), "coarse")
     d_pad = D + 11
-    gid = spec.gid(d_pad)
-    assert (gid[D:] == spec.n_groups).all()
     x = jnp.ones((d_pad,), jnp.float32) * 2.0   # padding coords NONZERO
-    masses = np.asarray(group_sq_mass(x, jnp.asarray(gid), spec.n_groups))
+    masses = np.asarray(group_sums_dense([(x, (square,))], spec.ranges,
+                                         spec.n_groups))[:, 0]
     np.testing.assert_allclose(masses.sum(), 4.0 * D, rtol=1e-6)
     np.testing.assert_allclose(masses, [4.0 * s for s in spec.sizes],
                                rtol=1e-6)
+    at = np.asarray(group_sums_at(jnp.arange(d_pad), [square(x)],
+                                  spec.ranges, spec.n_groups))[:, 0]
+    np.testing.assert_allclose(at, masses, rtol=1e-6)
 
 
-def test_segment_reductions_match_numpy_reference():
+# ranges of the segment-reduction cases: (d, n_groups, ranges). Gaps
+# between ranges and coordinates past the last one belong to no group.
+RANGE_CASES = {
+    # shorter than one 1,024-block: the tail path alone
+    "tail_only": (97, 5, ((0, 10, 0), (10, 11, 3), (11, 60, 1),
+                          (60, 61, 0), (70, 97, 4))),
+    # one group owns interleaved ranges (norm leaves between kernels),
+    # cuts on and off the 1,024 grid, neighbours of one group to merge
+    "interleaved": (5000, 3, ((0, 1024, 0), (1024, 1030, 2),
+                              (1030, 2048, 1), (2048, 2050, 2),
+                              (2050, 3000, 0), (3000, 3500, 0),
+                              (3500, 4999, 1), (4999, 5000, 2))),
+    # ranges that start and end inside one 1,024-block, two of them in
+    # the same block, and a block cut three times
+    "inside_one_block": (4096, 4, ((0, 1100, 0), (1100, 1200, 1),
+                                   (1200, 1210, 2), (1210, 1900, 3),
+                                   (1900, 4096, 0))),
+    # group 1 owns nothing; group 2 a single coordinate
+    "empty_group": (3000, 3, ((0, 2047, 0), (2047, 2048, 2),
+                              (2048, 3000, 0))),
+    # whole blocks only (no tail), every cut on the grid
+    "aligned": (4096, 2, ((0, 2048, 0), (2048, 4096, 1))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RANGE_CASES))
+def test_segment_reductions_match_numpy_reference(case):
+    """The dense range reduction and the k-sparse one against the
+    coordinate-by-coordinate numpy reference, on the same operand."""
+    from commefficient_tpu.ops.segments import (group_sums_at,
+                                                group_sums_dense, nonzero,
+                                                square)
+    d, G, ranges = RANGE_CASES[case]
     rng = np.random.RandomState(3)
-    d, G = 97, 5
-    gid_np = rng.randint(0, G + 1, size=d).astype(np.int32)  # incl. drop
     x_np = rng.randn(d).astype(np.float32)
-    from commefficient_tpu.ops.segments import (group_count, group_sq_mass,
-                                                group_sum_at, group_sum_cols)
-    gid, x = jnp.asarray(gid_np), jnp.asarray(x_np)
-    ref_sq = np.zeros(G)
-    ref_ct = np.zeros(G)
-    for i in range(d):
-        if gid_np[i] < G:
-            ref_sq[gid_np[i]] += x_np[i] ** 2
-            ref_ct[gid_np[i]] += float(x_np[i] != 0)
-    np.testing.assert_allclose(np.asarray(group_sq_mass(x, gid, G)),
-                               ref_sq, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(group_count(x != 0, gid, G)),
-                               ref_ct, rtol=1e-6)
-    cols = [x * x, (x != 0).astype(jnp.float32)]
-    got = np.asarray(group_sum_cols(cols, gid, G))
-    np.testing.assert_allclose(got[:, 0], ref_sq, rtol=1e-5)
-    np.testing.assert_allclose(got[:, 1], ref_ct, rtol=1e-6)
-    idx = jnp.asarray([0, 5, 5, 96], jnp.int32)
-    ref_at = np.zeros(G)
-    for j in idx:
-        if gid_np[int(j)] < G:
-            ref_at[gid_np[int(j)]] += 1.0
-    np.testing.assert_allclose(
-        np.asarray(group_sum_at(jnp.ones(4), idx, gid, G)), ref_at)
+    x_np[rng.rand(d) < 0.3] = 0.0
+    y_np = rng.randn(d).astype(np.float32)
+    gid = gid_reference(ranges, G, d)
+    ref = group_sums_reference(
+        [x_np.astype(np.float64) ** 2, x_np != 0,
+         y_np.astype(np.float64) ** 2], gid, G)
+    x, y = jnp.asarray(x_np), jnp.asarray(y_np)
+    got = np.asarray(group_sums_dense(
+        [(x, (square, nonzero)), (y, (square,))], ranges, G))
+    np.testing.assert_allclose(got[:, 0], ref[:, 0], rtol=1e-5)
+    np.testing.assert_array_equal(got[:, 1], ref[:, 1])
+    np.testing.assert_allclose(got[:, 2], ref[:, 2], rtol=1e-5)
+    # the k-sparse path on the same update: its support, shuffled
+    idx = rng.permutation(np.flatnonzero(x_np)).astype(np.int32)
+    vals = jnp.asarray(x_np[idx])
+    at = np.asarray(group_sums_at(jnp.asarray(idx),
+                                  [square(vals), nonzero(vals)], ranges, G))
+    np.testing.assert_allclose(at[:, 0], ref[:, 0], rtol=1e-5)
+    np.testing.assert_array_equal(at[:, 1], ref[:, 1])
+    # repeated indices each count (the heavy-hitter winner counts)
+    rep = jnp.asarray([0, 5, 5, d - 1], jnp.int32)
+    ref_at = group_sums_reference([np.ones(4)], gid[np.asarray(rep)], G)
+    np.testing.assert_array_equal(
+        np.asarray(group_sums_at(rep, [jnp.ones(4)], ranges, G)), ref_at)
+
+
+@pytest.mark.parametrize("case", sorted(RANGE_CASES))
+def test_dense_reduction_by_shards_matches_whole(case):
+    """A mesh chip reduces its own coordinate shard from its own
+    (traced) offset: shards of a length off the 1,024 grid, padding
+    past d in the last, sum to the whole vector's group sums."""
+    from commefficient_tpu.ops.segments import group_sums_dense, square
+    d, G, ranges = RANGE_CASES[case]
+    n = 3
+    d_pad = -(-d // n) * n + n * 7
+    x_np = np.random.RandomState(5).randn(d_pad).astype(np.float32)
+    ref = group_sums_reference([x_np.astype(np.float64) ** 2],
+                               gid_reference(ranges, G, d_pad), G)[:, 0]
+    shard = d_pad // n
+    by_shard = jax.jit(lambda xs, off: group_sums_dense(
+        [(xs, (square,))], ranges, G, offset=off))
+    got = sum(np.asarray(by_shard(jnp.asarray(
+        x_np[i * shard:(i + 1) * shard]), jnp.asarray(i * shard)))[:, 0]
+        for i in range(n))
+    np.testing.assert_allclose(got, ref, rtol=1e-5)
 
 
 def test_gpt2_scanned_blocks_split_per_block():
@@ -246,6 +321,39 @@ def test_mesh_sketch_reports_null_grad_mass_counts_live(devices):
         sig["update_norm"] ** 2, rel=1e-4)
 
 
+@pytest.mark.parametrize("mode", ["uncompressed", "true_topk"])
+def test_mesh_dense_operands_reduce_by_shard(devices, mode):
+    """Dense operands on a mesh (the dense gradient and error of every
+    dense mode, the uncompressed update): each chip reduces its own
+    coordinate shard of the mesh-padded vector from its own offset and
+    one psum recombines — the same groups the one-device round reports,
+    padding (d = 21 over 8 chips) in none."""
+    from commefficient_tpu.parallel import make_mesh
+    mesh = make_mesh((8,), ("clients",), devices=devices)
+    kw = dict(mode=mode, error_type="virtual" if mode == "true_topk"
+              else "none", num_workers=8, num_clients=16)
+    rng = np.random.RandomState(1)
+    batch = {"x": jnp.asarray(rng.randn(8, B, D_IN), jnp.float32),
+             "y": jnp.asarray(rng.randn(8, B, D_OUT), jnp.float32)}
+    mask, ids = jnp.ones((8, B), bool), jnp.arange(8, dtype=jnp.int32)
+    got = {}
+    for name, m in (("one", None), ("mesh", mesh)):
+        cfg = make_runtime(**kw).cfg
+        rt = FedRuntime(cfg, make_params(), loss_fn, num_clients=16, mesh=m)
+        state = rt.init_state()
+        for _ in range(2):
+            state, metrics = rt.round(state, ids, batch, mask, 0.05)
+        got[name] = fetch(metrics), signals_to_host(metrics["signals"])
+    assert rt.d_pad > D
+    (one, _), (ls, sig) = got["one"], got["mesh"]
+    for key in ("grad_mass", "update_mass", "error_mass"):
+        np.testing.assert_allclose(ls[key], one[key], rtol=1e-4)
+    assert ls["topk_count"] == one["topk_count"]
+    assert sum(ls["topk_count"]) == (rt.cfg.k if mode == "true_topk" else D)
+    assert sum(ls["update_mass"]) == pytest.approx(
+        sig["update_norm"] ** 2, rel=1e-4)
+
+
 @pytest.mark.slow
 def test_seq_sharded_sketch_reports_null_grad_mass_counts_live():
     """The seq-sharded half of the null contract: a ("clients","seq")
@@ -316,14 +424,14 @@ def test_groups_do_not_change_numerics():
 def test_off_and_no_telemetry_hlo_byte_identity():
     """--signal_groups off compiles the group machinery out entirely:
     byte-identical HLO to a no-signals / no-telemetry round regardless
-    of the groups setting, and the off round carries no gid argument."""
+    of the groups setting."""
     batch, mask, ids = make_batch()
 
     def hlo(**kw):
         rt = make_runtime(**kw)
         return rt._round.lower(
             rt.init_state(), ids, batch, mask,
-            jnp.asarray(0.05, jnp.float32), rt.cs, rt._gid).as_text()
+            jnp.asarray(0.05, jnp.float32), rt.cs).as_text()
 
     assert hlo(telemetry=False, signal_groups="coarse") == \
         hlo(telemetry=False, signal_groups="off")
@@ -332,9 +440,39 @@ def test_off_and_no_telemetry_hlo_byte_identity():
     # sanity: with signals live the groups DO change the lowering
     assert hlo(signal_groups="coarse") != hlo(signal_groups="off")
     rt_off = make_runtime(signal_groups="off")
-    assert rt_off._gid is None and rt_off.group_spec is None
+    assert rt_off.group_spec is None
     _, metrics = rt_off.round(rt_off.init_state(), ids, batch, mask, 0.05)
     assert metrics["layer_signals"] is None
+
+
+@pytest.mark.parametrize("mode", ["sketch", "uncompressed"])
+def test_round_takes_no_group_map(mode):
+    """The groups add no argument to the round (PR 32: a (d_pad,) int32
+    group-id map rode along, 4 B a parameter): the only d-long integer
+    round_step takes is the byte ledger's coord_last_update, and the
+    memory ledger's argument bytes are those of the groups-off round."""
+    from commefficient_tpu.telemetry.memory_ledger import \
+        round_memory_ledger
+    batch, mask, ids = make_batch()
+    kw = dict(mode=mode, error_type="virtual" if mode == "sketch"
+              else "none")
+
+    def lowered_args(rt):
+        state = rt.init_state()
+        avals = jax.tree.leaves(rt._round.lower(
+            state, ids, batch, mask, jnp.asarray(0.05, jnp.float32),
+            rt.cs).in_avals)
+        ledger = round_memory_ledger(rt, state, ids, batch, mask, 0.05)
+        return avals, ledger["argument_bytes"]
+
+    rt = make_runtime(**kw)
+    avals, arg_bytes = lowered_args(rt)
+    d_long_ints = [a for a in avals if a.shape == (rt.d_pad,)
+                   and jnp.issubdtype(a.dtype, jnp.integer)]
+    assert len(d_long_ints) == 1, d_long_ints       # coord_last_update
+    assert rt._layer_signals and rt.group_spec.n_groups == 2
+    _, arg_bytes_off = lowered_args(make_runtime(signal_groups="off", **kw))
+    assert arg_bytes == arg_bytes_off
 
 
 # ------------------------------------------------- schema + emission

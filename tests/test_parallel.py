@@ -314,7 +314,7 @@ def test_bf16_sketch_tables():
     # native bf16 wire.)
     txt = rt16._round.lower(
         rt16.init_state(), cids, batch, mask,
-        jnp.asarray(0.1, jnp.float32), rt16.cs, rt16._gid).as_text()
+        jnp.asarray(0.1, jnp.float32), rt16.cs).as_text()
     # the sharded server tail (PR 11) reduce-SCATTERS the table over
     # columns, so the bf16 wire now pins the scattered collective: the
     # payload enters as the full bf16 table and leaves as the (r, c/8)

@@ -143,7 +143,7 @@ def test_round_and_cohort_trace_one_client_step(mode):
     args = (jnp.arange(W, dtype=jnp.int32), batch, jnp.ones((W, B), bool),
             jnp.asarray(0.05, jnp.float32))
     in_round = client_step_instructions(
-        rt._round.lower(rt.init_state(), *args, rt.cs, rt._gid))
+        rt._round.lower(rt.init_state(), *args, rt.cs))
     in_cohort = client_step_instructions(
         rt_async._cohort.lower(rt_async.init_state(), *args, rt_async.cs))
     assert len(in_round) > 20

@@ -129,8 +129,7 @@ def test_long_seq_cuts_attention_memory():
 
     def temp_bytes(rt):
         lowered = rt._round.lower(rt.init_state(), ids, batch, mask,
-                                  jnp.asarray(0.05, jnp.float32), rt.cs,
-                                  rt._gid)
+                                  jnp.asarray(0.05, jnp.float32), rt.cs)
         ma = lowered.compile().memory_analysis()
         return ma.temp_size_in_bytes
 

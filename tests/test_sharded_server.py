@@ -523,7 +523,7 @@ def test_ineligible_auto_falls_back_to_replicated_hlo():
         texts.append(rt._round.lower(
             st, jnp.arange(8, dtype=jnp.int32), batch_for(8, 4, 1),
             jnp.ones((8, 4), bool), jnp.asarray(0.1, jnp.float32),
-            rt.cs, rt._gid).as_text())
+            rt.cs).as_text())
     assert texts[0] == texts[1]
 
 

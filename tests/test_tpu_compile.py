@@ -214,7 +214,7 @@ def test_sharded_server_tail_decodes_with_the_kernel(topo, monkeypatch):
     fn = jax.jit(shard_map(
         blk, mesh=mesh,
         in_specs=(tab, tab, tab, P(), jax.tree.map(lambda _: P(), cs)),
-        out_specs=(P("clients"), tab, tab), check_vma=False))
+        out_specs=(P("clients"), tab, tab, (P(), P())), check_vma=False))
 
     def sds(shape, dtype, spec):
         return jax.ShapeDtypeStruct(shape, dtype,
@@ -232,3 +232,51 @@ def test_sharded_server_tail_decodes_with_the_kernel(topo, monkeypatch):
     spans = [line for line in hlo.splitlines()
              if " gather(" in line and f"f32[{c}]" in line]
     assert not spans, spans[:2]
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_chip", "four_chips"])
+def test_group_sums_dense_is_one_read_and_no_loop(topo, sharded):
+    """The layer signals' dense reduction (ops/segments.py) at
+    ResNet-50's d and group count, whole on one chip and by coordinate
+    shard under ``shard_map`` over the four: no temporary near the operand (the
+    (nb, 8, 128) view is a bitcast that fuses into the block reduce; an
+    (nb, 1024) view, or a second reader of it, made a d-long copy), no
+    loop (the cut blocks as one gather ran four small operations a
+    row), and across chips one all-reduce and nothing else."""
+    from commefficient_tpu.telemetry.layer_signals import (
+        GroupSpec, layer_group_signals)
+    d, n_ranges, n_groups = 25504026, 160, 37
+    cuts = np.unique(np.random.RandomState(0).randint(1, d, n_ranges - 1))
+    bounds = [0, *cuts.tolist(), d]
+    ranges = tuple((a, b, i % n_groups)
+                   for i, (a, b) in enumerate(zip(bounds, bounds[1:])))
+    sizes = [0] * n_groups
+    for a, b, g in ranges:
+        sizes[g] += b - a
+    spec = GroupSpec(names=tuple(map(str, range(n_groups))),
+                     sizes=tuple(sizes), ranges=ranges, d=d)
+    if sharded:
+        mesh = Mesh(np.array(topo.devices), ("clients",))
+        length = -(-d // 4) * 4
+        sharding = NamedSharding(mesh, P("clients"))
+    else:
+        mesh, length = None, d
+        sharding = SingleDeviceSharding(topo.devices[0])
+
+    def signals(update, grad):
+        out = layer_group_signals(None, spec=spec, update=update,
+                                  grad_dense=grad, mesh=mesh)
+        return out["update_mass"], out["topk_count"], out["grad_mass"]
+
+    x = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=sharding)
+    compiled = jax.jit(signals).lower(x, x).compile()
+    # under half of one f32 copy of what the chip holds of an operand
+    held = length // (4 if sharded else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * held
+    hlo = compiled.as_text()
+    assert not re.search(r" while\(", hlo)
+    collectives = re.findall(
+        r" (all-reduce|all-gather|all-to-all|collective-permute)"
+        r"(?:-start)?\(", hlo)
+    assert collectives == (["all-reduce"] if sharded else [])
