@@ -181,6 +181,9 @@ def dense_grouped_attention(q, k, v, window=None):
 
 
 GROUPED_ATTN_BLOCK = 512
+# the ``checkpoint_name`` of what the blocked kernel's backward rule reads
+# beside q, k and v: its output and logsumexp
+GROUPED_ATTN_RESIDUAL = "grouped_attn_residual"
 
 
 def splash_grouped_attention(q, k, v, window=None):
@@ -189,8 +192,12 @@ def splash_grouped_attention(q, k, v, window=None):
     form mapped over the KV heads): no (H, S, S) scores in HBM, and the
     blocks the mask removes entirely (above the diagonal; on a window
     layer also below the band) are never visited, forward or backward.
-    Off the TPU, or where S is not a multiple of the block, the plain
-    path."""
+    The kernel's output and logsumexp carry the name
+    ``GROUPED_ATTN_RESIDUAL``, so a ``jax.checkpoint`` around the caller
+    whose policy saves that name (``models/laguna.LagunaLM``) does not run
+    the forward kernel a second time; outside a checkpoint the name is the
+    identity. Off the TPU, or where S is not a multiple of the block, the
+    plain path, which names nothing."""
     S, H, D = q.shape[-3:]
     KV = k.shape[-2]
     blk = GROUPED_ATTN_BLOCK
@@ -205,7 +212,8 @@ def splash_grouped_attention(q, k, v, window=None):
         block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
         block_kv_dq=blk)
     kernel = sk.make_splash_mqa_single_device(
-        sm.MultiHeadMask([one] * (H // KV)), block_sizes=sizes)
+        sm.MultiHeadMask([one] * (H // KV)), block_sizes=sizes,
+        residual_checkpoint_name=GROUPED_ATTN_RESIDUAL)
     qb = (q * (1.0 / math.sqrt(D))).astype(q.dtype).reshape(
         (-1, S, KV, H // KV, D)).transpose(0, 2, 3, 1, 4)
     kb = k.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from commefficient_tpu.models.gpt2 import auto_grouped_attention
+from commefficient_tpu.models.gpt2 import (GROUPED_ATTN_RESIDUAL,
+                                           auto_grouped_attention)
 from commefficient_tpu.telemetry.profiling import phase
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -87,6 +88,10 @@ class LagunaConfig:
     # ids [lo, hi) of the experts this chip holds in every sparse layer
     experts_held: Tuple[int, int] = (0, 256)
     compute_dtype: Any = jnp.bfloat16
+    # recompute every block in the backward pass, keeping of its interior
+    # only what carries GROUPED_ATTN_RESIDUAL: the blocked attention
+    # kernel's output and logsumexp where that kernel runs (TPU, S >= 1024,
+    # S a multiple of 512), nothing on the plain path. No flag selects it.
     remat: bool = False
 
     @property
@@ -316,7 +321,13 @@ class LagunaLM(nn.Module):
     ``valid`` (..., S) marks the positions that are tokens (None: all).
     Padding follows the tokens, attention is causal and the loss puts no
     label on padding, so what any layer computes at a padded position
-    reaches neither the loss nor a gradient: the expert layers skip them."""
+    reaches neither the loss nor a gradient: the expert layers skip them.
+    ``cfg.remat`` wraps each block in ``nn.remat`` with a policy that saves
+    ``GROUPED_ATTN_RESIDUAL`` alone: where the blocked attention kernel
+    runs, its output and logsumexp survive to the backward pass and the
+    forward kernel runs once a layer; everything else in the block (norms,
+    projections, rotary, gate, router, experts) is recomputed. On the plain
+    attention path nothing carries the name and the remat is the full one."""
 
     cfg: LagunaConfig
     attn_impl: Callable = auto_grouped_attention
@@ -330,8 +341,10 @@ class LagunaLM(nn.Module):
                           (cfg.vocab_size, cfg.hidden_size))
         positions = jnp.arange(input_ids.shape[-1])
         x = embed[input_ids].astype(cfg.compute_dtype)
-        block_cls = (nn.remat(LagunaBlock, static_argnums=())
-                     if cfg.remat else LagunaBlock)
+        block_cls = (nn.remat(
+            LagunaBlock, static_argnums=(),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                GROUPED_ATTN_RESIDUAL)) if cfg.remat else LagunaBlock)
         per_layer = []
         for i in range(cfg.num_hidden_layers):
             x, counts = block_cls(cfg, i, self.attn_impl,

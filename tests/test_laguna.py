@@ -443,3 +443,104 @@ def test_padded_positions_go_to_no_expert():
     assert np.abs(np.asarray(y[40:])).max() == 0
     np.testing.assert_array_equal(np.asarray(counts["tokens"]),
                                   np.asarray(counts_tokens["tokens"]))
+
+
+def _loss(model, ids):
+    def loss(p):
+        hidden, head, _ = model.apply(p, ids)
+        return (hidden @ head.T).mean() + (hidden ** 2).mean()
+    return loss
+
+
+def _grad(model, params, ids):
+    return jax.jit(jax.value_and_grad(_loss(model, ids)))(params)
+
+
+def _bit_equal(a, b):
+    assert float(a[0]) == float(b[0])
+    for x, y in zip(jax.tree.leaves(a[1]), jax.tree.leaves(b[1]), strict=True):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_remat_changes_nothing_on_the_plain_path():
+    """``cfg.remat`` keeps only what carries ``GROUPED_ATTN_RESIDUAL``,
+    and on the plain attention path nothing does: loss and every gradient
+    leaf are the unwrapped blocks' to the bit. (In float32: under bf16
+    the CPU compiler rounds a recomputed block's fusions differently,
+    with or without a policy.)"""
+    b = _case(5)[1]
+    ids = fam.sample_batch(b, 1, 5)["input_ids"]
+    out = [_grad(LagunaLM(dataclasses.replace(
+        b.lcfg, compute_dtype=jnp.float32, remat=remat)), b.params, ids)
+        for remat in (False, True)]
+    _bit_equal(*out)
+    assert max(float(jnp.abs(g).max())
+               for g in jax.tree.leaves(out[1][1])) > 0
+
+
+@pytest.fixture
+def blocked_kernel(monkeypatch):
+    """``splash_grouped_attention`` takes its TPU branch here, with the
+    blocked kernel in interpret mode: one full and one window layer, 2
+    query heads over 1 KV head of 128, S = 1,024 (two blocks)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(sk, "make_splash_mqa_single_device",
+                        functools.partial(sk.make_splash_mqa_single_device,
+                                          interpret=True))
+    hf = tiny(2)
+    hf.update(num_key_value_heads=1, head_dim=128, sliding_window=512,
+              num_attention_heads_per_layer=[2, 2])
+
+    lcfg = LagunaConfig.from_hf(hf, compute_dtype=jnp.float32)
+    assert lcfg.layer_types == ("full_attention", "sliding_attention")
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 1024), 0, 64)
+    params = jax.jit(LagunaLM(lcfg).init)(jax.random.PRNGKey(0), ids)
+    return lambda remat=True: (
+        LagunaLM(dataclasses.replace(lcfg, remat=remat)), params, ids)
+
+
+def _residuals(capsys, model, params, ids):
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(_loss(model, ids), params)
+    return sorted(capsys.readouterr().out.strip().splitlines())
+
+
+def test_block_remat_keeps_the_blocked_kernels_two_residuals(
+        blocked_kernel, capsys, monkeypatch):
+    """What the backward pass is handed from each remat'd block whose
+    attention is the blocked kernel: beside what full remat hands it (the
+    block's arguments and output), the kernel's output and logsumexp and
+    nothing else of the block's interior."""
+    from commefficient_tpu.models import laguna
+    kept = _residuals(capsys, *blocked_kernel())
+    monkeypatch.setattr(laguna, "GROUPED_ATTN_RESIDUAL", "carried_by_nothing")
+    full = _residuals(capsys, *blocked_kernel())
+    extra = list(kept)
+    for line in full:
+        extra.remove(line)
+    # (B, KV, G, S, D) and (B, KV, G, S), a layer
+    assert sorted(line.split()[0] for line in extra) == sorted([
+        "f32[1,1,2,1024,128]", "f32[1,1,2,1024]"] * 2), extra
+    assert all("splash_attention" in line for line in extra), extra
+    none = _residuals(capsys, *blocked_kernel(remat=False))
+    assert len(none) > len(kept) + 20
+
+
+def test_kept_residuals_give_full_remats_gradients_to_the_bit(
+        blocked_kernel, monkeypatch):
+    """The kept values are the ones the second forward would have
+    recomputed, from the same kernel on the same inputs: in float32 the
+    gradients are full remat's, and no remat's, to the bit. (Under bf16
+    on the chip they are not: the compiler rounds inside its fusions, and
+    the fusions change with what is kept; PERF.md section 6, PR 33.)"""
+    from commefficient_tpu.models import laguna
+    kept = _grad(*blocked_kernel())
+    plain = _grad(*blocked_kernel(remat=False))
+    monkeypatch.setattr(laguna, "GROUPED_ATTN_RESIDUAL", "carried_by_nothing")
+    full = _grad(*blocked_kernel())
+    _bit_equal(kept, full)
+    _bit_equal(kept, plain)
+    assert min(float(jnp.abs(g).max())
+               for g in jax.tree.leaves(kept[1])) > 0

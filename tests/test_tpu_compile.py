@@ -10,6 +10,7 @@ process at a time may load the TPU library, and under pytest-xdist only
 the worker given this file does.
 """
 
+import collections
 import dataclasses
 import re
 
@@ -232,6 +233,44 @@ def test_sharded_server_tail_decodes_with_the_kernel(topo, monkeypatch):
     spans = [line for line in hlo.splitlines()
              if " gather(" in line and f"f32[{c}]" in line]
     assert not spans, spans[:2]
+
+
+def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
+        one_chip, monkeypatch):
+    """One client's ``value_and_grad`` of the Laguna loss at the benchmark
+    cell's shape (the configuration file's five layers, 1 x 1 x 4,096,
+    bf16, ``remat=True``): the blocked attention kernel's output and
+    logsumexp survive each block's rematerialisation
+    (``GROUPED_ATTN_RESIDUAL``), so the forward kernel is compiled once a
+    layer and not twice (10 / 5 / 5 under full remat), and what is kept
+    (288 MiB of bf16 outputs) does not raise the temporaries: 643.0 MB
+    here, 644.8 under full remat."""
+    from commefficient_tpu.losses import make_laguna_loss
+    from commefficient_tpu.models.gpt2 import resolve_attn
+    from commefficient_tpu.models.laguna import LagunaConfig, LagunaLM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lcfg = LagunaConfig.from_json(
+        "perfbench/configs/laguna_xs2_share32.json",
+        compute_dtype=jnp.bfloat16, remat=True)
+    assert lcfg.num_hidden_layers == 5
+    S = 4096
+    model = LagunaLM(lcfg, attn_impl=resolve_attn("auto", grouped=True))
+    loss_fn = make_laguna_loss(model, lcfg.vocab_size - 1, lm_chunk=128)
+    ids = jax.ShapeDtypeStruct((1, 1, S), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 1, S), jnp.int32)))
+    compiled = jax.jit(jax.value_and_grad(loss_fn, has_aux=True)).lower(
+        params, {"input_ids": ids},
+        jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=one_chip)).compile()
+    calls = [line.split()[0] for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    counts = collections.Counter(
+        re.sub(r"^%|_(no_)?residuals.*", "", name) for name in calls)
+    assert counts == {"splash_mqa_fwd": 5, "splash_mqa_dq": 5,
+                      "splash_mqa_dkv": 5}, counts
+    assert compiled.memory_analysis().temp_size_in_bytes < 680e6
 
 
 @pytest.mark.parametrize("sharded", [False, True],
