@@ -40,7 +40,7 @@ from commefficient_tpu.core.server import (Support, robust_aggregate,
                                            validate_mode_combo,
                                            validate_regimes)
 from commefficient_tpu.core.state import FedState
-from commefficient_tpu.ops import ravel_params
+from commefficient_tpu.ops import make_unraveler, ravel_params
 from commefficient_tpu.ops.sketch import make_sketch_impl
 from commefficient_tpu.telemetry import tracing
 from commefficient_tpu.telemetry.clients import (CLIENT_GRAD_KEYS,
@@ -105,8 +105,8 @@ class FedRuntime:
                  num_clients: Optional[int] = None,
                  mesh=None,
                  seq_spec: Optional[Dict[str, int]] = None):
-        flat, unravel = ravel_params(params)
-        cfg = cfg.replace(grad_size=int(flat.size))
+        grad_size, unravel = make_unraveler(params)
+        cfg = cfg.replace(grad_size=grad_size)
         # a loss that reports more than (loss, one metric) says so itself
         # (losses.make_laguna_loss: the expert layers' counters)
         n_results = getattr(loss_fn_train, "num_results", None)
@@ -133,7 +133,9 @@ class FedRuntime:
         validate_regimes(cfg)
         self.cfg = cfg
         self.unravel = unravel
-        self.initial_weights = flat
+        # the caller's tree, by reference: the runtime holds nothing d-long
+        # of its own beside the states it hands out
+        self._init_params = params
         self.mesh = mesh
         # sequence/context parallelism: a mesh with a "seq" axis runs every
         # client's model seq-sharded (ring attention; see parallel/ring.py
@@ -778,26 +780,37 @@ class FedRuntime:
 
     # ------------------------------------------------------------------ state
 
+    @property
+    def initial_weights(self) -> jax.Array:
+        """The flat fp32 vector of the tree the runtime was built from,
+        ravelled anew on every read; nothing keeps it."""
+        return ravel_params(self._init_params)[0]
+
     def _state_template(self):
         """Structure-only FedState (no allocation) for sharding layout."""
-        return jax.eval_shape(self._make_state, jax.random.PRNGKey(0),
-                              self.initial_weights)
+        return jax.eval_shape(
+            self._make_state, jax.random.PRNGKey(0),
+            jax.ShapeDtypeStruct((self.d_pad,), jnp.float32))
 
     def init_state(self, seed: Optional[int] = None) -> FedState:
         # the key is made here from the Python integer: as a jit argument
         # a seed past 2**31 overflows the int32 it would be parsed into
         rng = jax.random.PRNGKey(self.cfg.seed if seed is None else seed)
+        # first, while nothing else of the state exists: the tree, the
+        # ravel's temporaries and this vector are the set-up's high-water
+        # mark, and the temporaries are gone before the next leaf is made
+        weights = ravel_params(self._init_params, pad_to=self.d_pad)[0]
         if self._state_sharding is not None:
             # create the state directly in its sharded layout — no single
             # device ever holds the full per-client arrays. The weights are
             # a jit ARGUMENT: as a closure constant they would be serialized
             # into the HLO shipped to the compiler (0.5 GB at GPT-2 scale)
             return jax.jit(self._make_state,
-                           out_shardings=self._state_sharding)(
-                               rng, self.initial_weights)
-        return self._make_state(rng, self.initial_weights)
+                           out_shardings=self._state_sharding)(rng, weights)
+        return self._make_state(rng, weights)
 
-    def _make_state(self, rng, initial_weights) -> FedState:
+    def _make_state(self, rng, weights) -> FedState:
+        """``weights``: the (d_pad,) vector made for this state alone."""
         cfg = self.cfg
         # Server-side transmitted-space state lives at the mesh-padded
         # length so it shards evenly (see __init__). Per-client dense rows
@@ -820,10 +833,9 @@ class FedRuntime:
             return jnp.zeros(shape, jnp.float32) if cond else None
 
         return FedState(
-            # copy: the round step donates its input state, and the shared
-            # self.initial_weights buffer must survive repeated init_state()
-            ps_weights=jnp.pad(jnp.asarray(initial_weights),
-                               (0, self.d_pad - d)),
+            # no copy: every init_state() ravels a vector of its own, so
+            # the round step may donate it with the rest of the state
+            ps_weights=weights,
             Vvelocity=zeros_tx,
             Verror=jnp.zeros_like(zeros_tx),
             step=jnp.zeros((), jnp.int32),
@@ -833,7 +845,7 @@ class FedRuntime:
             client_errors=maybe((n,) + client_tx, cfg.needs_client_errors),
             # every client starts with the initial PS weights
             # (reference fed_aggregator.py:105-111)
-            client_weights=(jnp.broadcast_to(initial_weights, (n, d))
+            client_weights=(jnp.broadcast_to(weights[:d], (n, d))
                             if cfg.do_topk_down else None),
             coord_last_update=(jnp.full((self.d_pad,), -1, jnp.int32)
                                if cfg.track_bytes else None),

@@ -127,7 +127,10 @@ def setup_checkpointing(cfg: FedConfig, runtime: FedRuntime, name: str):
                                               params_fingerprint)
     mgr = CheckpointManager(os.path.join(cfg.checkpoint_path, name),
                             sharded=cfg.checkpoint_sharded)
-    fp = params_fingerprint(runtime.unravel(runtime.initial_weights))
+    # the layout alone is fingerprinted: shapes, no d-long ravel
+    fp = params_fingerprint(jax.eval_shape(
+        runtime.unravel,
+        jax.ShapeDtypeStruct((cfg.grad_size,), jnp.float32)))
     # sketch state (Vvelocity/Verror tables) is only meaningful under the
     # EXACT sketch construction that encoded it: record a generation
     # marker so a resume under different shifts/signs (e.g. the r3 change
@@ -1381,8 +1384,8 @@ def load_finetune_params(cfg: FedConfig, model, params):
     backbone, so the federated vector covers only the head."""
     path = os.path.join(cfg.finetune_path, cfg.model + ".npz")
     loaded = np.load(path)["ps_weights"]
-    from commefficient_tpu.ops import ravel_params
-    _, unravel = ravel_params(params)
+    from commefficient_tpu.ops import make_unraveler
+    _, unravel = make_unraveler(params)
     full = unravel(jnp.asarray(loaded))
     head_keys = [k for k in full["params"]
                  if k in ("head", "classifier", "fc")]

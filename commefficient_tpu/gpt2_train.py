@@ -157,8 +157,8 @@ def load_pretrained(out_dir: str):
         raise ValueError(
             f"{out_dir}: saved weights were written under a different "
             f"parameter layout ({saved_fp} != {fp})")
-    from commefficient_tpu.ops import ravel_params
-    _, unravel = ravel_params(params)
+    from commefficient_tpu.ops import make_unraveler
+    _, unravel = make_unraveler(params)
     flat = np.load(os.path.join(out_dir, "weights.npz"))["ps_weights"]
     params = unravel(jnp.asarray(flat))
     hash_fn = os.path.join(out_dir, "hash_tokenizer.json")
