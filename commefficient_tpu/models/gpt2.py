@@ -181,30 +181,29 @@ def dense_grouped_attention(q, k, v, window=None):
 
 
 GROUPED_ATTN_BLOCK = 512
+# ``auto``: the blocked kernel from this sequence length up
+GROUPED_ATTN_AUTO_FROM = 1024
 # the ``checkpoint_name`` of what the blocked kernel's backward rule reads
 # beside q, k and v: its output and logsumexp
 GROUPED_ATTN_RESIDUAL = "grouped_attn_residual"
 
 
-def splash_grouped_attention(q, k, v, window=None):
-    """The same through the TPU's blocked Pallas kernel
+def blocked_grouped_kernel(S, H, KV, window=None):
+    """The TPU's blocked Pallas kernel
     (jax.experimental.pallas.ops.tpu.splash_attention, its multi-query
-    form mapped over the KV heads): no (H, S, S) scores in HBM, and the
-    blocks the mask removes entirely (above the diagonal; on a window
-    layer also below the band) are never visited, forward or backward.
-    The kernel's output and logsumexp carry the name
+    form mapped over batch and KV heads) in the layout it reads and
+    writes: q (B, KV, H / KV, S, D) already scaled by 1/sqrt(D), k and v
+    (B, KV, S, D) -> (B, KV, H / KV, S, D). No (H, S, S) scores in HBM,
+    and the blocks the mask removes entirely (above the diagonal; on a
+    window layer also below the band) are never visited, forward or
+    backward. Its output and logsumexp carry the name
     ``GROUPED_ATTN_RESIDUAL``, so a ``jax.checkpoint`` around the caller
     whose policy saves that name (``models/laguna.LagunaLM``) does not run
     the forward kernel a second time; outside a checkpoint the name is the
-    identity. Off the TPU, or where S is not a multiple of the block, the
-    plain path, which names nothing."""
-    S, H, D = q.shape[-3:]
-    KV = k.shape[-2]
-    blk = GROUPED_ATTN_BLOCK
-    if jax.default_backend() != "tpu" or S % blk:
-        return dense_grouped_attention(q, k, v, window)
+    identity."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
+    blk = GROUPED_ATTN_BLOCK
     one = (sm.CausalMask((S, S)) if window is None
            else sm.LocalMask((S, S), window_size=(window - 1, 0), offset=0))
     sizes = sk.BlockSizes(
@@ -214,20 +213,49 @@ def splash_grouped_attention(q, k, v, window=None):
     kernel = sk.make_splash_mqa_single_device(
         sm.MultiHeadMask([one] * (H // KV)), block_sizes=sizes,
         residual_checkpoint_name=GROUPED_ATTN_RESIDUAL)
+    return jax.vmap(jax.vmap(kernel))
+
+
+def _blocked_kernel_runs(S):
+    return jax.default_backend() == "tpu" and S % GROUPED_ATTN_BLOCK == 0
+
+
+def splash_grouped_attention(q, k, v, window=None):
+    """``dense_grouped_attention``'s contract through
+    ``blocked_grouped_kernel``: the scale, the reshapes and the
+    transposes between (..., S, H, D) and the kernel's (B, KV, G, S, D)
+    are XLA's, each a pass over its tensor in HBM (a caller that holds
+    q, k and the output as the projections read and write them does all
+    of it in one pass a tensor: ``models/laguna.LagunaBlock``). Off the
+    TPU, or where S is not a multiple of the block, the plain path,
+    which names nothing."""
+    S, H, D = q.shape[-3:]
+    KV = k.shape[-2]
+    if not _blocked_kernel_runs(S):
+        return dense_grouped_attention(q, k, v, window)
     qb = (q * (1.0 / math.sqrt(D))).astype(q.dtype).reshape(
         (-1, S, KV, H // KV, D)).transpose(0, 2, 3, 1, 4)
     kb = k.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
     vb = v.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
-    out = jax.vmap(jax.vmap(kernel))(qb, kb, vb)      # (B, KV, G, S, D)
+    out = blocked_grouped_kernel(S, H, KV, window)(qb, kb, vb)
     return out.transpose(0, 3, 1, 2, 4).reshape(q.shape)
 
 
 def auto_grouped_attention(q, k, v, window=None):
     """The blocked kernel from S = 1024 up (as ``auto_causal_attention``;
     at S = 4096 the plain path's scores are 3.2 GB a sequence)."""
-    if q.shape[-3] >= 1024:
+    if q.shape[-3] >= GROUPED_ATTN_AUTO_FROM:
         return splash_grouped_attention(q, k, v, window)
     return dense_grouped_attention(q, k, v, window)
+
+
+def runs_blocked_kernel(attn_impl, S):
+    """Whether ``attn_impl`` (an entry of ``GROUPED_ATTN_IMPLS``) runs
+    ``blocked_grouped_kernel`` on sequences of S here: the test
+    ``splash_grouped_attention`` and ``auto_grouped_attention`` make."""
+    if attn_impl is auto_grouped_attention:
+        return S >= GROUPED_ATTN_AUTO_FROM and _blocked_kernel_runs(S)
+    return attn_impl is splash_grouped_attention and _blocked_kernel_runs(S)
 
 
 GROUPED_ATTN_IMPLS = {"dense": dense_grouped_attention,

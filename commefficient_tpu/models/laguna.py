@@ -35,7 +35,10 @@ from flax import linen as nn
 from jax import lax
 
 from commefficient_tpu.models.gpt2 import (GROUPED_ATTN_RESIDUAL,
-                                           auto_grouped_attention)
+                                           auto_grouped_attention,
+                                           blocked_grouped_kernel,
+                                           runs_blocked_kernel)
+from commefficient_tpu.ops.rope_pallas import gate_from_heads, rope_to_heads
 from commefficient_tpu.telemetry.profiling import phase
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -169,13 +172,39 @@ def rope_tables(spec: RopeSpec, head_dim: int, positions):
 
 def apply_rope(x, cos, sin):
     """Rotate the first ``2 * cos.shape[-1]`` dimensions of x (..., S, H,
-    D) in the half-split convention (``rotate_half``); the rest pass."""
+    D) in the half-split convention (``rotate_half``); the rest pass.
+    The plain path's rotary (CPU, S < 1,024, the float32 reference): it
+    slices and concatenates the last dimension in float32, which on the
+    TPU is several float32 (S, H, D) arrays through HBM; where the
+    blocked attention kernel runs, ``rope_gated_blocked_attention`` does
+    the same arithmetic in one pass."""
     half = cos.shape[-1]
     xf = x.astype(jnp.float32)
     x1, x2, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
     c, s = cos[:, None, :], sin[:, None, :]
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_gated_blocked_attention(q, k, v, gate, cos, sin, window=None):
+    """``apply_rope`` on q and k, ``blocked_grouped_kernel`` and the gate,
+    for q (..., S, H, D), k and v (..., S, KV, D) that are views of the
+    projections' (..., S, H x D) outputs, gate (..., S, H) float32 ->
+    the gated output (..., S, H, D). Every head-shaped tensor crosses HBM
+    once a pass in its own dtype: ``ops/rope_pallas.py``'s kernels rotate,
+    scale and go head-major in one pass over q and k, and gate and go
+    back in one; v's transpose is a bfloat16 copy; the backward passes
+    are those kernels' transposes. Same values as the plain path (same
+    float32 arithmetic, same roundings)."""
+    lead, (S, H, D), KV = q.shape[:-3], q.shape[-3:], k.shape[-2]
+    flat = lambda t: t.reshape((-1, S, t.shape[-2] * D))
+    qb, kb = rope_to_heads(flat(q), flat(k), cos, sin, head_dim=D,
+                           scale=1.0 / math.sqrt(D))
+    with phase("fed_attention"):
+        vb = v.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
+        o = blocked_grouped_kernel(S, H, KV, window)(qb, kb, vb)
+    o = gate_from_heads(o, gate.reshape((-1, S, H)))
+    return o.reshape(lead + (S, H, D))
 
 
 class RMSNorm(nn.Module):
@@ -271,6 +300,18 @@ class ExpertLayer(nn.Module):
 
 
 class LagunaBlock(nn.Module):
+    """One pre-norm block: attention, then the dense or the expert layer.
+    Two paths through the attention, chosen by what the code can see and
+    by no flag. Where ``attn_impl`` runs the blocked kernel
+    (``runs_blocked_kernel``: TPU, S a multiple of 512, from 1,024 up
+    under ``auto``) and ``head_dim`` is a multiple of 128,
+    ``rope_gated_blocked_attention``: q, k and the gated output cross HBM
+    once a pass each, in ``compute_dtype``, as the projections write and
+    read them. Elsewhere (the CPU, short sequences, the float32
+    reference, small heads) the plain path: ``apply_rope``, ``attn_impl``
+    on (..., S, H, D), the gate product in float32. ``fed_attention``
+    wraps the attention proper on both: not the projections, rotary or
+    gate, nor on the blocked path q's scale."""
     cfg: LagunaConfig
     layer: int
     attn_impl: Callable = auto_grouped_attention
@@ -292,11 +333,14 @@ class LagunaBlock(nn.Module):
         gate = jax.nn.sigmoid(
             _dense(H, dt, "g_proj")(h).astype(jnp.float32))
         cos, sin = rope_tables(rope, D, positions)
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        with phase("fed_attention"):
-            o = self.attn_impl(
-                q, k, v, window=cfg.sliding_window if sliding else None)
-        o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+        window = cfg.sliding_window if sliding else None
+        if D % 128 == 0 and runs_blocked_kernel(self.attn_impl, q.shape[-3]):
+            o = rope_gated_blocked_attention(q, k, v, gate, cos, sin, window)
+        else:
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            with phase("fed_attention"):
+                o = self.attn_impl(q, k, v, window=window)
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
         x = x + _dense(cfg.hidden_size, dt, "o_proj")(
             o.reshape(o.shape[:-2] + (H * D,)))
 
@@ -327,7 +371,9 @@ class LagunaLM(nn.Module):
     runs, its output and logsumexp survive to the backward pass and the
     forward kernel runs once a layer; everything else in the block (norms,
     projections, rotary, gate, router, experts) is recomputed. On the plain
-    attention path nothing carries the name and the remat is the full one."""
+    attention path nothing carries the name and the remat is the full one.
+    Which attention path a block takes, and what then crosses HBM around
+    the kernel, is ``LagunaBlock``'s to say."""
 
     cfg: LagunaConfig
     attn_impl: Callable = auto_grouped_attention

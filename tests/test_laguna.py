@@ -480,8 +480,9 @@ def test_remat_changes_nothing_on_the_plain_path():
 
 @pytest.fixture
 def blocked_kernel(monkeypatch):
-    """``splash_grouped_attention`` takes its TPU branch here, with the
-    blocked kernel in interpret mode: one full and one window layer, 2
+    """``LagunaBlock`` takes its TPU branch here, with the blocked kernel
+    and the rotary and gate kernels around it (``ops/rope_pallas.py``)
+    in interpret mode: one full and one window layer, 2
     query heads over 1 KV head of 128, S = 1,024 (two blocks)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
@@ -489,6 +490,10 @@ def blocked_kernel(monkeypatch):
     monkeypatch.setattr(sk, "make_splash_mqa_single_device",
                         functools.partial(sk.make_splash_mqa_single_device,
                                           interpret=True))
+    from commefficient_tpu.models import laguna
+    for name in ("rope_to_heads", "gate_from_heads"):
+        monkeypatch.setattr(laguna, name, functools.partial(
+            getattr(laguna, name), interpret=True))
     hf = tiny(2)
     hf.update(num_key_value_heads=1, head_dim=128, sliding_window=512,
               num_attention_heads_per_layer=[2, 2])
@@ -544,3 +549,149 @@ def test_kept_residuals_give_full_remats_gradients_to_the_bit(
     _bit_equal(kept, plain)
     assert min(float(jnp.abs(g).max())
                for g in jax.tree.leaves(kept[1])) > 0
+
+
+def _plain_to_heads(x, cos, sin, heads, scale):
+    """``LagunaBlock``'s plain path up to the blocked kernel's operand:
+    ``apply_rope``, ``splash_grouped_attention``'s scale and transpose."""
+    from commefficient_tpu.models.laguna import apply_rope
+    B, S, width = x.shape
+    D = width // math.prod(heads)
+    q = apply_rope(x.reshape(B, S, -1, D), cos, sin)
+    if scale is not None:
+        q = (q * scale).astype(q.dtype)
+    return jnp.moveaxis(q.reshape((B, S) + heads + (D,)), 1, -2)
+
+
+def _plain_from_heads(o, gate):
+    """The way back: the transpose, ``LagunaBlock``'s gate product."""
+    B, S, H = gate.shape
+    o = jnp.moveaxis(o.reshape(B, H, S, -1), 1, 2)
+    return (o.astype(jnp.float32) * gate[..., None]).astype(
+        o.dtype).reshape(B, S, -1)
+
+
+def _within_ulps(got, want, bits):
+    """|got - want| is at most the spacing of ``bits``-bit mantissas at
+    the larger of the two (7: one bfloat16 ulp; 6: two), or, where a sum
+    cancels, a float32 ulp of the largest value."""
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    top = np.maximum(np.abs(got), np.abs(want))
+    ulp = np.exp2(np.floor(np.log2(np.maximum(top, 1e-30))) - bits)
+    ulp = np.maximum(ulp, 2.0 ** -22 * top.max())
+    worst = (np.abs(got - want) / ulp).max()
+    assert worst <= 1 and top.max() > 0, worst
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind,G", [("sliding_attention", 8),
+                                    ("full_attention", 6)],
+                         ids=["window_64_heads", "full_48_heads_yarn"])
+def test_rotary_and_gate_kernels_equal_the_plain_path(kind, G, dtype):
+    """``ops/rope_pallas.py`` in interpret mode against the plain path
+    around the blocked kernel, values and ``jax.vjp``: q (64 heads, half
+    = 64 on a window layer; 48 heads, half = 32 under the YaRN tables with
+    their ``attention_factor`` on a full layer) rotated, rounded, scaled
+    and head-major as (KV, G), with k (8 KV heads) the same unscaled in
+    the one call; the gated way back; a batch of two sequences of 128
+    positions (two passes of the kernels' inner loop), the second padded
+    with zeros from position 80. Two sets of operands. Where every
+    product and sum is exact (whole numbers times tables in eighths) the
+    two are equal to the bit in both dtypes: same permutation, signs,
+    scale and order of roundings. Under the real tables XLA's CPU backend
+    contracts ``a * b + c * d`` into a fused multiply-add, and not the
+    same product in the two programs, so float32 agrees to a float32 ulp
+    of the products and bfloat16 to one bfloat16 ulp a rounding (on the
+    chip, which has no such instruction, the forward passes are equal to
+    the bit: PERF.md, PR 35). The gate has one product an element and is
+    equal to the bit everywhere."""
+    from commefficient_tpu.models.laguna import RopeSpec
+    from commefficient_tpu.ops.rope_pallas import (gate_from_heads,
+                                                   rope_to_heads)
+    dt = jnp.dtype(dtype)
+    KV, D, B, S = 8, 128, 2, 128
+    H = KV * G
+    cos, sin = rope_tables(RopeSpec.from_dict(ROPE[kind]), D, jnp.arange(S))
+    assert cos.shape[-1] == (64 if G == 8 else 32)
+    if kind == "full_attention":
+        assert float(jnp.abs(cos).max()) > 1.4        # the attention factor
+    eighths = lambda t: jnp.round(t * 8) / 8
+    keys = jax.random.split(jax.random.PRNGKey(H), 7)
+    pad = ((jnp.arange(S) < 80)[None, :, None]
+           | (jnp.arange(B) == 0)[:, None, None]).astype(dt)
+
+    def draw(key, shape, whole):
+        x = jax.random.normal(key, shape, jnp.float32)
+        return (jnp.round(x * 2) if whole else x).astype(dt)
+
+    for whole in (True, False):
+        c, s = (eighths(cos), eighths(sin)) if whole else (cos, sin)
+        # the transpose scales first: 1/sqrt(D) is exact on neither
+        # path, and what float32 rotates after it has no exact products
+        scale = 0.125 if whole and dtype == "float32" else 1 / math.sqrt(D)
+        q = draw(keys[0], (B, S, H * D), whole) * pad
+        k = draw(keys[1], (B, S, KV * D), whole) * pad
+        cts = (draw(keys[2], (B, KV, G, S, D), whole),
+               draw(keys[3], (B, KV, S, D), whole))
+        got, got_vjp = jax.vjp(lambda q, k: rope_to_heads(
+            q, k, c, s, head_dim=D, scale=scale, interpret=True), q, k)
+        want, want_vjp = jax.vjp(lambda q, k: (
+            _plain_to_heads(q, c, s, (KV, G), scale),
+            _plain_to_heads(k, c, s, (KV,), None)), q, k)
+        assert [a.shape for a in got] == [a.shape for a in cts]
+        assert got[0].dtype == got[1].dtype == dt
+        for a, b in zip(got + got_vjp(cts), want + want_vjp(cts)):
+            if whole:
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b, np.float32))
+            elif dtype == "float32":
+                # an ulp of the products, which may cancel in the sum
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=0,
+                    atol=2.0 ** -22 * float(jnp.abs(b).max()))
+            else:
+                # one ulp at each of q's two roundings: the second is
+                # of a product with 0.088, whose ulp may be half as wide
+                _within_ulps(a, b, 6)
+
+        o = draw(keys[4], (B, KV, G, S, D), whole)
+        gate = jax.nn.sigmoid(jax.random.normal(keys[5], (B, S, H)))
+        ct = draw(keys[6], (B, S, H * D), whole)
+        got, got_vjp = jax.vjp(functools.partial(
+            gate_from_heads, interpret=True), o, gate)
+        want, want_vjp = jax.vjp(_plain_from_heads, o, gate)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        (d_o, d_gate), (want_o, want_gate) = got_vjp(ct), want_vjp(ct)
+        np.testing.assert_array_equal(
+            np.asarray(d_o, np.float32),
+            np.asarray(want_o, np.float32).reshape(d_o.shape))
+        assert d_gate.dtype == jnp.float32
+        # a sum of 128 float32 products, in the kernel's order
+        np.testing.assert_allclose(np.asarray(d_gate),
+                                   np.asarray(want_gate), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_blocked_path_is_the_plain_path_with_the_blocked_kernel(
+        blocked_kernel, monkeypatch):
+    """``LagunaBlock`` where the blocked kernel runs (the rotary and gate
+    kernels around it, interpret mode) against the same model on the
+    plain path (``apply_rope``, the plain attention, the gate in XLA), in
+    float32: loss and gradients agree as two float32 softmaxes do."""
+    from commefficient_tpu.models import laguna
+    model, params, ids = blocked_kernel(remat=False)
+    calls = []
+    monkeypatch.setattr(laguna, "rope_to_heads", lambda *a, _f=laguna.
+                        rope_to_heads, **kw: calls.append(1) or _f(*a, **kw))
+    blocked = _grad(model, params, ids)
+    assert len(calls) == model.cfg.num_hidden_layers
+    plain = _grad(LagunaLM(model.cfg, attn_impl=attn.dense_grouped_attention),
+                  params, ids)
+    assert len(calls) == model.cfg.num_hidden_layers
+    np.testing.assert_allclose(float(blocked[0]), float(plain[0]), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(blocked[1]), jax.tree.leaves(plain[1]),
+                    strict=True):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4 * scale, rtol=0)

@@ -235,6 +235,51 @@ def test_sharded_server_tail_decodes_with_the_kernel(topo, monkeypatch):
     assert not spans, spans[:2]
 
 
+def _hbm_writes(hlo):
+    """(name, opcode, dtype, dims) of every array an instruction of the
+    compiled text writes to HBM: the instructions outside fusion bodies,
+    less those that write nothing of their own (parameters, tuples,
+    bitcasts, control flow), the kernels (a ``custom-call`` answers for
+    its own outputs) and what holds a matrix product, alone or in its
+    fusion. An instruction on several lines is joined first."""
+    text = re.sub(r'\n(?="|\}\})', " ", hlo)
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            body = comps.setdefault(head[1], [])
+        elif line.startswith("}"):
+            body = None
+        elif body is not None and " = " in line:
+            body.append(line)
+    called = lambda line: re.search(r"calls=%?([\w.-]+)", line)[1]
+    fused = {called(line) for lines in comps.values() for line in lines
+             if " fusion(" in line}
+    product = {name for name, lines in comps.items()
+               if any(re.search(r" (convolution|dot)\(", line)
+                      for line in lines)}
+    silent = {"parameter", "get-tuple-element", "tuple", "bitcast",
+              "constant", "while", "conditional", "call", "opt-barrier",
+              "custom-call", "convolution", "dot", "copy-done",
+              "slice-done"}
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            found = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = (.*?) ([\w-]+)\(",
+                             line)
+            if (not found or found[3] in silent
+                    or found[3] == "fusion" and called(line) in product):
+                continue
+            shapes = re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]", found[2])
+            # an asynchronous copy names its destination, its source and
+            # a context: the first is the write
+            for dtype, dims in shapes[:1 if found[3].endswith("-start")
+                                      else None]:
+                yield (found[1], found[3], dtype,
+                       tuple(int(n) for n in dims.split(",")))
+
+
 def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
         one_chip, monkeypatch):
     """One client's ``value_and_grad`` of the Laguna loss at the benchmark
@@ -242,12 +287,20 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
     bf16, ``remat=True``): the blocked attention kernel's output and
     logsumexp survive each block's rematerialisation
     (``GROUPED_ATTN_RESIDUAL``), so the forward kernel is compiled once a
-    layer and not twice (10 / 5 / 5 under full remat), and what is kept
-    (288 MiB of bf16 outputs) does not raise the temporaries: 643.0 MB
-    here, 644.8 under full remat."""
+    layer and not twice (10 / 5 / 5 under full remat). Around it
+    (``ops/rope_pallas.py``, PR 35) rotary, scale, gate and the change
+    between the projections' (S, H x D) and the kernel's (KV, G, S, D)
+    are one pass a tensor: q and k in (one call for both) and the output
+    back, forward, remat forward and backward, 30 calls a client. No other
+    instruction writes a float32 array shaped by the heads: the plain
+    path's rotary, casts and relayouts wrote 104 of them, 5.7 GB a
+    client, and 5.6 GB of bfloat16 ones where 1.8 are left (v's
+    transposes, jax's rounding of the kept outputs, the compiler's
+    prefetches). The client step's temporaries: 269 MB (643 before)."""
     from commefficient_tpu.losses import make_laguna_loss
     from commefficient_tpu.models.gpt2 import resolve_attn
     from commefficient_tpu.models.laguna import LagunaConfig, LagunaLM
+    from commefficient_tpu.ops import rope_pallas as rp
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lcfg = LagunaConfig.from_json(
         "perfbench/configs/laguna_xs2_share32.json",
@@ -264,13 +317,27 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
     compiled = jax.jit(jax.value_and_grad(loss_fn, has_aux=True)).lower(
         params, {"input_ids": ids},
         jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=one_chip)).compile()
-    calls = [line.split()[0] for line in compiled.as_text().splitlines()
+    hlo = compiled.as_text()
+    calls = [line.split()[0] for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     counts = collections.Counter(
-        re.sub(r"^%|_(no_)?residuals.*", "", name) for name in calls)
+        re.sub(r"^%|_(no_)?residuals.*|\.\d+$", "", name) for name in calls)
     assert counts == {"splash_mqa_fwd": 5, "splash_mqa_dq": 5,
-                      "splash_mqa_dkv": 5}, counts
-    assert compiled.memory_analysis().temp_size_in_bytes < 680e6
+                      "splash_mqa_dkv": 5, rp.ROPE_KERNEL_NAME: 15,
+                      rp.GATE_KERNEL_NAME: 10,
+                      rp.GATE_BWD_KERNEL_NAME: 5}, counts
+    KV, D = lcfg.num_key_value_heads, lcfg.head_dim
+    head_counts = {KV, *lcfg.num_attention_heads_per_layer,
+                   *(h // KV for h in lcfg.num_attention_heads_per_layer)}
+    assert head_counts == {8, 6, 48, 64}
+    head_shaped = [w for w in _hbm_writes(hlo)
+                   if S in w[3] and head_counts & set(w[3])
+                   and np.prod(w[3]) >= S * KV * D]
+    assert not [w for w in head_shaped if w[2] == "f32"], head_shaped
+    # what is left for XLA in bfloat16, against 5.6e9 B before; how many
+    # prefetches the compiler makes moves it by a few 1e8
+    assert sum(2 * int(np.prod(w[3])) for w in head_shaped) < 2.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
 @pytest.mark.parametrize("sharded", [False, True],
