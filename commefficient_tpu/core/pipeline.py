@@ -58,6 +58,21 @@ what the loop actually *waited*, while the ``data_fetch`` spans keep the
 input path's real cost visible in the teleview timeline. Overlap shows
 up as data_fetch spans (worker tid) running under round dispatch spans
 (main tid).
+
+Both threads say which global round their work is for
+(``tracing.set_round``): the worker before it fetches round g, the
+consumer when it is asked for round g, so the ``data_fetch``, the
+``data_wait`` and the runtime's ``round_dispatch`` of one round carry the
+same ``round`` in the tracer. What an epoch's turnover costs the loop is
+``pipeline_close`` (drain and join), ``pipeline_open`` (the constructor,
+thread start included) and the first round's ``data_wait``, which finds
+the queue empty. The ``data_wait`` span of a round handed out carries
+``ready``: whether its batch was already queued when asked for (the call
+that meets the end-of-epoch sentinel hands out no round and has no mark;
+the inline path has no ``data_wait``, so none is ready). The call that
+meets the sentinel is work for the next epoch's first round and says so;
+once the epoch is exhausted or closed the thread is in no round (None)
+until the next ``pipeline_open``.
 """
 
 from __future__ import annotations
@@ -123,6 +138,7 @@ class RoundPipeline:
         if skip < 0:
             raise ValueError(f"skip must be >= 0, got {skip}")
         self._skip = int(skip)
+        self.rounds_out = 0  # rounds handed out
         if enabled and depth < 1:
             # this used to silently degrade to the inline fetch — a
             # caller asking for prefetch got none and no message. The
@@ -136,28 +152,48 @@ class RoundPipeline:
         self.threaded = bool(enabled)
         self._exhausted = False
         self._thread: Optional[threading.Thread] = None
-        if self.threaded:
-            self._q: queue.Queue = queue.Queue(maxsize=max(int(depth), 1))
-            self._stop = threading.Event()
-            self._thread = threading.Thread(
-                target=self._worker, name="round-prefetch", daemon=True)
-            self._thread.start()
-        else:
-            self._inline = self._inline_iter()
+        # opening the epoch is work for its first round
+        tracing.set_round(self._next_round())
+        with tracing.span("pipeline_open"):
+            if self.threaded:
+                self._q: queue.Queue = queue.Queue(
+                    maxsize=max(int(depth), 1))
+                self._stop = threading.Event()
+                self._thread = threading.Thread(
+                    target=self._worker, name="round-prefetch", daemon=True)
+                self._thread.start()
+            else:
+                self._inline = self._inline_iter()
 
     # ------------------------------------------------------------ iteration
 
     def __iter__(self) -> Iterator[RoundInput]:
         return self
 
+    def _next_round(self) -> int:
+        """The global round the next ``__next__`` hands out (rounds come
+        in the sampler's order, none dropped)."""
+        return self._start + self._skip + self.rounds_out + 1
+
     def __next__(self) -> RoundInput:
-        if not self.threaded:
-            return next(self._inline)
         if self._exhausted:
             raise StopIteration
+        tracing.set_round(self._next_round())
+        if not self.threaded:
+            try:
+                item = next(self._inline)
+            except StopIteration:
+                self._exhausted = True
+                tracing.set_round(None)
+                raise
+            self.rounds_out += 1
+            return item
+        ready = not self._q.empty()
         t0 = time.perf_counter()
-        with tracing.span("data_wait"):
+        with tracing.span("data_wait") as waited:
             kind, payload = self._q.get()
+            if kind is _ITEM:
+                waited.set(ready=ready)
         wait = time.perf_counter() - t0
         if kind is _ERR:
             self._exhausted = True
@@ -167,6 +203,7 @@ class RoundPipeline:
             self._exhausted = True
             self.close()
             raise StopIteration
+        self.rounds_out += 1
         return payload._replace(wait_s=wait)
 
     def _inline_iter(self) -> Iterator[RoundInput]:
@@ -196,6 +233,7 @@ class RoundPipeline:
                 if i < self._skip:
                     continue      # resume fast-forward (see class doc)
                 g = self._start + i + 1
+                tracing.set_round(g)
                 t0 = time.perf_counter()
                 with tracing.span("data_fetch"):
                     batch = self._fetch(rnd, g)
@@ -231,22 +269,23 @@ class RoundPipeline:
         at the epoch boundary after consuming every round; do not close
         a pipeline mid-stream and keep fetching from the same dataset
         expecting inline-identical augmentation draws."""
-        if not self.threaded or self._thread is None:
-            return
-        self._stop.set()
-        # drain so a worker blocked in put() observes the stop event
-        while True:
-            try:
-                self._q.get_nowait()
-            except queue.Empty:
-                break
-        self._thread.join(timeout=join_timeout)
-        if self._thread.is_alive():  # pragma: no cover — hung foreign fetch
-            import sys
-            print("WARNING: round-prefetch thread did not join within "
-                  f"{join_timeout}s (fetch hung?); left as daemon",
-                  file=sys.stderr)
-        self._thread = None
+        if self._thread is not None:
+            with tracing.span("pipeline_close"):
+                self._stop.set()
+                # drain so a worker blocked in put() observes the stop event
+                while True:
+                    try:
+                        self._q.get_nowait()
+                    except queue.Empty:
+                        break
+                self._thread.join(timeout=join_timeout)
+            if self._thread.is_alive():  # pragma: no cover — hung fetch
+                import sys
+                print("WARNING: round-prefetch thread did not join within "
+                      f"{join_timeout}s (fetch hung?); left as daemon",
+                      file=sys.stderr)
+            self._thread = None
+        tracing.set_round(None)  # what follows is for no round
 
     def __enter__(self) -> "RoundPipeline":
         return self

@@ -24,6 +24,7 @@ fed_aggregator.py:455).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -47,6 +48,12 @@ from commefficient_tpu.telemetry.clients import (CLIENT_GRAD_KEYS,
                                                  summarize_per_client)
 from commefficient_tpu.telemetry.profiling import phase
 from commefficient_tpu.telemetry.signals import round_signals
+
+
+# every FedRuntime of the process marks its spans with an ordinal of its
+# own (``runtime=`` in the tracer), so the rounds of a second runtime (a
+# reference check after a timed window) are told from the first's
+_ORDINALS = itertools.count()
 
 
 class _ClientHalf(NamedTuple):
@@ -105,6 +112,17 @@ class FedRuntime:
                  num_clients: Optional[int] = None,
                  mesh=None,
                  seq_spec: Optional[Dict[str, int]] = None):
+        self.ordinal = next(_ORDINALS)
+        with self._span("runtime_init"):
+            self._build(cfg, params, loss_fn_train, loss_fn_val,
+                        num_clients, mesh, seq_spec)
+
+    def _span(self, name: str):
+        """A host span marked with this runtime's ordinal."""
+        return tracing.span(name, runtime=self.ordinal)
+
+    def _build(self, cfg, params, loss_fn_train, loss_fn_val, num_clients,
+               mesh, seq_spec):
         grad_size, unravel = make_unraveler(params)
         cfg = cfg.replace(grad_size=grad_size)
         # a loss that reports more than (loss, one metric) says so itself
@@ -793,6 +811,10 @@ class FedRuntime:
             jax.ShapeDtypeStruct((self.d_pad,), jnp.float32))
 
     def init_state(self, seed: Optional[int] = None) -> FedState:
+        with self._span("init_state"):
+            return self._init_state(seed)
+
+    def _init_state(self, seed: Optional[int]) -> FedState:
         # the key is made here from the Python integer: as a jit argument
         # a seed past 2**31 overflows the int32 it would be parsed into
         rng = jax.random.PRNGKey(self.cfg.seed if seed is None else seed)
@@ -1843,7 +1865,7 @@ class FedRuntime:
         where payload carries the unnormalized transmitted-space sum the
         AsyncAggregator merges."""
         assert self._cohort is not None, "--async_agg is off"
-        with tracing.span("cohort_dispatch"):
+        with self._span("cohort_dispatch"):
             return self._cohort(state, jnp.asarray(client_ids, jnp.int32),
                                 batch, jnp.asarray(mask),
                                 self._prep_lr(lr), self.cs)
@@ -1878,7 +1900,7 @@ class FedRuntime:
     def commit(self, state: FedState, lr) -> Tuple[FedState, Dict]:
         """Commit the buffered aggregate through the server step."""
         assert self._commit_jit is not None, "--async_agg is off"
-        with tracing.span("commit_dispatch"):
+        with self._span("commit_dispatch"):
             return self._commit_jit(state, self._prep_lr(lr), self.cs)
 
     # -------------------------------------------------------------- user API
@@ -1888,21 +1910,27 @@ class FedRuntime:
         """Run one federated round. ``client_ids``: (num_workers,) int32;
         ``batch``: pytree with leaves (num_workers, batch_size, ...);
         ``mask``: (num_workers, batch_size); ``lr``: scalar or (d,) vector."""
-        # span = the async dispatch (argument staging + jit call return);
-        # device completion lands in the caller's "device_wait" span. A
-        # compile shows up here as a multi-second dispatch — cross-check
-        # with the `compile` event the JitWatcher emits for the same round
-        with tracing.span("round_dispatch"):
-            return self._round(state, jnp.asarray(client_ids, jnp.int32),
-                               batch, jnp.asarray(mask), self._prep_lr(lr),
-                               self.cs)
+        # round_dispatch = the async dispatch: round_stage (the host's
+        # arguments onto the device) and round_launch (the jitted call's
+        # return, the compile watcher's signature check included); device
+        # completion lands in the caller's "device_wait" span. A compile
+        # shows up as a multi-second round_launch with compile_lower /
+        # compile_backend spans under it
+        with self._span("round_dispatch"):
+            with self._span("round_stage"):
+                client_ids = jnp.asarray(client_ids, jnp.int32)
+                mask = jnp.asarray(mask)
+                lr = self._prep_lr(lr)
+            with self._span("round_launch"):
+                return self._round(state, client_ids, batch, mask, lr,
+                                   self.cs)
 
     def val(self, state: FedState, batch, mask):
         """Masked evaluation on the current PS weights; returns
         (results_tuple, n_valid). On a mesh the batch pads up to a
         mesh-divisible item count (padding items are masked out) and
         shards over all devices — see _val_step_sharded."""
-        with tracing.span("val_dispatch"):
+        with self._span("val_dispatch"):
             mask = jnp.asarray(mask)
             if self.mesh is not None:
                 n = self.mesh.size

@@ -362,17 +362,15 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
     # driver only
     prof = ProfilerWindow(cfg.profile_dir, cfg.profile_rounds)
     # span tracer + MFU/starvation accounting (telemetry/tracing.py,
-    # telemetry/utilization.py): only installed when a telemetry stream
-    # exists — with --no_telemetry the process-global tracer stays the
-    # NullTracer and every span site is a shared no-op context manager
+    # telemetry/utilization.py): a tracer this loop drains into the
+    # stream is installed only when a telemetry stream exists — with
+    # --no_telemetry the spans stay in the process's default ring. Either
+    # way every span is a "fed:" annotation in a profiler trace
+    # (--profile_rounds), on the same clock as the device's ops
     tracer = util = None
     monitor = recorder = ledger = None
     if telemetry is not None:
-        # under --profile_rounds the spans also enter the profiler's own
-        # trace (prefix "fed:"), on the same clock as the device's ops
-        tracer = tracing.install(tracing.SpanTracer(annotate=(
-            (lambda name: jax.profiler.TraceAnnotation("fed:" + name))
-            if prof.enabled else None)))
+        tracer = tracing.install(tracing.SpanTracer())
         util = UtilizationTracker(telemetry, peak_flops=cfg.peak_flops,
                                   peak_hbm_gbps=cfg.peak_hbm_gbps,
                                   watcher=telemetry.watcher(),

@@ -104,6 +104,9 @@ class DeviceStore:
             self._batch = jax.jit(self._batch_impl, out_shardings=out_sh)
         else:
             self._batch = jax.jit(self._batch_impl)
+        # the first round_batch holds the gather's compile (the data set is
+        # a constant of that executable): its span has a name of its own
+        self._gathered = False
 
     @property
     def nbytes(self) -> int:
@@ -179,7 +182,9 @@ class DeviceStore:
         index upload + the async gather/augment dispatch — a long
         data_gather span against a short round means the batch jit (not
         the round) owns the input-wait fraction."""
-        with tracing.span("data_gather"):
+        name = "data_gather" if self._gathered else "data_gather_first"
+        self._gathered = True
+        with tracing.span(name):
             return self._batch(jnp.asarray(flat_idx, jnp.int32), rng)
 
 

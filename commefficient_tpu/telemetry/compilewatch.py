@@ -26,6 +26,8 @@ from typing import Any, Callable, Dict
 
 import jax
 
+from commefficient_tpu.telemetry import tracing
+
 # latest compiled executable per watched name, process-wide: whoever has
 # no handle on the runtime (a metric reader, an operator's notebook) reads
 # the HLO of the program that is running — same pattern as
@@ -153,10 +155,15 @@ class JitWatcher:
             compiled = cache.get(key)
             if compiled is None:
                 try:
+                    # also as spans, under whatever span is open: a
+                    # recompile inside a timed window is a child of its
+                    # round_launch
                     t0 = time.perf_counter()
-                    lowered = fn.lower(*args)
+                    with tracing.span("compile_lower"):
+                        lowered = fn.lower(*args)
                     t1 = time.perf_counter()
-                    compiled = _compile(lowered)
+                    with tracing.span("compile_backend"):
+                        compiled = _compile(lowered)
                     t2 = time.perf_counter()
                 except Exception:
                     # un-lowerable input (or an AOT-unsupported transform

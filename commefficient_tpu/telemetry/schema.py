@@ -281,8 +281,11 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
     },
     # batched wall-time spans (telemetry/tracing.py): the tracer's
     # completed-span buffer, drained at the round-record cadence OUTSIDE
-    # the timed region. Each span: {name, ts (seconds since t0 on the
-    # monotonic clock), dur_s, tid, depth}. t0_wall anchors the
+    # the timed region. Each span: {id, parent (the enclosing span's id
+    # on its thread), round (the global round the work is for, or null),
+    # name, ts (seconds since t0 on the monotonic clock), dur_s, tid,
+    # depth} and what its site attached (runtime, ready); the list's
+    # items are not validated, so no version changed. t0_wall anchors the
     # monotonic epoch to unix time; teleview's `timeline` subcommand
     # renders the stream into a perfetto/chrome-tracing trace.json
     "span": {
