@@ -24,6 +24,7 @@ fed_aggregator.py:455).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -962,43 +963,28 @@ class FedRuntime:
             if client_finite is not None else nan)
         return d
 
-    def _download_coord_counts(self, coord_last_update: jax.Array,
+    @staticmethod
+    def _download_coord_counts(coord_last_update: jax.Array,
                                thresholds: jax.Array) -> jax.Array:
         """Per-client count of coordinates updated at-or-after the
         client's last download (the download-byte accounting): counts[w]
-        = |{i : coord_last_update[i] >= thresholds[w]}|.
+        = |{i : coord_last_update[i] >= thresholds[w]}|, (W,) int32.
 
-        Single device this streams BLOCK by block through a lax.scan —
-        the obvious fused broadcast-compare-reduce materializes its
-        converted (W, d) s32 intermediate on CPU and TPU (measured: the
-        largest temp buffer of the fused-encode cohort, 2x the dense
-        gradient this PR's encode fusion removes; ~4 GB at GPT-2 124M
-        with 8 clients), so the accounting would single-handedly fail
-        the dryrun's temp < d*4 gate. Peak temp here is O(W * block).
-        On a mesh the broadcast form stays: the d axis is sharded, so
-        each device holds only a (W, d/n) slice, and a host-chosen block
-        split would fight the partitioner's own sharding of d."""
-        if self._axis is not None:
-            return (coord_last_update[None, :]
-                    >= thresholds[:, None]).sum(axis=1)
-        d = coord_last_update.shape[0]
-        blk = max(512, min(65536, d // 16))
-        nb = -(-d // blk)
-        pad = nb * blk - d
-        if pad:
-            # padding must never satisfy ``>= threshold`` for any real
-            # threshold (round indices) — int32 min is below them all
-            coord_last_update = jnp.pad(
-                coord_last_update, (0, pad),
-                constant_values=jnp.iinfo(jnp.int32).min)
-        blocks = coord_last_update.reshape(nb, blk)
-
-        def body(acc, b):
-            return acc + (b[None, :] >= thresholds[:, None]).sum(axis=1), None
-
-        counts, _ = lax.scan(
-            body, jnp.zeros(thresholds.shape, jnp.int32), blocks)
-        return counts
+        One scalar reduction a client over the vector as it lies. The W
+        reductions share their operand, so XLA fuses them as siblings of
+        ONE read at the rate of the memory (a flat int32 vector is tiled
+        1,024 wide on the TPU as it is: no view, no pad, no tail), and
+        nothing (W, d) exists. The broadcast-compare-reduce over a minor
+        axis asked for a (W, d) s32 on the CPU, and the scan over
+        65,536-wide blocks that avoided it until PR 37 paid a pad and a
+        relayout copy of the vector and a copy of every block for it
+        (36.9 ms a round in Laguna where this read is 2.1; PERF.md
+        section 6). On a mesh GSPMD reduces each chip's coordinate shard
+        where it lies and one small all-reduce adds the W counts
+        (tests/test_tpu_compile.py pins both compiles)."""
+        return jnp.stack([
+            (coord_last_update >= thresholds[w]).sum(dtype=jnp.int32)
+            for w in range(thresholds.shape[0])])
 
     def _download_ledger(self, state: FedState, client_ids: jax.Array):
         """The dispatch-time half of the byte ledger (``track_bytes``):
@@ -1009,8 +995,17 @@ class FedRuntime:
         client_last_round)``."""
         with phase("fed_byte_ledger"):
             thresholds = state.client_last_round[client_ids]
-            counts = self._download_coord_counts(state.coord_last_update,
-                                                 thresholds)
+            whole = lambda x: x
+            if self.shardings is not None:
+                # the W thresholds and the W counts whole on every chip
+                # (each compares its coordinate shard with all of them):
+                # read off or stacked into a vector sharded by client,
+                # every scalar is a collective-permute of its own
+                whole = functools.partial(
+                    lax.with_sharding_constraint,
+                    shardings=self.shardings.replicated)
+            counts = whole(self._download_coord_counts(
+                state.coord_last_update, whole(thresholds)))
             # per-SLOT byte vectors kept alive for the client_stats
             # quantiles (telemetry/clients.py) — the scatters below are
             # the same data keyed by client id over the whole universe
@@ -1592,7 +1587,10 @@ class FedRuntime:
                         jnp.arange(self.d_pad) < cfg.grad_size, update, 0.0)
             ps_weights = state.ps_weights - padded
 
-        # ---- byte accounting: record which coordinates changed this round
+        # ---- byte accounting: record which coordinates changed this
+        # round. One dense pass even where the update is k winners: a
+        # scatter of 50,000 marks takes the chip 4.4 ms whatever d, this
+        # pass 1.7 ms at d = 1.2e8 and 5.3 at 3.9e8 (PERF.md section 6)
         coord_last_update = state.coord_last_update
         if cfg.track_bytes:
             with phase("fed_byte_ledger"):

@@ -12,8 +12,9 @@
 - HLO byte-identity where the fused encode must be invisible (non-sketch
   modes; auto-with-blocker == explicit off);
 - the --sketch_fused_encode on fail-fast guard;
-- the blocked-scan download-byte accounting against the numpy reference
-  (the (W, d) broadcast it replaced was the round's largest temp).
+- the byte ledger against numpy: the download count (one fused read of
+  ``coord_last_update``) and, round by round through every mode and an
+  async cohort + commit, the marks and the byte vectors.
 """
 
 import jax
@@ -30,6 +31,7 @@ from commefficient_tpu.models.stream_mlp import (init_stream_mlp,
                                                  make_stream_mlp_loss)
 from commefficient_tpu.ops.sketch import (loop_token_zero, make_sketch_impl,
                                           sketch_encode_accum)
+from commefficient_tpu.parallel import make_mesh
 from tests.test_parallel import make_batch, quad_loss
 
 W, B = 4, 4
@@ -401,19 +403,159 @@ def test_fused_encode_auto_with_blocker_hlo_identical_to_off():
 # ----------------------------------------------------- byte-count accounting
 
 
-def test_download_coord_counts_blocked_scan_matches_numpy():
-    """The blocked-scan byte accounting (which replaced the (W, d)
-    broadcast-compare-reduce — the fused round's largest temp buffer)
-    against the obvious numpy reference, incl. a d that does not divide
-    the block and thresholds the padding would satisfy if mis-padded."""
-    rt = FedRuntime(make_cfg(), make_params(), quad_loss, num_clients=16)
-    rng = np.random.RandomState(0)
-    for d in (100, 512 * 3 + 17, 2048):
-        clu = jnp.asarray(rng.randint(-1, 40, (d,)), jnp.int32)
-        # include the minimum threshold present in real states (0 after
-        # init, possibly -1-ish sentinels) — padding must never count
-        thr = jnp.asarray([0, 3, -1, 39], jnp.int32)
-        got = np.asarray(jax.jit(rt._download_coord_counts)(clu, thr))
-        ref = (np.asarray(clu)[None, :]
-               >= np.asarray(thr)[:, None]).sum(axis=1)
-        np.testing.assert_array_equal(got, ref, err_msg=str(d))
+@pytest.mark.parametrize("n_thresholds", [1, 4, 8])
+@pytest.mark.parametrize("d", [100, 1553, 2048, 7 * 1024, 3 * 1024 + 5,
+                               8 * 128 + 1])
+def test_download_coord_counts_matches_numpy(d, n_thresholds):
+    """The byte ledger's count (one fused read of the vector, PR 37)
+    against the obvious numpy reference, under jit: lengths on and off
+    the TPU's 1,024-wide tile, marks that include -1 (never updated, and
+    what mesh padding holds), thresholds that include -1 (counts every
+    coordinate), 0, the highest mark, one above it (counts none) and
+    repeats."""
+    rng = np.random.RandomState(d + n_thresholds)
+    hi = 39
+    marks = rng.randint(-1, hi + 1, (d,)).astype(np.int32)
+    marks[rng.randint(0, d, 3)] = [-1, 0, hi]
+    thr = np.asarray([hi, -1, 0, hi + 1, 3, hi, 3, 17][:n_thresholds],
+                     np.int32)
+    got = jax.jit(FedRuntime._download_coord_counts)(jnp.asarray(marks),
+                                                     jnp.asarray(thr))
+    assert got.dtype == jnp.int32 and got.shape == (n_thresholds,)
+    ref = (marks[None, :] >= thr[:, None]).sum(axis=1)
+    np.testing.assert_array_equal(np.asarray(got), ref)
+    if n_thresholds >= 4:
+        assert ref[1] == d and ref[3] == 0
+
+
+class _LedgerModel:
+    """The byte ledger in numpy: what a client downloads is 4 bytes a
+    coordinate marked at or after its last round, a round marks the
+    coordinates its update changed."""
+
+    def __init__(self, rt):
+        self.n, self.upload = rt.num_clients, rt.cfg.upload_wire_bytes()
+        self.marks = np.full((rt.d_pad,), -1, np.int32)
+        self.last_round = np.zeros((self.n,), np.int32)
+        self.step = 0
+
+    def dispatch(self, ids):
+        counts = (self.marks[None, :]
+                  >= self.last_round[ids][:, None]).sum(axis=1)
+        self.down, self.up = (np.zeros((self.n,), np.float32)
+                              for _ in range(2))
+        self.down[ids], self.up[ids] = 4.0 * counts, self.upload
+        self.last_round[ids] = self.step
+
+    def commit(self, update):
+        self.marks = np.where(update != 0, self.step,
+                              self.marks).astype(np.int32)
+        self.step += 1
+
+    def check(self, state, out=None):
+        np.testing.assert_array_equal(np.asarray(state.coord_last_update),
+                                      self.marks)
+        np.testing.assert_array_equal(np.asarray(state.client_last_round),
+                                      self.last_round)
+        if out is not None:
+            np.testing.assert_array_equal(
+                np.asarray(out["download_bytes"]), self.down)
+            np.testing.assert_array_equal(
+                np.asarray(out["upload_bytes"]), self.up)
+
+
+def _spy_server_half(rt):
+    """Record, round by round, the padded update the server half applied
+    and its k-sparse form (None where the rule hands none over)."""
+    seen, inner = [], rt._server_half
+
+    def spy(*args, **kw):
+        srv = inner(*args, **kw)
+        jax.debug.callback(
+            lambda padded, support: seen.append(
+                (np.asarray(padded),
+                 support and tuple(map(np.asarray, support)))),
+            srv.applied, srv.support)
+        return srv
+
+    rt._server_half = spy
+    return seen
+
+
+LEDGER_CASES = {
+    # name: (config, lr is a per-parameter vector, mesh devices)
+    "sketch_scalar_lr": (dict(), False, 0),
+    "sketch_lr_vector": (dict(), True, 0),
+    "true_topk": (dict(mode="true_topk", k=20), False, 0),
+    "uncompressed": (dict(mode="uncompressed", error_type="none"), False, 0),
+    "sketch_scalar_lr_mesh": (dict(), False, 4),
+    "true_topk_mesh": (dict(mode="true_topk", k=20), False, 4),
+    "async_cohort_commit": (dict(async_agg=True, max_inflight=1,
+                                 buffer_goal=1), False, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(LEDGER_CASES))
+def test_byte_ledger_trajectory_matches_numpy(case):
+    """Four rounds through the runtime's own entry points: after every
+    round ``coord_last_update``, ``client_last_round`` and the byte
+    vectors equal the numpy model's, whose marks are ``where(update !=
+    0, step, old)`` of the update the server half applied, be it dense
+    or the scatter of its k winners. The third round's lr is 0 (an lr
+    vector: a third of its entries always are), so winners whose value
+    is exactly zero are met and not marked; a parameter the loss never
+    reads gives true top-k zero winners in every round; mesh padding
+    past d stays -1."""
+    extra, lr_vector, n_mesh = LEDGER_CASES[case]
+    cfg = make_cfg(**extra)
+    params = {**make_params(), "unused": jnp.ones((3,), jnp.float32)}
+    mesh = make_mesh((n_mesh,), ("clients",)) if n_mesh else None
+    rt = FedRuntime(cfg, params, quad_loss, num_clients=16, mesh=mesh)
+    d = rt.cfg.grad_size
+    assert d == 21 and rt.d_pad == (24 if n_mesh else d)
+    seen = _spy_server_half(rt)
+    model = _LedgerModel(rt)
+    state = rt.init_state()
+    model.check(state)
+    batch, mask, _ = make_batch(3, W=W, B=B)
+    if mesh is not None:
+        # the W thresholds and counts are whole on every chip: read off
+        # or stacked into a vector sharded by client, each scalar was a
+        # collective-permute of its own (24 in the ResNet-50 mesh round)
+        hlo = rt._round.lower(state, jnp.arange(W, dtype=jnp.int32), batch,
+                              mask, rt._prep_lr(0.1), rt.cs).compile()
+        assert " collective-permute" not in hlo.as_text()
+    for r, scale in enumerate([0.1, 0.1, 0.0, 0.1]):
+        # clients 0-3, 3-6, 6-9, 9-12: one repeats from round to round
+        ids = (np.arange(W) + 3 * r).astype(np.int32)
+        lr = scale
+        if lr_vector:
+            lr = np.full((d,), 0.1, np.float32)
+            lr[r % 3::3] = 0.0
+        model.dispatch(ids)
+        if cfg.async_agg:
+            state, out = rt.cohort(state, ids, batch, mask, lr)
+            model.check(state)          # dispatch-time half alone
+            state = rt.merge_first(state, out["sum"], out["n_total"])
+            state, _ = rt.commit(state, lr)
+        else:
+            state, out = rt.round(state, ids, batch, mask, lr)
+        jax.effects_barrier()
+        assert len(seen) == r + 1
+        padded, support = seen[-1]
+        assert padded.shape == (rt.d_pad,) and not padded[d:].any()
+        # the k winners once more, where the rule selects them and one
+        # scalar lr scales them (core/server.py:_support)
+        assert (support is None) == (lr_vector
+                                     or cfg.mode == "uncompressed")
+        if support is not None:
+            idx, vals = support
+            dense = np.zeros_like(padded)
+            dense[idx[idx < d]] = vals[idx < d]
+            np.testing.assert_array_equal(dense, padded)
+            if scale == 0.0 or cfg.mode == "true_topk":
+                assert (vals == 0).any()
+        model.commit(padded)
+        model.check(state, out)
+    assert (model.marks[:d] >= 0).any() and (model.marks[d:] == -1).all()
+    assert int(state.step) == 4

@@ -39,6 +39,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _collectives(hlo):
+    """The collectives of a compiled text, by kind, in program order."""
+    return re.findall(
+        r" (all-reduce|all-gather|all-to-all|collective-permute"
+        r"|reduce-scatter)(?:-start)?\(", hlo)
+
+
 # (c, r, m, widest): the benchmark's two sketch geometries at their real m, and
 # the widest c CirculantSketch.pallas_blocker lets through at r = 1 and
 # r = 5 (TABLE_VMEM_BUDGET on the decode's wrap-padded table): at r = 1
@@ -382,7 +389,53 @@ def test_group_sums_dense_is_one_read_and_no_loop(topo, sharded):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * held
     hlo = compiled.as_text()
     assert not re.search(r" while\(", hlo)
-    collectives = re.findall(
-        r" (all-reduce|all-gather|all-to-all|collective-permute)"
-        r"(?:-start)?\(", hlo)
-    assert collectives == (["all-reduce"] if sharded else [])
+    assert _collectives(hlo) == (["all-reduce"] if sharded else [])
+
+
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one_chip", "four_chips"])
+@pytest.mark.parametrize("d", [389634048, 124444416],
+                         ids=["laguna_d", "gpt2_d"])
+def test_byte_ledger_reads_the_marks_once_and_copies_nothing(topo, d,
+                                                             sharded):
+    """The dispatch-time byte ledger (core/runtime.py) at the two
+    language cells' d, W = 8: the download count with the ledger's
+    scatters over the client universe. No temporary near the vector
+    (until PR 37 a pad and an (nb, 65536) relayout of it, two d-long
+    copies; a (W, d) compare would be 12.5 GB), no loop (a 5,946-step
+    scan) and no Mosaic call (the uncompressed round may hold none, and
+    GSPMD partitions none). Over the four chips, d sharded as the state
+    shards it and the thresholds whole on every chip, as the ledger
+    hands them over: the count alone, one all-reduce of the W counts
+    and no other collective."""
+    import types
+    from commefficient_tpu.core import FedRuntime
+    W, n_clients = 8, 256
+    if sharded:
+        mesh = Mesh(np.array(topo.devices), ("clients",))
+        whole = NamedSharding(mesh, P())
+        marks_sh = NamedSharding(mesh, P("clients"))
+    else:
+        whole = marks_sh = SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape, sh=whole: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                      sharding=sh)
+
+    def ledger(marks, last_round, step, client_ids):
+        if sharded:
+            return FedRuntime._download_coord_counts(marks, last_round[:W])
+        rt = types.SimpleNamespace(
+            num_clients=n_clients, _upload_bytes=4.0 * 5 * 524288,
+            shardings=None,
+            _download_coord_counts=FedRuntime._download_coord_counts)
+        state = types.SimpleNamespace(coord_last_update=marks,
+                                      client_last_round=last_round,
+                                      step=step)
+        return FedRuntime._download_ledger(rt, state, client_ids)
+
+    compiled = jax.jit(ledger).lower(
+        arg((d,), marks_sh), arg((n_clients,)), arg(()), arg((W,))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    hlo = compiled.as_text()
+    assert not re.search(r" while\(", hlo)
+    assert "tpu_custom_call" not in hlo
+    assert _collectives(hlo) == (["all-reduce"] if sharded else [])
