@@ -43,6 +43,7 @@ from commefficient_tpu.telemetry import maybe_create as make_telemetry
 from commefficient_tpu.telemetry.clients import (client_stats_to_host,
                                                  make_ledger)
 from commefficient_tpu.telemetry.health import AnomalyMonitor, FlightRecorder
+from commefficient_tpu.telemetry.schema import MOE_COUNTER_FIELDS
 from commefficient_tpu.utils import (
     PiecewiseLinear,
     TableLogger,
@@ -345,8 +346,10 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
           resume_info=None, guard=None, round_counters=()):
     """The shared driver loop. ``round_counters`` names what the training
     loss returns after (loss, accuracy): the round's telemetry event
-    carries their means over the round's items under ``moe``
-    (models/laguna.MOE_COUNTERS; a non-zero ``dropped`` raises).
+    carries their means over the round's items, the expert layers'
+    under ``moe`` (models/layers.MOE_COUNTERS; a non-zero ``dropped``
+    raises), any other under its own name (models/joyai.ROUND_COUNTERS:
+    ``main_nll``, ``mtp_nll``).
     Returns ``(state, summary)``; ``summary``
     is None when the run ended before its schedule — a preemption drain
     (an orderly handoff) or an abort (non-finite update, alert abort,
@@ -868,11 +871,13 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
                             ids = np.asarray(rnd.client_ids)
                             down_clients = [float(x) for x in down_all[ids]]
                             up_clients = [float(x) for x in up_all[ids]]
-                        moe = None
+                        moe, named = None, {}
                         if round_counters:
-                            moe = {name: float((r * nv).sum() / tot)
-                                   for name, r in zip(round_counters,
-                                                      res[2:])}
+                            named = {name: float((r * nv).sum() / tot)
+                                     for name, r in zip(round_counters,
+                                                        res[2:])}
+                            moe = {name: named.pop(name)
+                                   for name in MOE_COUNTER_FIELDS}
                             if moe.get("dropped"):
                                 raise RuntimeError(
                                     f"round {global_round}: the expert "
@@ -881,7 +886,7 @@ def train(cfg: FedConfig, runtime: FedRuntime, state, train_ds, val_ds,
                             rnd=global_round, epoch=epoch + 1, lr=float(lr),
                             loss=float((res[0] * nv).sum() / tot),
                             acc=float((res[acc_idx] * nv).sum() / tot),
-                            n_valid=float(nv.sum()), moe=moe,
+                            n_valid=float(nv.sum()), moe=moe, **named,
                             download_bytes=down_total,
                             upload_bytes=up_total,
                             host_s=host_s,

@@ -14,7 +14,10 @@ Run:  python -m commefficient_tpu.gpt2_train --mode sketch \
 layer that holds a share of its experts) from a ``config.json`` in the
 published key set instead: next-token cross-entropy on every non-pad token
 of the same PersonaChat packing, through the same runtime, store, sampler
-and pipeline.
+and pipeline. ``--model joyai --model_checkpoint <config.json>`` trains
+``models/joyai.JoyAILM`` the same way (latent attention, a sigmoid router
+with a selection bias, a multi-token-prediction module whose loss is added
+at 0.3). ``CONFIG_MODELS`` is the table of such models.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ import dataclasses
 import json
 import math
 import os
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +45,9 @@ from commefficient_tpu.cv_train import (
 from commefficient_tpu.data.fed_persona import (FedPERSONA, HashTokenizer,
                                                 get_tokenizer)
 from commefficient_tpu.losses import (make_gpt2_train_loss,
-                                      make_gpt2_val_loss, make_laguna_loss)
+                                      make_gpt2_val_loss, make_joyai_loss,
+                                      make_laguna_loss)
+from commefficient_tpu.models import joyai as joyai_lib
 from commefficient_tpu.models import laguna as laguna_lib
 from commefficient_tpu.models.gpt2 import (
     NUM_SPECIAL_TOKENS,
@@ -75,18 +81,43 @@ def build_gpt2(cfg: FedConfig, tokenizer):
     return GPT2DoubleHeads(gcfg, attn_impl=resolve_attn(cfg.attn_impl)), gcfg
 
 
-def build_laguna(cfg: FedConfig):
-    """(model, LagunaConfig, tokenizer) from ``--model_checkpoint``, a
-    ``config.json`` in the published key set (it may state a chip's share,
-    see ``LagunaConfig.from_hf``). No public tokenizer is at hand offline:
+@dataclasses.dataclass(frozen=True)
+class ConfigModel:
+    """One ``--model`` that is built from ``--model_checkpoint``, a
+    ``config.json`` in its published key set: the configuration class
+    (``from_json``; it may read a chip's share), the module, the loss
+    builder ``(model, pad_id, lm_chunk, counters)``, what the training
+    loss reports after (loss, accuracy), and the model's operations
+    ``(config, tokens, S)``."""
+    config: type
+    module: type
+    make_loss: Callable
+    counters: Tuple[str, ...]
+    flops: Callable
+
+
+CONFIG_MODELS = {
+    "laguna": ConfigModel(laguna_lib.LagunaConfig, laguna_lib.LagunaLM,
+                          make_laguna_loss, laguna_lib.MOE_COUNTERS,
+                          laguna_lib.laguna_model_flops),
+    "joyai": ConfigModel(joyai_lib.JoyAIConfig, joyai_lib.JoyAILM,
+                         make_joyai_loss, joyai_lib.ROUND_COUNTERS,
+                         joyai_lib.joyai_model_flops),
+}
+
+
+def build_config_model(cfg: FedConfig):
+    """(model, its configuration, tokenizer) of a ``CONFIG_MODELS`` entry
+    from ``--model_checkpoint``. No public tokenizer is at hand offline:
     words hash into the configuration's vocabulary less the five special
     tokens, which take its last rows."""
-    lcfg = laguna_lib.LagunaConfig.from_json(
+    entry = CONFIG_MODELS[cfg.model]
+    mcfg = entry.config.from_json(
         cfg.model_checkpoint, compute_dtype=jnp.dtype(cfg.compute_dtype),
         remat=cfg.do_remat)
-    model = laguna_lib.LagunaLM(
-        lcfg, attn_impl=resolve_attn(cfg.attn_impl, grouped=True))
-    return model, lcfg, HashTokenizer(lcfg.vocab_size - NUM_SPECIAL_TOKENS)
+    model = entry.module(
+        mcfg, attn_impl=resolve_attn(cfg.attn_impl, grouped=True))
+    return model, mcfg, HashTokenizer(mcfg.vocab_size - NUM_SPECIAL_TOKENS)
 
 
 def make_gpt2_schedule(cfg: FedConfig):
@@ -179,9 +210,9 @@ def main(argv=None, *, on_finish=None):
     cfg = cfg.replace(dataset_name="PERSONA")
 
     timer = Timer()
-    laguna = cfg.model == "laguna"
-    if laguna:
-        model, gcfg, tokenizer = build_laguna(cfg)
+    built = CONFIG_MODELS.get(cfg.model)    # None: GPT-2 DoubleHeads
+    if built:
+        model, gcfg, tokenizer = build_config_model(cfg)
     else:
         tokenizer = get_tokenizer(cfg.model_checkpoint)
     max_seq_len = cfg.max_seq_len or (64 if cfg.do_test else 280)
@@ -201,10 +232,10 @@ def main(argv=None, *, on_finish=None):
     cfg = cfg.replace(num_clients=train_ds.num_clients)
 
     sample = train_ds.gather(np.zeros((1,), np.int64))
-    if laguna:
+    if built:
         params = jax.jit(model.init)(jax.random.PRNGKey(cfg.seed),
                                      jnp.asarray(sample["input_ids"]))
-        print(f"laguna: {gcfg.num_hidden_layers} layers, experts "
+        print(f"{cfg.model}: {gcfg.num_hidden_layers} layers, experts "
               f"{gcfg.experts_held[0]}-{gcfg.experts_held[1] - 1} of "
               f"{gcfg.num_experts} held, vocabulary {gcfg.vocab_size}; "
               "training from scratch")
@@ -231,15 +262,15 @@ def main(argv=None, *, on_finish=None):
     seq_shards = (mesh.shape["seq"]
                   if mesh is not None and "seq" in mesh.axis_names else 1)
     round_counters = ()
-    if laguna:
+    if built:
         if seq_shards > 1:
-            raise ValueError("--model laguna has no sequence-parallel "
+            raise ValueError(f"--model {cfg.model} has no sequence-parallel "
                              "attention; drop the seq mesh axis")
         pad_id = tokenizer.convert_tokens_to_ids("<pad>")
-        loss_train = make_laguna_loss(model, pad_id, lm_chunk=cfg.lm_chunk)
-        loss_val = make_laguna_loss(model, pad_id, lm_chunk=cfg.lm_chunk,
-                                    counters=False)
-        round_counters = laguna_lib.MOE_COUNTERS
+        loss_train = built.make_loss(model, pad_id, lm_chunk=cfg.lm_chunk)
+        loss_val = built.make_loss(model, pad_id, lm_chunk=cfg.lm_chunk,
+                                   counters=False)
+        round_counters = built.counters
     elif seq_shards > 1:
         if max_seq_len % seq_shards:
             raise ValueError(
@@ -260,7 +291,7 @@ def main(argv=None, *, on_finish=None):
                                           lm_chunk=cfg.lm_chunk)
     # validation always runs the dense model (same param pytree); on a
     # mesh the val batch shards over all devices (runtime._val_step_sharded)
-    if not laguna:
+    if not built:
         loss_val = make_gpt2_val_loss(model, lm_chunk=cfg.lm_chunk)
     runtime = FedRuntime(cfg, params, loss_train, loss_val,
                          num_clients=train_ds.num_clients,
@@ -272,7 +303,7 @@ def main(argv=None, *, on_finish=None):
           f"initialized in {timer():.2f}s")
 
     ckpt_mgr, start_epoch, restored, resume_info = setup_checkpointing(
-        cfg, runtime, "laguna" if laguna else "gpt2_doubleheads")
+        cfg, runtime, cfg.model if built else "gpt2_doubleheads")
     if restored is not None:
         state = restored
 
@@ -299,8 +330,7 @@ def main(argv=None, *, on_finish=None):
     # gpt2_model_flops); tokens/round = W x B x candidates x seq
     round_tokens = (cfg.num_workers * runtime.batch_size
                     * cfg.num_candidates * max_seq_len)
-    model_flops = (laguna_lib.laguna_model_flops if laguna
-                   else gpt2_model_flops)
+    model_flops = built.flops if built else gpt2_model_flops
     round_flops = model_flops(gcfg, round_tokens, max_seq_len)
     tsv = TSVLogger()
     guard = PreemptGuard(cfg.preempt_grace)
@@ -324,9 +354,9 @@ def main(argv=None, *, on_finish=None):
     if summary is not None:
         nll = summary["test_loss"]
         print(f"final val nll {nll:.4f} ppl {math.exp(min(nll, 20)):.2f} "
-              f"{'token' if laguna else 'mc'} acc "
+              f"{'token' if built else 'mc'} acc "
               f"{summary['test_acc']:.4f}")
-    if cfg.do_checkpoint and summary is not None and not laguna:
+    if cfg.do_checkpoint and summary is not None and not built:
         # reference parity: weights + config + tokenizer, reloadable
         # without this run's code in hand (fed_aggregator.py:208-211)
         save_pretrained(os.path.join(cfg.checkpoint_path,

@@ -201,6 +201,44 @@ def make_laguna_loss(model, pad_id: int, lm_chunk: int = 128,
     return loss_fn
 
 
+def make_joyai_loss(model, pad_id: int, lm_chunk: int = 128,
+                    mtp_coef: float = 0.3, counters: bool = True):
+    """``L_main + mtp_coef x L_mtp`` for ``models/joyai.JoyAILM`` on the
+    ``core/client`` contract, each a mean cross-entropy over its non-pad
+    labels through the same chunked scan and the one untied head: the
+    main stream's labels are ``input_ids`` shifted by one, the prediction
+    module's by two (its stream has S - 2 labelled positions). ``batch``
+    as for ``make_laguna_loss``. With ``counters`` (the training loss)
+    the results are ``(loss, (token accuracy, main_nll, mtp_nll) +
+    MOE_COUNTERS)``: ``models.joyai.ROUND_COUNTERS`` after the accuracy.
+    Without (validation): the main stream's cross-entropy alone, which
+    is what a perplexity is of, and its accuracy."""
+    from commefficient_tpu.models.joyai import ROUND_COUNTERS
+    from commefficient_tpu.telemetry.profiling import phase
+
+    def loss_fn(params, batch, mask):
+        ids = batch["input_ids"]
+        hidden, hidden_mtp, head, moe = model.apply(params, ids,
+                                                    ids != pad_id)
+        labels = jnp.where(ids == pad_id, -100, ids)
+        m = mask.astype(jnp.float32)
+        chunk = lm_chunk if lm_chunk > 0 else ids.shape[-1]
+        main, acc = _chunked_lm_nll(hidden, head, labels, m, chunk,
+                                    with_acc=True)
+        if not counters:
+            return main, (acc,)
+        with phase("fed_mtp"):
+            # position i of the module's stream against token i + 2
+            mtp = _chunked_lm_nll(hidden_mtp[..., :-1, :], head,
+                                  labels[..., 1:], m, chunk)
+        named = {**moe, "main_nll": main, "mtp_nll": mtp}
+        return main + mtp_coef * mtp, (acc,) + tuple(
+            lax.stop_gradient(named[k]) for k in ROUND_COUNTERS)
+
+    loss_fn.num_results = 2 + (len(ROUND_COUNTERS) if counters else 0)
+    return loss_fn
+
+
 def make_cv_loss(model, compute_dtype: str = "bfloat16",
                  frozen_params=None) -> Callable:
     """Masked softmax cross-entropy + top-1 accuracy (reference

@@ -24,6 +24,7 @@ from commefficient_tpu.models.resnets import (
     wide_resnet50_2,
     wide_resnet101_2,
 )
+from commefficient_tpu.models.joyai import JoyAIConfig, JoyAILM
 from commefficient_tpu.models.laguna import LagunaConfig, LagunaLM
 
 _REGISTRY = {
@@ -42,9 +43,11 @@ _REGISTRY = {
     "resnext101_32x8d": resnext101_32x8d,
     "wide_resnet50_2": wide_resnet50_2,
     "wide_resnet101_2": wide_resnet101_2,
-    # a language model: built from a config.json by gpt2_train
-    # (--model laguna --model_checkpoint <config.json>), not by cv_train
+    # language models: built from a config.json by gpt2_train
+    # (--model laguna|joyai --model_checkpoint <config.json>), not by
+    # cv_train
     "laguna": LagunaLM,
+    "joyai": JoyAILM,
 }
 
 MODEL_NAMES = sorted(_REGISTRY)
@@ -59,5 +62,6 @@ def get_model(name: str):
             f"unknown model {name!r}; choices: {MODEL_NAMES}") from None
 
 
-__all__ = ["get_model", "MODEL_NAMES", "LagunaConfig", "LagunaLM"] + [
-    n for n in _REGISTRY if n != "laguna"]
+__all__ = ["get_model", "MODEL_NAMES", "LagunaConfig", "LagunaLM",
+           "JoyAIConfig", "JoyAILM"] + [
+    n for n in _REGISTRY if n not in ("laguna", "joyai")]

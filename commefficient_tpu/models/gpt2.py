@@ -162,8 +162,10 @@ def auto_causal_attention(q, k, v, dropout_rng=None):
 
 
 def dense_grouped_attention(q, k, v, window=None):
-    """Plain causal grouped-query attention: q (..., S, H, D), k and v
-    (..., S, KV, D) with H a multiple of KV -> (..., S, H, D). Query head
+    """Plain causal grouped-query attention: q (..., S, H, D), k
+    (..., S, KV, D) and v (..., S, KV, Dv) with H a multiple of KV ->
+    (..., S, H, Dv); scores over sqrt(D), the width of q and k, which v
+    need not share (latent attention: 192 and 128). Query head
     i reads KV head i // (H / KV). ``window``: key j is visible to query
     i iff 0 <= i - j < window (None: every earlier key). fp32 softmax."""
     S, H, D = q.shape[-3:]
@@ -177,7 +179,7 @@ def dense_grouped_attention(q, k, v, window=None):
         mask &= i - j < window
     probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
     out = jnp.einsum("...kgqs,...skd->...qkgd", probs.astype(v.dtype), v)
-    return out.reshape(q.shape)
+    return out.reshape(q.shape[:-1] + v.shape[-1:])
 
 
 GROUPED_ATTN_BLOCK = 512
@@ -192,8 +194,9 @@ def blocked_grouped_kernel(S, H, KV, window=None):
     """The TPU's blocked Pallas kernel
     (jax.experimental.pallas.ops.tpu.splash_attention, its multi-query
     form mapped over batch and KV heads) in the layout it reads and
-    writes: q (B, KV, H / KV, S, D) already scaled by 1/sqrt(D), k and v
-    (B, KV, S, D) -> (B, KV, H / KV, S, D). No (H, S, S) scores in HBM,
+    writes: q (B, KV, H / KV, S, D) already scaled by 1/sqrt(D), k
+    (B, KV, S, D) and v (B, KV, S, Dv) -> (B, KV, H / KV, S, Dv); the
+    kernel takes both widths from its operands. No (H, S, S) scores in HBM,
     and the blocks the mask removes entirely (above the diagonal; on a
     window layer also below the band) are never visited, forward or
     backward. Its output and logsumexp carry the name
@@ -236,9 +239,9 @@ def splash_grouped_attention(q, k, v, window=None):
     qb = (q * (1.0 / math.sqrt(D))).astype(q.dtype).reshape(
         (-1, S, KV, H // KV, D)).transpose(0, 2, 3, 1, 4)
     kb = k.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
-    vb = v.reshape((-1, S, KV, D)).transpose(0, 2, 1, 3)
+    vb = v.reshape((-1, S, KV, v.shape[-1])).transpose(0, 2, 1, 3)
     out = blocked_grouped_kernel(S, H, KV, window)(qb, kb, vb)
-    return out.transpose(0, 3, 1, 2, 4).reshape(q.shape)
+    return out.transpose(0, 3, 1, 2, 4).reshape(q.shape[:-1] + v.shape[-1:])
 
 
 def auto_grouped_attention(q, k, v, window=None):

@@ -32,24 +32,20 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-from jax import lax
 
 from commefficient_tpu.models.gpt2 import (GROUPED_ATTN_RESIDUAL,
                                            auto_grouped_attention,
                                            blocked_grouped_kernel,
                                            runs_blocked_kernel)
+# the decoder layers this model shares with models/joyai.py; the names
+# stay importable from here
+from commefficient_tpu.models.layers import (MOE_COUNTERS,  # noqa: F401
+                                             ExpertLayer, RMSNorm, SwiGLU,
+                                             linear, moe_counters)
 from commefficient_tpu.ops.rope_pallas import gate_from_heads, rope_to_heads
 from commefficient_tpu.telemetry.profiling import phase
 
 FULL, SLIDING = "full_attention", "sliding_attention"
-# what the model reports beside the loss, per microbatch (core/client.py
-# averages them over a client's items): tokens per held expert over the
-# sparse layers, the share of the routed slots that land on held experts,
-# and the slots of held experts the dispatch could not take (always 0)
-MOE_COUNTERS = ("tokens_per_expert_min", "tokens_per_expert_mean",
-                "tokens_per_expert_max", "held_share", "dropped")
-
-
 @dataclasses.dataclass(frozen=True)
 class RopeSpec:
     """One entry of the published ``rope_parameters``."""
@@ -90,6 +86,8 @@ class LagunaConfig:
     sliding_rope: RopeSpec = RopeSpec()
     # ids [lo, hi) of the experts this chip holds in every sparse layer
     experts_held: Tuple[int, int] = (0, 256)
+    # the expert layer's routing rule (models/layers.ROUTER_SCORING)
+    router_scoring: str = "softmax"
     compute_dtype: Any = jnp.bfloat16
     # recompute every block in the backward pass, keeping of its interior
     # only what carries GROUPED_ATTN_RESIDUAL: the blocked attention
@@ -207,98 +205,6 @@ def rope_gated_blocked_attention(q, k, v, gate, cos, sin, window=None):
     return o.reshape(lead + (S, H, D))
 
 
-class RMSNorm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        return xf * lax.rsqrt(var + self.eps) * scale
-
-
-def _dense(features, dt, name):
-    return nn.Dense(features, use_bias=False, dtype=dt, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
-
-
-class SwiGLU(nn.Module):
-    width: int
-    out: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        g = _dense(self.width, self.dtype, "gate_proj")(x)
-        u = _dense(self.width, self.dtype, "up_proj")(x)
-        return _dense(self.out, self.dtype, "down_proj")(nn.silu(g) * u)
-
-
-class ExpertLayer(nn.Module):
-    """Router over all experts, the held experts' part of the sum.
-
-    Every held expert is applied to every token, as one batched product
-    over the held experts, and its output weighted by what the router
-    gave it there: zero where the token did not choose it. No token can be
-    dropped, the shapes are static and so is the time. A dispatch that
-    sorts the routed slots by expert and runs grouped products over the
-    rows in use does a thirty-second of these operations when routing is
-    uniform, and it was built first (PERF.md, PR 28): with a dropless
-    guarantee its time follows the router, whose choices for the tokens of
-    one sequence are strongly correlated, and the round's time moved by 2%
-    from seed to seed. With as many experts held as a token chooses, every
-    token on every held expert is also that dispatch's worst case.
-    ``valid`` (the shape of xn less its last axis) marks the positions
-    that are tokens; the others are given nothing and counted nowhere."""
-    cfg: LagunaConfig
-
-    @nn.compact
-    def __call__(self, xn, valid=None):
-        cfg = self.cfg
-        dt = cfg.compute_dtype
-        E, I = cfg.hidden_size, cfg.moe_intermediate_size
-        lo, hi = cfg.experts_held
-        G, k = cfg.n_held, cfg.num_experts_per_tok
-        lead = xn.shape[:-1]
-        x = xn.reshape(-1, E)                                # (T, E) f32
-        T = x.shape[0]
-        init = nn.initializers.normal(0.02)
-        w_r = self.param("router", init, (E, cfg.num_experts))
-        w_gate = self.param("experts_gate", init, (G, E, I)).astype(dt)
-        w_up = self.param("experts_up", init, (G, E, I)).astype(dt)
-        w_down = self.param("experts_down", init, (G, I, E)).astype(dt)
-
-        logits = jnp.dot(x, w_r.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)
-        top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        top_w = top_p / top_p.sum(-1, keepdims=True)         # (T, k)
-
-        held = (top_e >= lo) & (top_e < hi)
-        if valid is not None:
-            held &= valid.reshape(-1, 1)
-        # (G, T): the router's weight of held expert g on token t, or 0
-        chosen = held[None] & (top_e[None] - lo
-                               == jnp.arange(G)[:, None, None])
-        w = (top_w[None] * chosen).sum(-1)
-        xc = x.astype(dt)
-        h = (nn.silu(jnp.einsum("te,gei->gti", xc, w_gate))
-             * jnp.einsum("te,gei->gti", xc, w_up))
-        # weighted before the down projection, which then sums over the
-        # held experts in float32: no (G, T, E) array
-        y = jnp.einsum("gti,gie->te", h * w[..., None].astype(dt), w_down,
-                       preferred_element_type=jnp.float32)
-        y = y * cfg.moe_routed_scaling_factor
-        tokens = chosen.any(-1).sum(-1)                      # (G,)
-        n_held_slots = held.sum()
-        counts = {"tokens": tokens.astype(jnp.float32),
-                  "held_share": n_held_slots / jnp.float32(T * k),
-                  # routed slots of held experts that got no product
-                  "dropped": (n_held_slots - tokens.sum()).astype(
-                      jnp.float32)}
-        return y.reshape(lead + (E,)).astype(dt), counts
-
-
 class LagunaBlock(nn.Module):
     """One pre-norm block: attention, then the dense or the expert layer.
     Two paths through the attention, chosen by what the code can see and
@@ -327,11 +233,11 @@ class LagunaBlock(nn.Module):
 
         h = RMSNorm(cfg.rms_norm_eps, name="input_norm")(x).astype(dt)
         heads = lambda t, n: t.reshape(t.shape[:-1] + (n, D))
-        q = heads(_dense(H * D, dt, "q_proj")(h), H)
-        k = heads(_dense(KV * D, dt, "k_proj")(h), KV)
-        v = heads(_dense(KV * D, dt, "v_proj")(h), KV)
+        q = heads(linear(H * D, dt, "q_proj")(h), H)
+        k = heads(linear(KV * D, dt, "k_proj")(h), KV)
+        v = heads(linear(KV * D, dt, "v_proj")(h), KV)
         gate = jax.nn.sigmoid(
-            _dense(H, dt, "g_proj")(h).astype(jnp.float32))
+            linear(H, dt, "g_proj")(h).astype(jnp.float32))
         cos, sin = rope_tables(rope, D, positions)
         window = cfg.sliding_window if sliding else None
         if D % 128 == 0 and runs_blocked_kernel(self.attn_impl, q.shape[-3]):
@@ -341,7 +247,7 @@ class LagunaBlock(nn.Module):
             with phase("fed_attention"):
                 o = self.attn_impl(q, k, v, window=window)
             o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
-        x = x + _dense(cfg.hidden_size, dt, "o_proj")(
+        x = x + linear(cfg.hidden_size, dt, "o_proj")(
             o.reshape(o.shape[:-2] + (H * D,)))
 
         hn = RMSNorm(cfg.rms_norm_eps, name="post_norm")(x)
@@ -399,21 +305,6 @@ class LagunaLM(nn.Module):
                 per_layer.append(counts)
         hidden = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
         return hidden, head, moe_counters(per_layer)
-
-
-def moe_counters(per_layer):
-    """The ``MOE_COUNTERS`` of one forward pass, over held experts and
-    sparse layers; zeros for a model without a sparse layer."""
-    if not per_layer:
-        return {name: jnp.zeros(()) for name in MOE_COUNTERS}
-    tokens = jnp.stack([c["tokens"] for c in per_layer])     # (layers, G)
-    return {
-        "tokens_per_expert_min": tokens.min(),
-        "tokens_per_expert_mean": tokens.mean(),
-        "tokens_per_expert_max": tokens.max(),
-        "held_share": jnp.stack([c["held_share"] for c in per_layer]).mean(),
-        "dropped": jnp.stack([c["dropped"] for c in per_layer]).sum(),
-    }
 
 
 def laguna_model_flops(cfg: LagunaConfig, tokens: int, S: int) -> float:

@@ -20,16 +20,24 @@ from typing import Callable, Optional, Tuple
 
 # The round's phases, flat and prefixed so that none can collide with a
 # jitted function's name in an op_name path (``jit(topk)``, ``jit(sort)``).
-# Where two nest (the encode, the table reduce, attention and the expert
-# layer sit inside the client step) the INNERMOST names the instruction;
-# nothing else nests.
+# Where two nest (the encode, the table reduce, attention, the latent
+# projections and the expert layer sit inside the client step, and a
+# prediction module's block inside ``fed_mtp``) the INNERMOST names the
+# instruction; nothing else nests.
 PHASES = (
     "fed_client_step",      # client_block: forward/backward, local rows, sum
     "fed_sketch_encode",    # every sketch encode of client gradients
-    "fed_attention",        # models/laguna.py: scores, mask, softmax, values
-                            # (or the kernel), both passes; not projections
-    "fed_moe",              # models/laguna.py: router, the held experts'
-                            # batched products; not the shared expert
+    "fed_attention",        # models/laguna.py, joyai.py: scores, mask,
+                            # softmax, values (or the kernel), both passes;
+                            # not projections
+    "fed_moe",              # models/layers.ExpertLayer: router, the held
+                            # experts' batched products; not the shared one
+    "fed_latent",           # models/joyai.py: latent attention's low-rank
+                            # projection pairs, latent norms, rotary, k_rope
+                            # over heads, scale and relayout; not W_o
+    "fed_mtp",              # models/joyai.py: the prediction module's norms,
+                            # W_eh, its block outside the three scopes above,
+                            # its head norm and chunked cross-entropy
     "fed_table_reduce",     # the cross-chip aggregation (mesh only)
     "fed_server_tail",      # normalize, momentum/EF, decode, top-k, apply
     "fed_signals",          # telemetry/signals.py round_signals
@@ -38,8 +46,9 @@ PHASES = (
     "fed_byte_ledger",      # track_bytes: download counts, last-update maps
 )
 
-# the phases only a model with such layers has (models/laguna.py)
-MODEL_PHASES = ("fed_attention", "fed_moe")
+# the phases only a model with such layers has (models/laguna.py: the
+# first two; models/joyai.py: all four)
+MODEL_PHASES = ("fed_attention", "fed_moe", "fed_latent", "fed_mtp")
 
 
 def phase(name: str):

@@ -350,14 +350,19 @@ class RunTelemetry:
                     upload_bytes: Optional[float],
                     host_s: float, dispatch_s: float,
                     device_s: float,
-                    moe: Optional[Dict[str, float]] = None) -> None:
+                    moe: Optional[Dict[str, float]] = None,
+                    main_nll: Optional[float] = None,
+                    mtp_nll: Optional[float] = None) -> None:
         """``moe`` (schema v12): the routed expert layers' counters of the
         round (tokens per held expert min / mean / max, the share of the
         routed slots that land on held experts, dropped tokens); null for
-        a model without such a layer."""
+        a model without such a layer. ``main_nll``, ``mtp_nll``: the two
+        terms of a loss with a multi-token-prediction module
+        (losses.make_joyai_loss: ``loss`` is main + 0.3 mtp); null for a
+        model without one."""
         self.event("round", round=rnd, epoch=epoch, lr=float(lr),
                    loss=float(loss), acc=float(acc), n_valid=float(n_valid),
-                   moe=moe,
+                   moe=moe, main_nll=main_nll, mtp_nll=mtp_nll,
                    download_bytes=download_bytes, upload_bytes=upload_bytes,
                    host_s=round(host_s, 6), dispatch_s=round(dispatch_s, 6),
                    device_s=round(device_s, 6))

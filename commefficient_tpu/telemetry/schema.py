@@ -130,6 +130,11 @@ EVENT_FIELDS: Dict[str, Dict[str, Any]] = {
         # experts and sparse layers, held_share of the tokens x top-k
         # routed slots, dropped: always 0); null without such a layer
         "moe": _opt_dict,
+        # the two terms of a loss with a multi-token-prediction module
+        # (loss = main_nll + 0.3 mtp_nll, models/joyai.py); null without
+        # one. OPTIONAL_FIELDS: a v12 stream from before them has neither
+        "main_nll": _opt_num,
+        "mtp_nll": _opt_num,
     },
     # per-epoch validation record (mirrors the console table row);
     # loss/acc metrics are null if non-finite (e.g. a NaN val sweep that
@@ -564,6 +569,13 @@ FIELDS_SINCE_V12: Dict[str, Tuple[str, ...]] = {
     "round": ("moe",),
 }
 
+# fields a stream of the current version may lack: added without a new
+# version because every writer since fills them (null where they do not
+# apply) and no reader requires them
+OPTIONAL_FIELDS: Dict[str, Tuple[str, ...]] = {
+    "round": ("main_nll", "mtp_nll"),
+}
+
 MOE_COUNTER_FIELDS = ("tokens_per_expert_min", "tokens_per_expert_mean",
                       "tokens_per_expert_max", "held_share", "dropped")
 
@@ -596,8 +608,11 @@ def validate_event(obj: Any,
     v9_only = FIELDS_SINCE_V9.get(kind, ())
     v11_only = FIELDS_SINCE_V11.get(kind, ())
     v12_only = FIELDS_SINCE_V12.get(kind, ())
+    optional = OPTIONAL_FIELDS.get(kind, ())
     for field, pred in spec.items():
         if field not in obj:
+            if field in optional:
+                continue
             if version < 6 and field in v6_only:
                 continue
             if version < 7 and field in v7_only:

@@ -171,10 +171,45 @@ def test_innermost_scope_names_the_instruction():
 def test_unknown_phase_raises():
     with pytest.raises(ValueError, match="nonsense"):
         phase("nonsense")
-    assert len(set(PHASES)) == len(PHASES) == 10
+    assert len(set(PHASES)) == len(PHASES) == 12
     assert all(p.startswith("fed_") for p in PHASES)
     with phase("fed_signals"):                    # a known one is a scope
         pass
+
+
+@pytest.mark.parametrize("family,config,want", [
+    ("joyai_moe", "joyai_flash_share32", set(MODEL_PHASES)),
+    ("laguna_moe", "laguna_xs2_share32", {"fed_attention", "fed_moe"}),
+])
+def test_model_phases_are_in_the_rounds_of_the_models_that_have_them(
+        family, config, want):
+    """A dense round of each config-built language model at its
+    configuration file's rehearsal sizes: latent attention's glue and the
+    prediction module name their instructions in the JoyAI round and in
+    no other's (the default model's: the test above)."""
+    import importlib
+    import json
+    fam = importlib.import_module(f"perfbench.families.{family}")
+    with open(spec.config_path(config)) as f:
+        hf = json.load(f)
+    cfg = fam.parse(["--mode", "uncompressed", "--error_type", "none",
+                     "--local_momentum", "0", "--weight_decay", "0",
+                     "--lm_chunk", "8", "--num_candidates", "1",
+                     "--max_seq_len", "32", "--compute_dtype", "float32",
+                     "--num_workers", "2", "--local_batch_size", "1",
+                     "--microbatch_size", "1", "--remat"])
+    b = fam.build(cfg, {**hf, **hf["rehearse"]}, 0)
+    rt = FedRuntime(cfg.replace(num_clients=2), b.params, b.loss_fn,
+                    num_clients=2)
+    rt.set_compile_watcher(compilewatch.JitWatcher(Recorder()))
+    batch = {k: v[:2, None] for k, v in b.dataset.arrays.items()}
+    rt.round(rt.init_state(), np.arange(2), batch, np.ones((2, 1), bool),
+             0.05)
+    table = phase_reader.parse_hlo(
+        rt.compile_watcher.executables["round_step"].as_text(), PHASES)
+    assert phases_of(table) & set(MODEL_PHASES) == want
+    by_phase = {p: sum(v == p for v in table.values()) for p in want}
+    assert min(by_phase.values()) >= 10, by_phase
 
 
 def test_latest_is_the_executable_that_ran_and_a_recompile_replaces_it():

@@ -347,6 +347,53 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
+def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
+        one_chip, monkeypatch):
+    """One client's ``value_and_grad`` of the JoyAI loss at the benchmark
+    cell's shape (the configuration file: five layers and the prediction
+    module, 1 x 1 x 4,096, bf16, ``remat=True``): latent attention's q and
+    k of 192 and v of 128 go through the blocked kernel as they are (one
+    query head a KV head, 32 of them; no padding to 256), the kernel's
+    output and logsumexp survive each block's rematerialisation, the
+    prediction module's block included: 6 forward, 6 dq, 6 dkv kernels and
+    not 12 forward; and nothing writes a (32, 4096, 4096) array of
+    scores."""
+    from commefficient_tpu.losses import make_joyai_loss
+    from commefficient_tpu.models.gpt2 import resolve_attn
+    from commefficient_tpu.models.joyai import JoyAIConfig, JoyAILM
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lcfg = JoyAIConfig.from_json(
+        "perfbench/configs/joyai_flash_share32.json",
+        compute_dtype=jnp.bfloat16, remat=True)
+    assert (lcfg.num_hidden_layers, lcfg.num_nextn_predict_layers) == (5, 1)
+    S, H = 4096, lcfg.num_attention_heads
+    model = JoyAILM(lcfg, attn_impl=resolve_attn("auto", grouped=True))
+    loss_fn = make_joyai_loss(model, lcfg.vocab_size - 1, lm_chunk=128)
+    ids = jax.ShapeDtypeStruct((1, 1, S), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 1, S), jnp.int32)))
+    compiled = jax.jit(jax.value_and_grad(loss_fn, has_aux=True)).lower(
+        params, {"input_ids": ids},
+        jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=one_chip)).compile()
+    hlo = compiled.as_text()
+    calls = [line.split()[0] for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    counts = collections.Counter(
+        re.sub(r"^%|_(no_)?residuals.*|\.\d+$", "", name) for name in calls)
+    assert counts == {"splash_mqa_fwd": 6, "splash_mqa_dq": 6,
+                      "splash_mqa_dkv": 6}, counts
+    # the kernels' operands are at the published widths
+    assert re.search(rf"bf16\[{H},1,{S},192\]", hlo)
+    assert not re.search(rf"\[{H},(1,)?{S},256\]", hlo)
+    scores = [w for w in _hbm_writes(hlo)
+              if w[3][-2:] == (S, S) or np.prod(w[3]) >= H * S * S]
+    assert not scores, scores
+    # the client step's temporaries (Laguna's: under 300 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+
+
 @pytest.mark.parametrize("sharded", [False, True],
                          ids=["one_chip", "four_chips"])
 def test_group_sums_dense_is_one_read_and_no_loop(topo, sharded):
