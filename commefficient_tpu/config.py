@@ -539,9 +539,12 @@ class FedConfig:
     # dots_with_no_batch_dims_saveable) applied when do_remat; "" = full
     remat_policy: str = ""
     # chunked LM cross-entropy: compute vocab logits ``lm_chunk`` tokens at
-    # a time under jax.checkpoint instead of materializing the full
-    # (tokens, vocab) fp32 tensor (+ cotangent) — the GPT-2 microbatch-8
-    # memory enabler (losses._chunked_lm_nll). 0 = dense
+    # a time, forward and (recomputed) backward, instead of materializing
+    # the full (tokens, vocab) fp32 tensor (+ cotangent) — the GPT-2
+    # microbatch-8 memory enabler (losses._chunked_lm_nll). It bounds the
+    # float32 logits alone: how many chunks' cotangents the backward keeps
+    # before it adds them into the head's gradient follows the shapes
+    # (losses.CE_GROUP_BYTES). 0 = dense
     lm_chunk: int = 0
     # GPT-2 attention implementation: "auto" (default — dense below
     # S=1024, flash above, the measured crossover on v5e:

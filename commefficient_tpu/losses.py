@@ -10,6 +10,7 @@ forward/backward while the federated vector and all server state stay fp32.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Tuple
 
 import jax
@@ -23,48 +24,138 @@ def _cast(tree, dtype):
         else t, tree)
 
 
+# Bytes of the logits' cotangents (tokens x V, in the dtype of the hidden
+# states) that the chunked cross-entropy's backward keeps between two
+# passes over the head's (V, E) float32 gradient: it sets how many chunks
+# make a group (``_ce_groups``). Measured on a v5e (PERF.md section 6, PR
+# 39): one float32 sequence of 32 chunks of 128 tokens is one group at
+# V = 16,160 (265 MB by this count; the TPU compiler stores them in
+# bfloat16, half that) and at V = 12,544, and each step from 4 chunks a
+# group to 32 was faster; GPT-2's chunk of 8 x 2 x 128 tokens x 50,262
+# (412 MB) stays a group of its own.
+CE_GROUP_BYTES = 256 << 20
+
+
+def _ce_groups(nch, chunk_bytes):
+    """(groups, chunks a group) for ``nch`` chunks whose logits' cotangent
+    is ``chunk_bytes`` each: the fewest groups that keep a group's
+    cotangents within ``CE_GROUP_BYTES``, of equal size (the last one
+    padded by fewer chunks than there are groups)."""
+    groups = -(-nch // max(1, min(nch, CE_GROUP_BYTES // chunk_bytes)))
+    return groups, -(-nch // groups)
+
+
+def _ce_chunks(hidden, labels, chunk, nch):
+    """The shifted stream as ``nch`` scan slices: hidden states
+    (nch, B, C, chunk, E) of positions 0..S-2 and their next-token labels
+    (nch, B, C, chunk), padded at the end with unlabelled positions."""
+    h, lab = hidden[..., :-1, :], labels[..., 1:]
+    B, C, T, E = h.shape
+    pad = nch * chunk - T
+    h = jnp.pad(h, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    lab = jnp.pad(lab, ((0, 0), (0, 0), (0, pad)), constant_values=-100)
+    return (h.reshape(B, C, nch, chunk, E).transpose(2, 0, 1, 3, 4),
+            lab.reshape(B, C, nch, chunk).transpose(2, 0, 1, 3))
+
+
+def _ce_forward(hidden, wte, labels, m, chunk, with_acc):
+    """``_chunked_lm_nll``'s results, and what its backward keeps beside
+    its arguments: the per-token logsumexp (nch, B, C, chunk) and the
+    clamped count of labelled tokens."""
+    nch = max(1, -(-(hidden.shape[-2] - 1) // chunk))
+    w = wte.astype(hidden.dtype)
+
+    def body(carry, inp):
+        num, den, hits = carry
+        hc, lc = inp                                  # (B, C, chunk, ...)
+        tok_valid = ((lc != -100) * m[:, None, None]).astype(jnp.float32)
+        logits = (hc @ w.T).astype(jnp.float32)
+        # log_softmax's own arithmetic, with its two halves kept apart
+        top = logits.max(-1)
+        shifted = logits - top[..., None]
+        lse = jnp.log(jnp.exp(shifted).sum(-1))
+        nll = lse - jnp.take_along_axis(
+            shifted, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
+        if with_acc:
+            hits = hits + ((jnp.argmax(logits, -1) == lc) * tok_valid).sum()
+        return ((num + (nll * tok_valid).sum(), den + tok_valid.sum(), hits),
+                top + lse)
+
+    (num, den, hits), lse = lax.scan(
+        body, (jnp.zeros(()),) * 3, _ce_chunks(hidden, labels, chunk, nch))
+    den = jnp.maximum(den, 1.0)
+    out = (num / den, hits / den) if with_acc else num / den
+    return out, lse, den
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _chunked_lm_nll(hidden, wte, labels, m, chunk, with_acc=False):
     """Shifted LM cross-entropy without ever materializing the full
     (tokens, vocab) logits: scan the sequence in ``chunk``-token slices,
     projecting + log-softmaxing each slice and accumulating the masked
-    NLL sums. ``jax.checkpoint`` on the scan body makes the backward pass
-    recompute each slice's logits instead of saving them, so peak memory
-    is O(chunk·V) — the enabler for microbatch ≥ 8 at the 32k-token GPT-2
-    round (the full fp32 logits + cotangent were ~1.6 GB per microbatch
-    step). fp32 accumulation; bitwise-equivalent math to the dense path
-    up to sum reordering (asserted by tests/test_models.py). ``wte`` is
-    the (V, E) output head, tied or not. ``with_acc``: also return the
-    share of labelled tokens whose largest logit is the label."""
-    h = hidden[..., :-1, :]                           # (B, C, S-1, E)
-    lab = labels[..., 1:]                             # (B, C, S-1)
-    B, C, T, E = h.shape
-    pad = (-T) % chunk
-    if pad:
-        h = jnp.pad(h, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        lab = jnp.pad(lab, ((0, 0), (0, 0), (0, pad)), constant_values=-100)
-    nch = (T + pad) // chunk
-    h = h.reshape(B, C, nch, chunk, E).transpose(2, 0, 1, 3, 4)
-    lab = lab.reshape(B, C, nch, chunk).transpose(2, 0, 1, 3)
+    NLL sums. fp32 accumulation; the dense path's math up to sum
+    reordering (tests/test_losses.py). ``wte`` is the (V, E) output
+    head, tied or not. ``with_acc``: also return the share of labelled
+    tokens whose largest logit is the label.
 
-    def body(carry, inp):
-        num, den, *hits = carry
-        hc, lc = inp                                  # (B, C, chunk, ...)
-        tok_valid = ((lc != -100) * m[:, None, None]).astype(jnp.float32)
-        logits = (hc @ wte.T.astype(hc.dtype)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits)
-        nll = -jnp.take_along_axis(
-            logp, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
-        if with_acc:
-            hits = [hits[0]
-                    + ((jnp.argmax(logits, -1) == lc) * tok_valid).sum()]
-        return (num + (nll * tok_valid).sum(),
-                den + tok_valid.sum(), *hits), None
+    Differentiable once, in ``hidden`` and ``wte``, by a backward of its
+    own (``_ce_backward``). Kept between the passes: the arguments, the
+    per-token logsumexp (4 B a token) and the count of labelled tokens;
+    no logits. The backward recomputes a chunk's logits in float32, forms
+    their cotangent in ``hidden``'s dtype and takes ``hidden``'s gradient
+    from it chunk by chunk, but sums the head's gradient once a group of
+    G chunks, in one product over the group's kept cotangents: the (V, E)
+    float32 accumulator crosses HBM nch / G times a call, not nch times
+    (autodiff of the scan carried it through every chunk). G follows the
+    shapes (``_ce_groups``): as many chunks as keep G x B x C x chunk x V
+    cotangents within ``CE_GROUP_BYTES``; G = 1 is autodiff's schedule.
+    Peak memory is O(chunk x V) float32 + that budget — the enabler for
+    microbatch >= 8 at the 32k-token GPT-2 round (the full fp32 logits +
+    cotangent were ~1.6 GB per microbatch step)."""
+    return _ce_forward(hidden, wte, labels, m, chunk, with_acc)[0]
 
-    (num, den, *hits), _ = lax.scan(
-        jax.checkpoint(body), (jnp.zeros(()),) * (3 if with_acc else 2),
-        (h, lab))
-    den = jnp.maximum(den, 1.0)
-    return (num / den, hits[0] / den) if with_acc else num / den
+
+def _ce_fwd(hidden, wte, labels, m, chunk, with_acc):
+    out, lse, den = _ce_forward(hidden, wte, labels, m, chunk, with_acc)
+    return out, (hidden, wte, labels, m, lse, den)
+
+
+def _ce_backward(chunk, with_acc, residuals, ct):
+    hidden, wte, labels, m, lse, den = residuals
+    B, C, S, E = hidden.shape
+    V, dt = wte.shape[0], hidden.dtype
+    scale = (ct[0] if with_acc else ct) / den         # the accuracy has none
+    nch = lse.shape[0]
+    groups, G = _ce_groups(nch, B * C * chunk * V * dt.itemsize)
+    h, lab = _ce_chunks(hidden, labels, chunk, groups * G)
+    lse = jnp.pad(lse, ((0, groups * G - nch), (0, 0), (0, 0), (0, 0)))
+    grouped = lambda t: t.reshape((groups, G) + t.shape[1:])
+    w = wte.astype(dt)
+
+    def chunk_body(_, inp):
+        hc, lc, lse_c = inp
+        weight = (lc != -100) * m[:, None, None] * scale
+        logits = (hc @ w.T).astype(jnp.float32)
+        p = jnp.exp(logits - lse_c[..., None])
+        dlogits = ((p - (jnp.arange(V) == lc[..., None]))
+                   * weight[..., None]).astype(dt)
+        return None, (dlogits @ w, dlogits)
+
+    def group_body(dw, inp):
+        hg, lg, lse_g = inp
+        _, (dh, dlogits) = lax.scan(chunk_body, None, (hg, lg, lse_g))
+        return dw + jnp.einsum("gbctv,gbcte->ve", dlogits, hg,
+                               preferred_element_type=jnp.float32), dh
+
+    dw, dh = lax.scan(group_body, jnp.zeros((V, E), jnp.float32),
+                      (grouped(h), grouped(lab), grouped(lse)))
+    dh = dh.reshape((groups * G, B, C, chunk, E)).transpose(1, 2, 0, 3, 4)
+    dh = dh.reshape(B, C, groups * G * chunk, E)[..., :S - 1, :]
+    return (jnp.pad(dh, ((0, 0), (0, 0), (0, 1), (0, 0))),
+            dw.astype(wte.dtype), None, None)
+
+
+_chunked_lm_nll.defvjp(_ce_fwd, _ce_backward)
 
 
 def _gpt2_losses(model, params, batch, mask, seq_axis=None, seq_shards=1,

@@ -287,6 +287,29 @@ def _hbm_writes(hlo):
                        tuple(int(n) for n in dims.split(",")))
 
 
+def _assert_head_gradient_is_one_product_a_stream(hlo, V, E, chunk=128,
+                                                  nch=32):
+    """The chunked cross-entropy of a compiled text
+    (``losses._ce_backward``) at one sequence of ``nch`` chunks, one
+    group: no loop carries the head's float32 (V, E) gradient (autodiff's
+    scan did, ``nch`` trips a stream, and its speed hung on the compiler
+    keeping that carry in VMEM), the product that forms it reads the
+    stream's cotangents, kept in bfloat16, and of float32 logits (V among
+    an array's extents, E not) there is one chunk at a time."""
+    carried = [line for line in hlo.splitlines()
+               if f"f32[{V},{E}]" in line.partition(" while(")[0]
+               and " while(" in line]
+    assert not carried, carried
+    logit_shaped = set()
+    for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]", hlo):
+        dims = tuple(map(int, dims.split(",")))
+        if V in dims and E not in dims:
+            logit_shaped.add((dtype, -(-int(np.prod(dims)) // (chunk * V))))
+    assert ("bf16", nch) in logit_shaped, logit_shaped
+    assert all(n <= (1 if dtype == "f32" else nch)
+               for dtype, n in logit_shaped), logit_shaped
+
+
 def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
         one_chip, monkeypatch):
     """One client's ``value_and_grad`` of the Laguna loss at the benchmark
@@ -303,7 +326,9 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
     path's rotary, casts and relayouts wrote 104 of them, 5.7 GB a
     client, and 5.6 GB of bfloat16 ones where 1.8 are left (v's
     transposes, jax's rounding of the kept outputs, the compiler's
-    prefetches). The client step's temporaries: 269 MB (643 before)."""
+    prefetches). The client step's temporaries: 269 MB (643 before; 281
+    since PR 39, whose chunked cross-entropy sums the head's gradient in
+    one product over the sequence's kept cotangents)."""
     from commefficient_tpu.losses import make_laguna_loss
     from commefficient_tpu.models.gpt2 import resolve_attn
     from commefficient_tpu.models.laguna import LagunaConfig, LagunaLM
@@ -345,6 +370,8 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
     # prefetches the compiler makes moves it by a few 1e8
     assert sum(2 * int(np.prod(w[3])) for w in head_shaped) < 2.5e9
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    _assert_head_gradient_is_one_product_a_stream(
+        hlo, lcfg.vocab_size, lcfg.hidden_size)
 
 
 def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
@@ -356,8 +383,9 @@ def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
     query head a KV head, 32 of them; no padding to 256), the kernel's
     output and logsumexp survive each block's rematerialisation, the
     prediction module's block included: 6 forward, 6 dq, 6 dkv kernels and
-    not 12 forward; and nothing writes a (32, 4096, 4096) array of
-    scores."""
+    not 12 forward; nothing writes a (32, 4096, 4096) array of scores;
+    and each stream's cross-entropy sums the head's gradient in one
+    product (PR 39)."""
     from commefficient_tpu.losses import make_joyai_loss
     from commefficient_tpu.models.gpt2 import resolve_attn
     from commefficient_tpu.models.joyai import JoyAIConfig, JoyAILM
@@ -392,6 +420,9 @@ def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
     assert not scores, scores
     # the client step's temporaries (Laguna's: under 300 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    # both streams' cross-entropy
+    _assert_head_gradient_is_one_product_a_stream(
+        hlo, lcfg.vocab_size, lcfg.hidden_size)
 
 
 @pytest.mark.parametrize("sharded", [False, True],
