@@ -12,9 +12,11 @@ Latent attention, every layer, H heads: ``c_q = RMSNorm(x W_qa)``;
 (one vector a position, shared by all heads), pairs (2i, 2i+1) by
 ``pos x theta^(-2i/rope)`` (``rope_interleave``). ``k = [k_nope ;
 k_rope]``; causal scores over sqrt(nope + rope), float32 softmax, values
-v_head_dim wide; ``x += concat(o) W_o``. The program holds the rotary
-dimensions half-split (all even members, then all odd): a fixed
-permutation of them on q and k alike, which leaves every score unchanged.
+v_head_dim wide; ``x += concat(o) W_o``. The plain path holds the rotary
+dimensions half-split (all even members, then all odd), the fused path
+around the blocked kernel holds the pairs where the projection writes
+them: each a fixed permutation of them on q and k alike, which leaves
+every score unchanged (``JoyAIBlock``).
 
 Layers below ``first_k_dense_replace``: SwiGLU of ``intermediate_size``.
 The others: ``models/layers.ExpertLayer`` under the rule ``sigmoid_bias``
@@ -52,6 +54,8 @@ from commefficient_tpu.models.laguna import RopeSpec, rope_tables
 from commefficient_tpu.models.layers import (MOE_COUNTERS, ExpertLayer,
                                              RMSNorm, SwiGLU, linear,
                                              moe_counters)
+from commefficient_tpu.ops import latent_pallas
+from commefficient_tpu.ops.latent_pallas import heads_to_rows, qkv_to_heads
 from commefficient_tpu.telemetry.profiling import phase
 
 # what the training loss reports after (loss, accuracy), in this order
@@ -139,7 +143,12 @@ class JoyAIConfig:
 def interleaved_rope(x, cos, sin):
     """Rotate the pairs (2i, 2i+1) of x (..., S, H, R) by the angles of
     (cos, sin), each (S, R/2), and hold the result half-split: the R/2
-    rotated even members, then the R/2 odd ones. Float32 arithmetic."""
+    rotated even members, then the R/2 odd ones. Float32 arithmetic, x's
+    dtype out. The plain path's rotary (the CPU, short sequences, the
+    float32 program) and the reference for the values of the fused path's
+    (``ops/latent_pallas.py``), which rotates the pairs in place: the same
+    products and roundings, another permutation of the lanes on q and k
+    alike."""
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., 0::2], xf[..., 1::2]
     c, s = cos[:, None, :], sin[:, None, :]
@@ -149,62 +158,71 @@ def interleaved_rope(x, cos, sin):
 
 class JoyAIBlock(nn.Module):
     """One pre-norm block: latent attention, then the dense or the expert
-    layer. ``fed_latent`` wraps both low-rank projection pairs, the two
-    latent norms, rotary, k_rope's spread over the heads and, where the
-    blocked kernel runs, the scale and the change to and from its layout;
-    ``fed_attention`` the attention proper (``attn_impl`` on the plain
-    path: the CPU, short sequences; ``blocked_grouped_kernel`` with one
-    query head a KV head where ``runs_blocked_kernel`` says so);
-    ``fed_moe`` the routed layer. W_o, the block's two norms, the dense
-    layer and the shared expert are the enclosing scope's."""
+    layer. Two paths through latent attention, chosen by what the code can
+    see and by no flag. Where ``attn_impl`` runs the blocked kernel
+    (``runs_blocked_kernel``: TPU, S a multiple of 512, from 1,024 up
+    under ``auto``) at the widths ``ops/latent_pallas.py`` takes (q and k
+    128 + 64, v 128), the fused path: the shared rotary key (S x 64) is
+    rotated in XLA, and q, k and v go from the projections' rows to the
+    kernel's (H, 1, S, D) layout in one pass a tensor (``qkv_to_heads``:
+    q's pairs rotated in place and scaled, k's rotary lanes the shared
+    key on every head), the output back in one (``heads_to_rows``); the
+    backward passes are those kernels' transposes. Elsewhere the plain
+    path, the reference for the values: ``interleaved_rope``, the
+    concatenations, ``attn_impl`` on (..., S, H, D). ``fed_latent`` wraps
+    both low-rank projection pairs, the two latent norms, rotary and, on
+    the fused path, the kernels around the attention; ``fed_attention``
+    the attention proper; ``fed_moe`` the routed layer. W_o, the block's
+    two norms, the dense layer and the shared expert are the enclosing
+    scope's."""
     cfg: JoyAIConfig
     sparse: bool
     attn_impl: Callable = auto_grouped_attention
 
     @nn.compact
-    def __call__(self, x, positions, valid=None):
+    def __call__(self, x, rope, valid=None):
         cfg = self.cfg
         dt, eps = cfg.compute_dtype, cfg.rms_norm_eps
         H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim)
         S = x.shape[-2]
-        blocked = runs_blocked_kernel(self.attn_impl, S)
+        fused = (runs_blocked_kernel(self.attn_impl, S)
+                 and latent_pallas.fits(H, dn, dr, dv))
 
         h = RMSNorm(eps, name="input_norm")(x).astype(dt)
         with phase("fed_latent"):
-            heads = lambda t: t.reshape(t.shape[:-1] + (H, -1))
             c_q = RMSNorm(eps, name="q_a_layernorm")(
                 linear(cfg.q_lora_rank, dt, "q_a_proj")(h)).astype(dt)
-            q = heads(linear(H * (dn + dr), dt, "q_b_proj")(c_q))
+            q = linear(H * (dn + dr), dt, "q_b_proj")(c_q)
             kv_a = linear(cfg.kv_lora_rank + dr, dt,
                           "kv_a_proj_with_mqa")(h)
             c_kv = RMSNorm(eps, name="kv_a_layernorm")(
                 kv_a[..., :cfg.kv_lora_rank]).astype(dt)
-            kv = heads(linear(H * (dn + dv), dt, "kv_b_proj")(c_kv))
-            cos, sin = rope_tables(RopeSpec(rope_theta=cfg.rope_theta), dr,
-                                   positions)
-            q_rope = interleaved_rope(q[..., dn:], cos, sin)
-            k_rope = interleaved_rope(
-                kv_a[..., None, cfg.kv_lora_rank:], cos, sin)
-            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
-            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-                k_rope, kv.shape[:-1] + (dr,))], axis=-1)
-            v = kv[..., dn:]
-            if blocked:
-                # the kernel's layout: q (B, KV = H, 1, S, D) scaled
-                q = (q * (1.0 / math.sqrt(dn + dr))).astype(dt).reshape(
-                    (-1, S, H, 1, dn + dr)).transpose(0, 2, 3, 1, 4)
-                k = k.reshape((-1, S, H, dn + dr)).transpose(0, 2, 1, 3)
-                v = v.reshape((-1, S, H, dv)).transpose(0, 2, 1, 3)
+            kv = linear(H * (dn + dv), dt, "kv_b_proj")(c_kv)
+            cos, sin, pairs = rope
+            if fused:
+                rows = lambda t: t.reshape((-1, S, t.shape[-1]))
+                q, k, v = qkv_to_heads(rows(q), rows(kv), rows(kv_a), pairs,
+                                       scale=1.0 / math.sqrt(dn + dr))
+            else:
+                heads = lambda t: t.reshape(t.shape[:-1] + (H, -1))
+                q, kv = heads(q), heads(kv)
+                q_rope = interleaved_rope(q[..., dn:], cos, sin)
+                k_rope = interleaved_rope(
+                    kv_a[..., None, cfg.kv_lora_rank:], cos, sin)
+                q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+                k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+                    k_rope, kv.shape[:-1] + (dr,))], axis=-1)
+                v = kv[..., dn:]
         with phase("fed_attention"):
-            o = (blocked_grouped_kernel(S, H, H)(q, k, v) if blocked
+            o = (blocked_grouped_kernel(S, H, H)(q, k, v) if fused
                  else self.attn_impl(q, k, v))
-        if blocked:
+        if fused:
             with phase("fed_latent"):
-                o = o.transpose(0, 3, 1, 2, 4).reshape(
-                    x.shape[:-1] + (H, dv))
-        x = x + linear(cfg.hidden_size, dt, "o_proj")(
-            o.reshape(o.shape[:-2] + (H * dv,)))
+                o = heads_to_rows(o).reshape(x.shape[:-1] + (H * dv,))
+        else:
+            o = o.reshape(o.shape[:-2] + (H * dv,))
+        x = x + linear(cfg.hidden_size, dt, "o_proj")(o)
 
         hn = RMSNorm(eps, name="post_norm")(x)
         if not self.sparse:
@@ -218,6 +236,17 @@ class JoyAIBlock(nn.Module):
                            cfg.hidden_size, dt,
                            name="shared_expert")(hn.astype(dt))
         return x + y, counts
+
+
+def rotary_tables(cfg: JoyAIConfig, positions):
+    """What every block's rotary reads, made once a forward pass (under
+    ``fed_latent``) and handed to the blocks: ``rope_tables``' (cos, sin)
+    for the plain path and their ``latent_pallas.pair_tables`` for the
+    fused one (the compiler drops what the path does not read)."""
+    with phase("fed_latent"):
+        cos, sin = rope_tables(RopeSpec(rope_theta=cfg.rope_theta),
+                               cfg.qk_rope_head_dim, positions)
+        return cos, sin, latent_pallas.pair_tables(cos, sin)
 
 
 @functools.lru_cache(maxsize=None)
@@ -238,7 +267,7 @@ class MTPModule(nn.Module):
     attn_impl: Callable = auto_grouped_attention
 
     @nn.compact
-    def __call__(self, h, next_embed, positions, valid=None):
+    def __call__(self, h, next_embed, rope, valid=None):
         cfg = self.cfg
         dt, eps = cfg.compute_dtype, cfg.rms_norm_eps
         both = jnp.concatenate([RMSNorm(eps, name="hnorm")(h),
@@ -246,7 +275,7 @@ class MTPModule(nn.Module):
                                axis=-1).astype(dt)
         x = linear(cfg.hidden_size, dt, "eh_proj")(both)
         x, counts = _block_cls(cfg.remat)(cfg, True, self.attn_impl,
-                                    name="layer")(x, positions, valid)
+                                    name="layer")(x, rope, valid)
         return RMSNorm(eps, name="norm")(x), counts
 
 
@@ -272,13 +301,13 @@ class JoyAILM(nn.Module):
                            (cfg.vocab_size, cfg.hidden_size))
         head = self.param("lm_head", nn.initializers.normal(0.02),
                           (cfg.vocab_size, cfg.hidden_size))
-        positions = jnp.arange(input_ids.shape[-1])
+        rope = rotary_tables(cfg, jnp.arange(input_ids.shape[-1]))
         x = embed[input_ids].astype(cfg.compute_dtype)
         per_layer = []
         for i in range(cfg.num_hidden_layers):
             x, counts = _block_cls(cfg.remat)(
                 cfg, i >= cfg.first_k_dense_replace, self.attn_impl,
-                name=f"layers_{i}")(x, positions, valid)
+                name=f"layers_{i}")(x, rope, valid)
             if counts is not None:
                 per_layer.append(counts)
         hidden = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
@@ -288,7 +317,7 @@ class JoyAILM(nn.Module):
                 valid = valid & shift(valid).at[..., -1].set(False)
             hidden_mtp, counts = MTPModule(cfg, self.attn_impl, name="mtp")(
                 x, embed[shift(input_ids)].astype(cfg.compute_dtype),
-                positions, valid)
+                rope, valid)
         per_layer.append(counts)
         return hidden, hidden_mtp, head, moe_counters(per_layer)
 

@@ -33,8 +33,10 @@ PHASES = (
     "fed_moe",              # models/layers.ExpertLayer: router, the held
                             # experts' batched products; not the shared one
     "fed_latent",           # models/joyai.py: latent attention's low-rank
-                            # projection pairs, latent norms, rotary, k_rope
-                            # over heads, scale and relayout; not W_o
+                            # projection pairs, latent norms, rotary tables;
+                            # ops/latent_pallas.py's kernels around the
+                            # attention (or the plain path's rotary and
+                            # concatenations); not W_o
     "fed_mtp",              # models/joyai.py: the prediction module's norms,
                             # W_eh, its block outside the three scopes above,
                             # its head norm and chunked cross-entropy

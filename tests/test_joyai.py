@@ -578,24 +578,40 @@ def test_remat_changes_nothing_on_the_plain_path():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_blocked_path_is_the_plain_path_with_the_blocked_kernel(
-        monkeypatch):
-    """``JoyAIBlock`` where the blocked kernel runs (interpret mode: q and
-    k 192 wide, v 128 wide, one query head a KV head, S = 1,024, the main
-    block and the prediction module's) against the same model on the
-    plain path, in float32: loss and gradients agree as two float32
-    softmaxes do, and the remat keeps the kernel's two residuals a
-    block."""
+@pytest.fixture
+def latent_kernels(monkeypatch):
+    """``JoyAIBlock`` takes its TPU branch here, with the blocked kernel
+    and the latent kernels around it (``ops/latent_pallas.py``) in
+    interpret mode."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
+    from commefficient_tpu.models import joyai
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(sk, "make_splash_mqa_single_device",
                         functools.partial(sk.make_splash_mqa_single_device,
                                           interpret=True))
+    for name in ("qkv_to_heads", "heads_to_rows"):
+        monkeypatch.setattr(joyai, name, functools.partial(
+            getattr(joyai, name), interpret=True))
+
+
+def _published_latent(heads=2, **kw):
     hf = tiny(1)
     hf.update(qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-              qk_head_dim=192, num_attention_heads=2)
-    lcfg = JoyAIConfig.from_hf(hf, compute_dtype=jnp.float32, remat=True)
+              qk_head_dim=192, num_attention_heads=heads, **kw)
+    return hf
+
+
+def test_blocked_path_is_the_plain_path_with_the_blocked_kernel(
+        latent_kernels):
+    """``JoyAIBlock`` where the blocked kernel runs (interpret mode: q and
+    k 192 wide, v 128 wide, one query head a KV head, S = 1,024, the main
+    block and the prediction module's; the latent kernels around it)
+    against the same model on the plain path, in float32: loss and
+    gradients agree as two float32 softmaxes do, and the remat keeps the
+    kernel's two residuals a block."""
+    lcfg = JoyAIConfig.from_hf(_published_latent(), compute_dtype=jnp.float32,
+                               remat=True)
     ids = jax.random.randint(jax.random.PRNGKey(1), (1, 1024), 0, 64)
     params = jax.jit(JoyAILM(lcfg).init)(jax.random.PRNGKey(0), ids)
     blocked = _grad(JoyAILM(lcfg), params, ids)
@@ -609,3 +625,158 @@ def test_blocked_path_is_the_plain_path_with_the_blocked_kernel(
         scale = float(jnp.abs(b).max())
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-3 * scale, rtol=0)
+
+
+# lane 128 + j of a head as the plain path holds it (the rotary pairs
+# half-split) is lane HALF_SPLIT[128 + j] of the fused path's (in place)
+HALF_SPLIT = np.concatenate([np.arange(128), 128 + np.arange(0, 64, 2),
+                             128 + np.arange(1, 64, 2)])
+
+
+def _plain_to_heads(q, kv, kv_a, cos, sin, scale):
+    """``JoyAIBlock``'s plain path from the projections' rows to the
+    blocked kernel's operands: ``interleaved_rope``, the concatenations,
+    ``splash_grouped_attention``'s scale and transposes. The shared key
+    is spread over the heads in float32 (the same values), so that its
+    transpose sums their cotangents in float32 as the kernel does."""
+    B, S, width = kv.shape
+    H = width // 256
+    q, kv = q.reshape(B, S, H, 192), kv.reshape(B, S, H, 256)
+    k_rope = interleaved_rope(kv_a[..., None, -64:], cos, sin)
+    q = jnp.concatenate([q[..., :128], interleaved_rope(q[..., 128:], cos,
+                                                        sin)], -1)
+    k = jnp.concatenate([kv[..., :128], jnp.broadcast_to(
+        k_rope.astype(jnp.float32), (B, S, H, 64)).astype(kv.dtype)], -1)
+    q = (q * scale).astype(q.dtype)
+    heads = lambda t: jnp.moveaxis(t, 2, 1)
+    return heads(q)[:, :, None], heads(k), heads(kv[..., 128:])
+
+
+def _within_ulps(got, want, bits):
+    """|got - want| is at most the spacing of ``bits``-bit mantissas at
+    the larger of the two (7: one bfloat16 ulp; 6: two), or, where a sum
+    cancels, a float32 ulp of the largest value."""
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    top = np.maximum(np.abs(got), np.abs(want))
+    ulp = np.exp2(np.floor(np.log2(np.maximum(top, 1e-30))) - bits)
+    ulp = np.maximum(ulp, 2.0 ** -22 * top.max())
+    worst = (np.abs(got - want) / ulp).max()
+    assert worst <= 1 and top.max() > 0, worst
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernels_equal_the_plain_path(dtype):
+    """``ops/latent_pallas.py`` in interpret mode against the plain path
+    around the blocked kernel at the published widths (32 heads, q and k
+    128 + 64, v 128, the latent 512 + 64), two sequences of 128 positions
+    (two passes of the kernels' inner loop), values and ``jax.vjp``: q, k
+    and v as the kernel reads them, the pairs in place where the plain
+    path holds them half-split (``HALF_SPLIT``), and the output back to
+    rows. Where every product and sum is exact (whole numbers, tables in
+    eighths) the two are equal to the bit in both dtypes: the same
+    products, signs, scale and roundings. Under the real tables XLA's CPU
+    backend contracts products into fused multiply-adds, and not the same
+    ones in the two programs: float32 agrees to a float32 ulp of the
+    largest value and bfloat16 to a bfloat16 ulp at each rounding."""
+    from commefficient_tpu.ops import latent_pallas as lp
+    dt = jnp.dtype(dtype)
+    B, S, H = 2, 128, 32
+    cos, sin = rope_tables(RopeSpec(rope_theta=32000000), 64, jnp.arange(S))
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+
+    def draw(key, shape, whole):
+        x = jax.random.normal(key, shape, jnp.float32)
+        return (jnp.round(x * 2) if whole else x).astype(dt)
+
+    for whole in (True, False):
+        c, s = ((jnp.round(cos * 8) / 8, jnp.round(sin * 8) / 8) if whole
+                else (cos, sin))
+        # 1/sqrt(192) is exact on neither path; float32 rotates what it
+        # scaled, whose products are not exact
+        scale = 0.125 if whole and dtype == "float32" else 1 / math.sqrt(192)
+        args = (draw(keys[0], (B, S, H * 192), whole),
+                draw(keys[1], (B, S, H * 256), whole),
+                draw(keys[2], (B, S, 512 + 64), whole))
+        cts = (draw(keys[3], (B, H, 1, S, 192), whole),
+               draw(keys[4], (B, H, S, 192), whole),
+               draw(keys[5], (B, H, S, 128), whole))
+        got, got_vjp = jax.vjp(lambda *a: lp.qkv_to_heads(
+            *a, lp.pair_tables(c, s), scale=scale, interpret=True), *args)
+        want, want_vjp = jax.vjp(lambda *a: _plain_to_heads(
+            *a, c, s, scale), *args)
+        assert [a.shape for a in got] == [a.shape for a in cts]
+        assert all(a.dtype == dt for a in got)
+        got = [a[..., HALF_SPLIT] if a.shape[-1] == 192 else a for a in got]
+        plain_cts = [a[..., HALF_SPLIT] if a.shape[-1] == 192 else a
+                     for a in cts]
+        pairs = list(zip(got + list(got_vjp(cts)),
+                         list(want) + list(want_vjp(tuple(plain_cts)))))
+        for a, b in pairs:
+            assert a.shape == b.shape and a.dtype == b.dtype
+            if whole:
+                np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                              np.asarray(b, np.float32))
+            elif dtype == "float32":
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=0,
+                    atol=2.0 ** -22 * float(jnp.abs(b).max()))
+            else:
+                _within_ulps(a, b, 6)
+        # the kv_a cotangent is the shared key's alone
+        assert not np.asarray(pairs[-1][0])[..., :512].any()
+
+    o = draw(keys[6], (B, H, 1, S, 128), False)
+    rows, back = jax.vjp(functools.partial(lp.heads_to_rows, interpret=True),
+                         o)
+    want = jnp.moveaxis(o[:, :, 0], 1, 2).reshape(B, S, H * 128)
+    np.testing.assert_array_equal(np.asarray(rows, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(back(want)[0], np.float32),
+                                  np.asarray(o, np.float32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fused_latent_path_gives_the_plain_paths_gradients(latent_kernels,
+                                                          monkeypatch, dtype):
+    """One ``JoyAIBlock`` at the published latent widths (4 heads,
+    S = 1,024) where the blocked kernel runs (interpret mode), the fused
+    path against the plain path in front of the same kernel
+    (``latent_pallas.fits`` made to say no: ``interleaved_rope``, the
+    concatenations, ``splash_grouped_attention``'s scale and transposes):
+    the block's output, and the gradients of x and of every
+    latent-attention weight and norm. The two paths differ only in the
+    order of the kernel's sums over the rotary lanes and in where the
+    shared key's cotangent is rounded: a relative L2 of 7e-5 at most in
+    bfloat16 and 3e-7 in float32 on the CPU, bounded at 1e-3 and 2e-6 (the
+    harness's own bfloat16 tolerance, ``checks.MODEL_TOL``, is 0.1)."""
+    from commefficient_tpu.models import joyai
+    from commefficient_tpu.ops import latent_pallas as lp
+    lcfg = JoyAIConfig.from_hf(_published_latent(4),
+                               compute_dtype=jnp.dtype(dtype))
+    S = 1024
+    block = joyai.JoyAIBlock(lcfg, False)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, S, 32)).astype(
+        lcfg.compute_dtype)
+    rope = joyai.rotary_tables(lcfg, jnp.arange(S))
+    params = block.init(jax.random.PRNGKey(3), x, rope)
+
+    def run(params, x):
+        y, _ = block.apply(params, x, rope)
+        return (y.astype(jnp.float32) ** 2).mean(), y
+
+    fused = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, x)
+    monkeypatch.setattr(lp, "fits", lambda *a: False)
+    plain = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, x)
+    tol = {"float32": 2e-6, "bfloat16": 1e-3}[dtype]
+    assert tol < checks.MODEL_TOL[dtype]["grad_rel_l2"]
+    rel = lambda a, b: float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                             / jnp.linalg.norm(b.astype(jnp.float32)))
+    assert rel(fused[0][1], plain[0][1]) < tol
+    names = ("q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa",
+             "kv_a_layernorm", "kv_b_proj", "o_proj")
+    grads = [(fused[1][0]["params"][n], plain[1][0]["params"][n])
+             for n in names] + [(fused[1][1], plain[1][1])]
+    for a, b in grads:
+        a, b = jax.tree.leaves(a), jax.tree.leaves(b)
+        assert len(a) == len(b) == 1
+        assert rel(a[0], b[0]) < tol, rel(a[0], b[0])

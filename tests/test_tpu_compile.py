@@ -374,6 +374,15 @@ def test_laguna_block_remat_keeps_the_attention_kernels_residuals(
         hlo, lcfg.vocab_size, lcfg.hidden_size)
 
 
+def _in_scope(hlo, scope):
+    """The names of the compiled text's instructions whose ``op_name``
+    lies under the ``jax.named_scope`` ``scope``."""
+    text = re.sub(r'\n(?="|\}\})', " ", hlo)
+    return {m[1] for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.-]+) = .*op_name=\"[^\"]*\b" + scope
+        + r"\b", text, re.M)}
+
+
 def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
         one_chip, monkeypatch):
     """One client's ``value_and_grad`` of the JoyAI loss at the benchmark
@@ -385,10 +394,20 @@ def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
     prediction module's block included: 6 forward, 6 dq, 6 dkv kernels and
     not 12 forward; nothing writes a (32, 4096, 4096) array of scores;
     and each stream's cross-entropy sums the head's gradient in one
-    product (PR 39)."""
+    product. Around the kernel (``ops/latent_pallas.py``) q, k and
+    v go from the projections' rows to its layout in one call a block and
+    pass (forward, remat forward: 12) and back in one (6), the output to
+    rows and back in one each (18). Under ``fed_latent`` no instruction
+    but those kernels writes a float32 array shaped by the sequence and
+    the heads (the plain path's rotary, casts and relayouts wrote 2.68 GB
+    of them a sequence), and what XLA writes there without a product in
+    bfloat16 is 0.05 GB (3.39 GB before: the concatenations, the spread
+    of the shared key, the relayouts of q, k, v, o and their
+    cotangents)."""
     from commefficient_tpu.losses import make_joyai_loss
     from commefficient_tpu.models.gpt2 import resolve_attn
     from commefficient_tpu.models.joyai import JoyAIConfig, JoyAILM
+    from commefficient_tpu.ops import latent_pallas as lp
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     lcfg = JoyAIConfig.from_json(
         "perfbench/configs/joyai_flash_share32.json",
@@ -411,13 +430,24 @@ def test_joyai_latent_attention_runs_the_blocked_kernel_once_a_block(
     counts = collections.Counter(
         re.sub(r"^%|_(no_)?residuals.*|\.\d+$", "", name) for name in calls)
     assert counts == {"splash_mqa_fwd": 6, "splash_mqa_dq": 6,
-                      "splash_mqa_dkv": 6}, counts
+                      "splash_mqa_dkv": 6, lp.QKV_KERNEL_NAME: 12,
+                      lp.QKV_BWD_KERNEL_NAME: 6, lp.O_KERNEL_NAME: 18}, counts
     # the kernels' operands are at the published widths
     assert re.search(rf"bf16\[{H},1,{S},192\]", hlo)
     assert not re.search(rf"\[{H},(1,)?{S},256\]", hlo)
-    scores = [w for w in _hbm_writes(hlo)
+    writes = list(_hbm_writes(hlo))
+    scores = [w for w in writes
               if w[3][-2:] == (S, S) or np.prod(w[3]) >= H * S * S]
     assert not scores, scores
+    latent = _in_scope(hlo, "fed_latent")
+    glue = [w for w in writes if w[0] in latent]
+    assert glue
+    head_shaped = [w for w in glue if S in w[3] and H in w[3]
+                   and np.prod(w[3]) >= S * H * lp.ROPE]
+    assert not [w for w in head_shaped if w[2] == "f32"], head_shaped
+    # bfloat16 written without a product, a sequence: the gradient of the
+    # latent projection (S x 576), the latent norm's output (S x 512)
+    assert sum(2 * int(np.prod(w[3])) for w in glue if w[2] == "bf16") < 1e9
     # the client step's temporaries (Laguna's: under 300 MB)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
     # both streams' cross-entropy
